@@ -12,7 +12,7 @@
 # The image also carries the full framework (CPU JAX), so it doubles as a
 # reproducible environment for the test suite:
 #   docker run --rm denormalized-tpu-kafka python -m pytest tests/ -q
-FROM python:3.11-slim
+FROM python:3.12-slim
 
 RUN apt-get update \
     && apt-get install -y --no-install-recommends g++ make \
@@ -25,7 +25,7 @@ COPY examples ./examples
 COPY tests ./tests
 COPY bench.py ./
 
-RUN pip install --no-cache-dir -e .[dev] "jax[cpu]"
+RUN pip install --no-cache-dir -e .[dev] "jax[cpu]==0.9.0"
 # pre-build the native components (each falls back to pure Python at
 # runtime if compilation is impossible, hence the permissive tail on
 # THIS step only — a failed pip install above still fails the build)

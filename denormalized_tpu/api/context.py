@@ -11,7 +11,9 @@ accumulator dtype, state capacities, device mesh, and the checkpoint backend.
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Any
 
 import jax.numpy as jnp
@@ -105,7 +107,7 @@ class EngineConfig:
     # single-device kernel strategy:
     #   'scatter'       — ship rows, device scatters them into the window
     #                     ring (general; right when host↔device bandwidth
-    #                     is plentiful, e.g. CPU JAX or co-located TPU)
+    #                     is plentiful)
     #   'pallas_dense'  — ship rows, dense MXU/VPU pallas kernel for
     #                     low-cardinality aggregation (auto-falls-back)
     #   'partial_merge' — reduce each batch on host (native C++ single
@@ -113,14 +115,15 @@ class EngineConfig:
     #                     the device merges them into the ring.  Traffic
     #                     scales with cardinality, not rows — the right
     #                     choice behind a narrow host↔device link
-    #   'auto'          — partial_merge on single-device TPU (host
-    #                     edge-reduction wins on the narrow link) and CPU
-    #                     (it beats XLA scatter adds there too), except
+    #   'auto'          — partial_merge on single-device TPU (a rule
+    #                     chosen on an earlier installation, not measured
+    #                     on this one; ROADMAP S2) and CPU (it beats XLA
+    #                     scatter adds there), except
     #                     f64 accumulators on CPU, which keep scatter:
     #                     the partial stripe's f32 hi/lo transport cannot
     #                     carry finite f64 sums beyond f32 range.  On
-    #                     backends neither measurement covers (e.g. a
-    #                     co-located GPU) 'auto' keeps row shipping
+    #                     backends neither covers (e.g. a GPU) 'auto'
+    #                     keeps row shipping
     device_strategy: str = "auto"
     # partial_merge pacing: merge the host stripe after this many rows even
     # if no window closed, and defer emission up to emit_lag_ms after a
@@ -128,8 +131,8 @@ class EngineConfig:
     # per device round-trip.  None = backend default: 0 on CPU (merges
     # are memcpy-cheap, and deferral would hold a paused live stream's
     # final windows until the next rowful batch), 200ms on every
-    # accelerator backend (TPU, GPU, ...) where the remote merge
-    # round-trip is worth amortizing
+    # accelerator backend (TPU, GPU, ...) to amortize the merge
+    # round-trip (not measured on this installation; ROADMAP S2)
     partial_merge_rows: int = 4_000_000
     emit_lag_ms: int | None = None
     # run backend.accumulate (native stripe reduction, GIL-releasing) on a
@@ -249,13 +252,6 @@ class EngineConfig:
     # (the pre-subsumption behavior; the bench's A/B control).
     mq_subsumption: bool = True
 
-    # persistent XLA compilation cache (jax_compilation_cache_dir): the
-    # engine prewarms its program ladders at stream start, which on a
-    # remote-compile TPU backend costs seconds per program on FIRST run;
-    # with the cache every later process start loads compiled binaries
-    # from disk instead.  None disables; default under ~/.cache.
-    compilation_cache_dir: str | None = "~/.cache/denormalized_tpu/xla"
-
     def set(self, key: str, value) -> "EngineConfig":
         """String-keyed setter for parity with SessionConfig::set
         (README.md:105 `denormalized_config.checkpoint`)."""
@@ -266,80 +262,33 @@ class EngineConfig:
         return self
 
 
-_cache_enabled = False
-_pending_cache_path: str | None = None
+def enable_compilation_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache for this process and
+    return the directory in use (None on the CPU backend).
 
+    The one cache policy, shared by the engine (first device touch, in the
+    window-state factory), ``bench.py`` and ``chip_smoke.py``: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and no
+    directory is set in code; otherwise ``<checkout>/.jax_cache`` — a fixed
+    path, never a temporary name, because a cache that moves between
+    processes never hits.  Every program is cached whatever it cost to
+    compile: the prewarm ladders are dozens of individually cheap programs,
+    and a run that re-reads them all adds no file.  A directory that cannot
+    be created is an error, not a silent recompile.
 
-def _activate_compilation_cache(path: str) -> None:
-    import os
-
+    CPU compiles are not cached: they are fast, and a cached CPU executable
+    may target machine features another host lacks.  Initializes the
+    backend; idempotent."""
     import jax
 
-    full = os.path.expanduser(path)
-    os.makedirs(full, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", full)
-    # cache even fast compiles: the ladder programs are individually
-    # cheap to compile locally but each costs a round-trip on a
-    # remote-compile backend
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-
-
-def _enable_compilation_cache(path: str | None) -> None:
-    """Point JAX's persistent compilation cache at ``path`` (once per
-    process).  A user-set ``JAX_COMPILATION_CACHE_DIR`` or an earlier
-    explicit configuration wins; failures are non-fatal (a read-only HOME
-    must not kill the stream — it just recompiles).
-
-    Only worthwhile for remote-compile accelerator backends; local CPU
-    compiles are fast, and caching them risks loading AOT artifacts whose
-    target machine features don't match the host (XLA warns of possible
-    SIGILL).  When the platform is explicitly configured we decide here;
-    when it is auto-detected (no JAX_PLATFORMS — the common TPU
-    deployment) the decision is DEFERRED to
-    :func:`ensure_compilation_cache_for_backend`, called from the device
-    chokepoint once a real backend exists, so auto-detected TPUs still
-    get the cache (round-2 ADVICE item)."""
-    global _cache_enabled, _pending_cache_path
-    if path is None or _cache_enabled:
-        return
-    _cache_enabled = True
-    import os
-
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        return
-    try:
-        import jax
-
-        if jax.config.jax_compilation_cache_dir:
-            return
-        plat = jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS", "")
-        if not plat:
-            # platform unknown until backend init — don't guess "cpu";
-            # remember the path and let the first device touch decide
-            _pending_cache_path = path
-            return
-        if "cpu" in plat:
-            return
-        _activate_compilation_cache(path)
-    except Exception:  # dnzlint: allow(broad-except) the compilation cache is a pure optimization — a jax-version quirk here must never take the engine down
-        pass
-
-
-def ensure_compilation_cache_for_backend() -> None:
-    """Finish a deferred cache decision now that a backend is initialized
-    (called from the window-state factory, the first point that touches
-    the device).  No-op unless Context deferred with a pending path."""
-    global _pending_cache_path
-    if _pending_cache_path is None:
-        return
-    path, _pending_cache_path = _pending_cache_path, None
-    try:
-        import jax
-
-        if jax.default_backend() != "cpu":
-            _activate_compilation_cache(path)
-    except Exception:  # dnzlint: allow(broad-except) the compilation cache is a pure optimization — a jax-version quirk here must never take the engine down
-        pass
+    if jax.devices()[0].platform == "cpu":
+        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        path = Path(__file__).resolve().parents[2] / ".jax_cache"
+        path.mkdir(exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", str(path))
+    return jax.config.jax_compilation_cache_dir
 
 
 class Context:
@@ -355,7 +304,6 @@ class Context:
         # shared nulls — so concurrently EXECUTING queries with
         # different settings no longer fight over a process-global flag
         # (the PR-6 documented limitation, since fixed).
-        _enable_compilation_cache(self.config.compilation_cache_dir)
 
     def __repr__(self) -> str:
         """String representation (reference context.py:16-30)."""

@@ -395,10 +395,11 @@ class Coordinator:
 
     def _popen_worker(self, argv: list[str]) -> subprocess.Popen:
         env = dict(os.environ)
-        # workers are host-side engine processes; an unset platform
-        # must not auto-grab an accelerator per worker (the device
-        # half stays per-worker via EngineConfig mesh settings)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # workers are host-side engine processes, whatever the
+        # coordinator runs on: a chip belongs to one process, so N
+        # workers that inherited JAX_PLATFORMS=tpu (or auto-detected
+        # one) would fail or hang on it
+        env["JAX_PLATFORMS"] = "cpu"
         return subprocess.Popen(
             argv,
             cwd=os.path.dirname(os.path.dirname(

@@ -247,8 +247,7 @@ class HostPartialStripe:
         covers the largest possible active-cell count.  A fixed spec-
         derived set — instead of pow2-of-observed-A — means every merge
         program can be compiled at construction: observed sizes vary with
-        pacing, and an unseen size mid-stream is a multi-second compile on
-        a remote-compile backend."""
+        pacing, and an unseen size mid-stream is a multi-second compile."""
         # at least one slide unit's worth of cells: the backend chunks
         # batches so a stripe never exceeds max(one unit, the cell cap)
         bound_cells = min(
@@ -375,9 +374,8 @@ class HostPartialStripe:
 
     def _split_sum(self, src: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         """(hi, lo) f32 split of a host f64 sum plane, int32-bitcast —
-        exact for f32 accumulators, ~1e-14 relative for f64 ones (the
-        remote runtime decomposes f64, so raw-bit f64 transport is not
-        portable)."""
+        exact for f32 accumulators, ~1e-14 relative for f64 ones (a TPU
+        has no native f64, so raw-bit f64 transport is not portable)."""
         # overflow-to-inf in the cast and inf - inf below are deliberate
         # (handled by the nonfin branch); suppress the spurious
         # RuntimeWarnings

@@ -25,44 +25,60 @@ scatter path without TPU hardware.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from denormalized_tpu.ops import segment_agg as sa
 
-# dense-path limits: G beyond this, or batches spanning more ring slots than
-# K_ACTIVE, fall back to the scatter path
+# dense-path limits: specs beyond these, or batches spanning more ring slots
+# than K_ACTIVE, fall back to the scatter path
 MAX_DENSE_GROUPS = 2048
+MAX_DENSE_VALUE_COLS = 4
 K_ACTIVE = 8
 TILE = 256
+# widest group tile one kernel instance holds.  The (TILE, G) one-hot and
+# its per-slot masked temporaries live in Mosaic's scoped VMEM (16 MiB on
+# v5e); at 1024 groups they overflow it for tumbling windows, so the group
+# axis is a grid axis and each instance sees at most this many lanes.
+GROUP_TILE = 512
+
+
+def group_tile(G: int) -> int:
+    """Lanes per kernel instance: G is a multiple of 128, so this is 128,
+    256 or 512 and always divides G."""
+    return math.gcd(G, GROUP_TILE)
 
 
 def _kernel(
     values_ref,  # (TILE, V) f32
     colvalid_ref,  # (TILE, V) f32 (1.0 valid)
-    rel_ref,  # (TILE, KREL) int32 — slots relative to base, -1 = dropped.
-    # One column per window the row fans out to (sliding: KREL =
-    # length_units), so the whole fan-out costs ONE kernel launch.
+    in_slot_ref,  # (TILE, K) f32 — 1.0 where the row feeds slot j
     gid_ref,  # (TILE, 1) int32
-    cnt_ref,  # (K, G*V) f32 out — valid-entry count per (slot, col, group)
-    sum_ref,  # (K, G*V) f32 out
-    min_ref,  # (K, G*V) f32 out
-    max_ref,  # (K, G*V) f32 out
-    rowcnt_ref,  # (K, G) f32 out — rows per (slot, group), for count(*)
+    cnt_ref,  # (K, V*GT) f32 out — valid-entry count per (slot, col, group)
+    sum_ref,  # (K, V*GT) f32 out
+    min_ref,  # (K, V*GT) f32 out
+    max_ref,  # (K, V*GT) f32 out
+    rowcnt_ref,  # (K, GT) f32 out — rows per (slot, group), for count(*)
     *,
-    G: int,
+    GT: int,
     V: int,
 ):
-    step = pl.program_id(0)
+    # grid = (group tiles, row tiles): the row axis is innermost, so each
+    # group tile's output block stays resident while every row tile folds
+    # into it, and is initialized on that tile's first row step
+    g0 = pl.program_id(0) * GT
+    step = pl.program_id(1)
     values = values_ref[:]
     colvalid = colvalid_ref[:]
-    rel = rel_ref[:]  # (TILE, KREL)
+    in_slots = in_slot_ref[:]
     gid = gid_ref[:]
 
-    # one-hot over groups, (TILE, G)
-    groups = jax.lax.broadcasted_iota(jnp.int32, (TILE, G), 1)
+    # one-hot over this tile's groups, (TILE, GT)
+    groups = g0 + jax.lax.broadcasted_iota(jnp.int32, (TILE, GT), 1)
     onehot = (gid == groups).astype(jnp.float32)
 
     @pl.when(step == 0)
@@ -74,32 +90,25 @@ def _kernel(
         rowcnt_ref[:] = jnp.zeros_like(rowcnt_ref)
 
     for j in range(K_ACTIVE):
-        # a row feeds slot j through at most one of its KREL fan-out
-        # columns (windows are distinct), so the sum is 0/1
-        in_slot = jnp.sum(
-            (rel == j).astype(jnp.float32), axis=1, keepdims=True
-        )  # (TILE, 1)
-        oh = onehot * in_slot  # rows of this slot only
-        # rows per (slot, group): MXU matmul with a ones vector
+        oh = onehot * in_slots[:, j : j + 1]  # rows of this slot only
         rowcnt_ref[j, :] += jnp.sum(oh, axis=0)
         for v in range(V):
             col = values[:, v : v + 1]  # (TILE, 1)
             ok = colvalid[:, v : v + 1]
             sel = (oh * ok) > 0
+            lanes = slice(v * GT, (v + 1) * GT)
             # count/sum via where-selection: masked-out lanes may hold NaN
             # (values behind an invalid mask are unspecified), and 0*NaN
             # would poison a multiplicative mask
-            cnt_ref[j, v * G : (v + 1) * G] += jnp.sum(oh * ok, axis=0)
-            sum_ref[j, v * G : (v + 1) * G] += jnp.sum(
-                jnp.where(sel, col, 0.0), axis=0
-            )
+            cnt_ref[j, lanes] += jnp.sum(oh * ok, axis=0)
+            sum_ref[j, lanes] += jnp.sum(jnp.where(sel, col, 0.0), axis=0)
             # min/max via masked broadcast reduce on the VPU
-            min_ref[j, v * G : (v + 1) * G] = jnp.minimum(
-                min_ref[j, v * G : (v + 1) * G],
+            min_ref[j, lanes] = jnp.minimum(
+                min_ref[j, lanes],
                 jnp.min(jnp.where(sel, col, jnp.inf), axis=0),
             )
-            max_ref[j, v * G : (v + 1) * G] = jnp.maximum(
-                max_ref[j, v * G : (v + 1) * G],
+            max_ref[j, lanes] = jnp.maximum(
+                max_ref[j, lanes],
                 jnp.max(jnp.where(sel, col, -jnp.inf), axis=0),
             )
 
@@ -110,57 +119,73 @@ def _kernel(
 def _dense_partials(
     values, colvalid, rel, gid, *, G: int, V: int, KREL: int, interpret: bool
 ):
-    """→ (rowcnt (K,G), cnt (K,G,V), sum (K,G,V), min (K,G,V), max (K,G,V))
+    """→ (rowcnt (K,G), cnt (K,V,G), sum (K,V,G), min (K,V,G), max (K,V,G))
 
-    ``rel`` is (B, KREL): each row's target slots (rebased), -1 = dropped."""
+    ``rel`` is (B, KREL): each row's target slots (rebased), -1 = dropped.
+    One column per window the row fans out to (sliding: KREL =
+    length_units); the columns are distinct windows, so folding them into
+    a 0/1 (B, K) slot-membership matrix here keeps the kernel itself
+    independent of KREL."""
     B = values.shape[0]
     assert B % TILE == 0
-    grid = (B // TILE,)
+    GT = group_tile(G)
+    n_gt = G // GT
+    slots = jnp.arange(K_ACTIVE, dtype=jnp.int32)
+    in_slot = jnp.any(
+        rel.reshape(-1, KREL, 1) == slots, axis=1
+    ).astype(jnp.float32)  # (B, K)
+
+    def rows(g, i):
+        return (i, 0)
+
+    def tile(g, i):
+        return (0, g)
+
     outs = pl.pallas_call(
-        functools.partial(_kernel, G=G, V=V),
-        grid=grid,
+        functools.partial(_kernel, GT=GT, V=V),
+        grid=(n_gt, B // TILE),
         in_specs=[
-            pl.BlockSpec((TILE, V), lambda i: (i, 0)),
-            pl.BlockSpec((TILE, V), lambda i: (i, 0)),
-            pl.BlockSpec((TILE, KREL), lambda i: (i, 0)),
-            pl.BlockSpec((TILE, 1), lambda i: (i, 0)),
+            pl.BlockSpec((TILE, V), rows),
+            pl.BlockSpec((TILE, V), rows),
+            pl.BlockSpec((TILE, K_ACTIVE), rows),
+            pl.BlockSpec((TILE, 1), rows),
         ],
-        out_specs=[
-            pl.BlockSpec((K_ACTIVE, G * V), lambda i: (0, 0)),
-            pl.BlockSpec((K_ACTIVE, G * V), lambda i: (0, 0)),
-            pl.BlockSpec((K_ACTIVE, G * V), lambda i: (0, 0)),
-            pl.BlockSpec((K_ACTIVE, G * V), lambda i: (0, 0)),
-            pl.BlockSpec((K_ACTIVE, G), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((K_ACTIVE, G * V), jnp.float32),
-            jax.ShapeDtypeStruct((K_ACTIVE, G * V), jnp.float32),
-            jax.ShapeDtypeStruct((K_ACTIVE, G * V), jnp.float32),
-            jax.ShapeDtypeStruct((K_ACTIVE, G * V), jnp.float32),
-            jax.ShapeDtypeStruct((K_ACTIVE, G), jnp.float32),
-        ],
+        out_specs=[pl.BlockSpec((K_ACTIVE, V * GT), tile)] * 4
+        + [pl.BlockSpec((K_ACTIVE, GT), tile)],
+        out_shape=[jax.ShapeDtypeStruct((K_ACTIVE, V * G), jnp.float32)] * 4
+        + [jax.ShapeDtypeStruct((K_ACTIVE, G), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(
         values.astype(jnp.float32),
         colvalid.astype(jnp.float32),
-        rel.reshape(-1, KREL),
+        in_slot,
         gid.reshape(-1, 1),
     )
     cnt, ssum, smin, smax, rowcnt = outs
-    shp = (K_ACTIVE, V, G)
-    return (
-        rowcnt,
-        cnt.reshape(shp),
-        ssum.reshape(shp),
-        smin.reshape(shp),
-        smax.reshape(shp),
-    )
+
+    def planes(a):
+        # kernel columns are (group tile, value col, lane) — regroup to
+        # (K, V, G)
+        return (
+            a.reshape(K_ACTIVE, n_gt, V, GT)
+            .transpose(0, 2, 1, 3)
+            .reshape(K_ACTIVE, V, G)
+        )
+
+    return rowcnt, planes(cnt), planes(ssum), planes(smin), planes(smax)
 
 
 def dense_supported(spec: sa.WindowKernelSpec) -> bool:
+    """The envelope the kernel is compiled over for v5e by
+    tests/test_tpu_aot_compile.py — keep the two in step."""
     return (
         spec.group_capacity <= MAX_DENSE_GROUPS
-        # sliding fan-out rides the (TILE, k) rel matrix in ONE launch; the
+        and spec.group_capacity % 128 == 0
+        and spec.num_value_cols <= MAX_DENSE_VALUE_COLS
+        # sliding fan-out rides the (B, k) rel matrix in ONE launch; the
         # batch's slot span must still fit the K_ACTIVE scratch rows (the
         # caller additionally checks the actual span per batch)
         and spec.length_units <= K_ACTIVE

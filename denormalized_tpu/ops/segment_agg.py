@@ -600,7 +600,7 @@ def reset_slot(
 def _gather_slot(spec: WindowKernelSpec, state, slot):
     # slot is TRACED: one compiled program serves every ring slot.  Indexing
     # with a Python int instead would compile a fresh gather per distinct
-    # slot — ruinous on a remote-compile TPU backend (seconds per window).
+    # slot (up to W compiles instead of one).
     return {
         c.label: jax.lax.dynamic_index_in_dim(
             state[c.label], slot, axis=0, keepdims=False

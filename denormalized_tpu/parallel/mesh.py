@@ -19,20 +19,13 @@ from jax.sharding import Mesh
 
 
 KEY_AXIS = "keys"
-
-# jax moved shard_map out of jax.experimental at 0.6; the pinned image
-# ships 0.4.x where only the experimental spelling exists.  One shim so
-# every kernel call site works on either — without it EVERY sharded
-# layout (and the multichip dryrun) dies with AttributeError on 0.4.x.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax < 0.6 images (like this one)
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 # second mesh axis for the 2-D layout: data-parallel row slices (each
 # slice ingests its own source partitions; ICI-local key blocks within a
 # slice, cross-slice merge only at emission — the axis that rides DCN in
 # a multi-slice job)
 SLICE_AXIS = "slices"
+
+shard_map = jax.shard_map
 
 
 def make_mesh_2d(

@@ -529,8 +529,9 @@ class StreamingWindowExec(ExecOperator):
         # (None): 0 on CPU — merges are memcpy-cheap, and the deferral
         # only re-checks on rowful batches, so it would hold a paused
         # live stream's final windows until the next batch arrives; 200ms
-        # on every accelerator backend (TPU, GPU, ...) — a remote merge
-        # round-trip over the host↔device link is worth amortizing.
+        # on every accelerator backend (TPU, GPU, ...), to amortize the
+        # merge round-trip — a value chosen on an earlier installation,
+        # not measured on this one (ROADMAP S2).
         if emit_lag_ms is None:
             emit_lag_ms = 0 if jax.default_backend() == "cpu" else 200
         self._emit_lag_s = emit_lag_ms / 1000.0
@@ -586,11 +587,15 @@ class StreamingWindowExec(ExecOperator):
             # deltas in _flush would miss
             m["partial_merges"] = self._backend.merges
             m["device_steps"] = self._backend.merges
+        elif hasattr(self._backend, "dense_updates"):
+            # single-device row shipping: the program each batch took
+            m["dense_updates"] = self._backend.dense_updates
+            m["scatter_updates"] = self._backend.scatter_updates
         m["bytes_h2d"] = self._backend.bytes_h2d
         m["bytes_d2h"] = self._backend.bytes_d2h
-        # what 'auto' actually chose AND what actually dispatched (round-3
-        # VERDICT weak-7: the report must RECORD the resolved strategy,
-        # not just the request) — each backend labels itself
+        # what 'auto' actually chose AND what actually dispatched (a
+        # report must RECORD the resolved strategy, not just the
+        # request) — each backend labels itself
         m["strategy_resolved"] = self._backend.strategy_name
         return m
 
@@ -727,8 +732,13 @@ class StreamingWindowExec(ExecOperator):
         exactly wrong for high-cardinality runs that grow repeatedly."""
         self._backend.bytes_h2d += old_backend.bytes_h2d
         self._backend.bytes_d2h += old_backend.bytes_d2h
-        if hasattr(self._backend, "merges") and hasattr(old_backend, "merges"):
-            self._backend.merges += old_backend.merges
+        for counter in ("merges", "dense_updates", "scatter_updates"):
+            if hasattr(self._backend, counter) and hasattr(old_backend, counter):
+                setattr(
+                    self._backend, counter,
+                    getattr(self._backend, counter)
+                    + getattr(old_backend, counter),
+                )
 
     def _ensure_capacity(self, max_win_rel: int):
         cap = self._backend.group_capacity
@@ -748,6 +758,9 @@ class StreamingWindowExec(ExecOperator):
         n = batch.num_rows
         if n == 0:
             return
+        # perf_counter at the first accepted batch: everything before it
+        # (plan build, prewarm ladders, restore) is set-up
+        self._metrics.setdefault("first_batch_at", t0)
         self._metrics["rows_in"] += n
         self._metrics["batches_in"] += 1
         self._obs_rows_in.add(n)

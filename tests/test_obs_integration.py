@@ -66,7 +66,7 @@ SOURCE_KEYS = {
 WINDOW_KEYS = {
     "rows_in", "batches_in", "late_rows", "windows_emitted",
     "device_steps", "partial_merges", "grow_events", "host_prep_s",
-    "bytes_h2d", "bytes_d2h", "strategy_resolved",
+    "bytes_h2d", "bytes_d2h", "strategy_resolved", "first_batch_at",
 }
 SESSION_KEYS = {
     "rows_in", "sessions_emitted", "late_rows", "salvage_rows_scanned",

@@ -186,3 +186,49 @@ def test_pallas_dense_small_bucket_falls_back(make_batch):
         .collect()
     )
     assert sum(int(c) for c in res.column("c")) == 9
+
+
+def test_dense_partials_tiles_the_group_axis():
+    """Above GROUP_TILE groups the kernel runs one grid row per group tile
+    and the wrapper regroups the (tile, col, lane) columns to (K, V, G):
+    every plane must match a per-cell numpy fold, including groups in the
+    second and third tiles."""
+    from denormalized_tpu.ops import pallas_window as pw
+
+    G, V, KREL, B = 1536, 2, 2, pw.TILE
+    assert G // pw.group_tile(G) == 3
+    rng = np.random.default_rng(3)
+    values = rng.normal(0, 5, (B, V)).astype(np.float32)
+    colvalid = rng.random((B, V)) > 0.2
+    first = rng.integers(-1, pw.K_ACTIVE - 1, B)  # -1 = dropped row
+    rel = np.stack([first, np.where(first >= 0, first + 1, -1)], axis=1)
+    gid = rng.integers(0, G, B)
+    gid[:4] = [0, pw.GROUP_TILE, 2 * pw.GROUP_TILE, G - 1]  # tile edges
+    rowcnt, cnt, ssum, smin, smax = (
+        np.asarray(a)
+        for a in pw._dense_partials(
+            values, colvalid, rel.astype(np.int32), gid.astype(np.int32),
+            G=G, V=V, KREL=KREL, interpret=True,
+        )
+    )
+    want_rows = np.zeros((pw.K_ACTIVE, G))
+    want_cnt = np.zeros((pw.K_ACTIVE, V, G))
+    want_sum = np.zeros((pw.K_ACTIVE, V, G))
+    want_min = np.full((pw.K_ACTIVE, V, G), np.inf)
+    want_max = np.full((pw.K_ACTIVE, V, G), -np.inf)
+    for b in range(B):
+        for j in rel[b]:
+            if j < 0:
+                continue
+            want_rows[j, gid[b]] += 1
+            for v in range(V):
+                if colvalid[b, v]:
+                    want_cnt[j, v, gid[b]] += 1
+                    want_sum[j, v, gid[b]] += values[b, v]
+                    want_min[j, v, gid[b]] = min(want_min[j, v, gid[b]], values[b, v])
+                    want_max[j, v, gid[b]] = max(want_max[j, v, gid[b]], values[b, v])
+    np.testing.assert_array_equal(rowcnt, want_rows)
+    np.testing.assert_array_equal(cnt, want_cnt)
+    np.testing.assert_allclose(ssum, want_sum, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(smin, want_min.astype(np.float32))
+    np.testing.assert_array_equal(smax, want_max.astype(np.float32))

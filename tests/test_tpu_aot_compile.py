@@ -1,0 +1,148 @@
+"""Ahead-of-time compiles for a TPU v5e, on a machine that has none.
+
+libtpu can build a device topology without hardware
+(``jax.experimental.topologies``), and lowering against one of its devices
+runs the real XLA:TPU and Mosaic compilers.  Interpret mode on the CPU
+never sees a Mosaic refusal (scoped-VMEM overflow, an unsupported layout),
+so this is the only guard a CPU run gives the Pallas kernel before a chip
+call — and it costs no chip time.  Skipped where the topology cannot be
+built (no libtpu).
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from denormalized_tpu.ops import pallas_window as pw
+from denormalized_tpu.ops import segment_agg as sa
+from denormalized_tpu.ops.host_partial import HostPartialStripe
+
+B = 131_072  # the `simple` deployment's arrival batch
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure means "no libtpu here"
+        pytest.skip(f"cannot build a v5e topology: {type(e).__name__}: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _simple_spec(slide_ms=1000):
+    # upstream simple_aggregation: count/min/max/avg of one column
+    aggs = [("count", 0), ("min", 0), ("max", 0), ("avg", 0)]
+    return sa.WindowKernelSpec(
+        components=tuple(sa.components_for(aggs)),
+        num_value_cols=1,
+        window_slots=16,
+        group_capacity=1024,
+        length_ms=1000,
+        slide_ms=slide_ms,
+    ), tuple(aggs)
+
+
+def _state(spec, sharding):
+    return {
+        c.label: _sds(
+            sharding,
+            (spec.window_slots, spec.group_capacity),
+            spec.init_value(c).dtype,
+        )
+        for c in spec.components
+    }
+
+
+def _compile_dense(sharding, V, KREL, G):
+    pw._dense_partials.lower(
+        _sds(sharding, (B, V), jnp.float32),
+        _sds(sharding, (B, V), jnp.bool_),
+        _sds(sharding, (B, KREL), jnp.int32),
+        _sds(sharding, (B,), jnp.int32),
+        G=G, V=V, KREL=KREL, interpret=False,
+    ).compile()
+
+
+def test_dense_kernel_compiles_over_its_envelope(v5e):
+    """Every spec ``dense_supported`` admits reaches Mosaic as one of
+    (value cols) x (group tile) kernel bodies: the fan-out KREL is folded
+    outside the kernel and G only sets the grid.  A body's VMEM grows with
+    both, so each value-column count is compiled at the widest tile and
+    each narrower tile once; then the widest grid, where the kernel used
+    to overflow, at several fan-outs."""
+    tiles = sorted(
+        {pw.group_tile(g) for g in range(128, pw.MAX_DENSE_GROUPS + 1, 128)}
+    )
+    assert tiles == [128, 256, pw.GROUP_TILE]
+    for V in range(1, pw.MAX_DENSE_VALUE_COLS + 1):
+        _compile_dense(v5e, V, 1, pw.GROUP_TILE)
+    for gt in tiles[:-1]:
+        _compile_dense(v5e, 1, 1, gt)
+    for V, KREL in ((1, 1), (2, 1), (pw.MAX_DENSE_VALUE_COLS, pw.K_ACTIVE)):
+        _compile_dense(v5e, V, KREL, pw.MAX_DENSE_GROUPS)
+
+
+def test_dense_supported_stays_inside_the_compiled_envelope():
+    spec, _ = _simple_spec()
+
+    def admits(**kw):
+        return pw.dense_supported(dataclasses.replace(spec, **kw))
+
+    assert admits(group_capacity=pw.MAX_DENSE_GROUPS)
+    assert not admits(group_capacity=pw.MAX_DENSE_GROUPS + 128)
+    assert admits(num_value_cols=pw.MAX_DENSE_VALUE_COLS)
+    assert not admits(num_value_cols=pw.MAX_DENSE_VALUE_COLS + 1)
+    assert admits(slide_ms=1000 // pw.K_ACTIVE)
+    assert not admits(slide_ms=100)  # ten windows per row
+
+
+@pytest.mark.parametrize("slide_ms", [1000, 200])
+def test_scatter_update_compiles(v5e, slide_ms):
+    spec, _ = _simple_spec(slide_ms)
+    sa.update_state.lower(
+        spec,
+        _state(spec, v5e),
+        _sds(v5e, (B, 1), jnp.float32),
+        _sds(v5e, (B, 1), jnp.bool_),
+        _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, (B,), jnp.bool_),
+        _sds(v5e, (), jnp.int32),
+    ).compile()
+
+
+@pytest.mark.parametrize("dense", [False, True])
+def test_merge_partials_compiles(v5e, dense):
+    spec, _ = _simple_spec()
+    stripe = HostPartialStripe(spec, spec.group_capacity)
+    a_pad = stripe.transfer_buckets()[-1]
+    lean = sa.lean_possible(spec)
+    rows = stripe.n_planes(lean) + (0 if dense else 1)
+    sa.merge_partials.lower(
+        spec, stripe.SUB, a_pad, lean, dense, _state(spec, v5e),
+        _sds(v5e, (rows, a_pad + 2), jnp.int32),
+    ).compile()
+
+
+def test_emission_programs_compile(v5e):
+    spec, aggs = _simple_spec()
+    slot = _sds(v5e, (), jnp.int32)
+    G = spec.group_capacity
+    sa._gather_and_reset.lower(
+        spec, 8, G, _state(spec, v5e), slot, sa.lean_possible(spec)
+    ).compile()
+    sa._finals_and_reset.lower(
+        spec, aggs, 8, G, _state(spec, v5e), slot
+    ).compile()
