@@ -158,6 +158,7 @@ def _dense_partials(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="dnz_dense_partials",
     )(
         values.astype(jnp.float32),
         colvalid.astype(jnp.float32),

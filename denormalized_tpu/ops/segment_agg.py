@@ -239,6 +239,11 @@ def _apply_component(
     raise ValueError(comp.kind)
 
 
+# the ``dnz.*`` scopes below name the four device programs of the window
+# operator in the profiler's device trace (op_name metadata only: results,
+# shapes and the compiled code do not depend on them).  Each sits on the
+# body every layout shares, so the sharded variants carry the same name.
+@jax.named_scope("dnz.update_state")
 def update_state_impl(
     spec: WindowKernelSpec,
     state: dict[str, jax.Array],
@@ -362,6 +367,7 @@ def lean_possible(spec: WindowKernelSpec) -> bool:
     return any(lean_skippable(c) for c in spec.components)
 
 
+@jax.named_scope("dnz.merge_partials")
 def merge_partials_body(
     spec: WindowKernelSpec,
     SUB: int,
@@ -457,6 +463,7 @@ def merge_partials_body(
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 5), donate_argnums=3)
+@jax.named_scope("dnz.gather_and_reset")
 def _gather_and_reset(
     spec: WindowKernelSpec,
     n: int,
@@ -523,6 +530,7 @@ def finals_possible(agg_specs: tuple) -> bool:
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), donate_argnums=4)
+@jax.named_scope("dnz.finals_and_reset")
 def _finals_and_reset(
     spec: WindowKernelSpec,
     agg_specs: tuple,

@@ -40,6 +40,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from denormalized_tpu.ops import segment_agg as sa
 from denormalized_tpu.parallel.mesh import KEY_AXIS, SLICE_AXIS, shard_map
+from denormalized_tpu.runtime.tracing import NULL_CLOCK
 
 
 class WindowStateBackend:
@@ -55,6 +56,10 @@ class WindowStateBackend:
     # bench JSON and chip_smoke.py's per-leg lines
     bytes_h2d: int = 0
     bytes_d2h: int = 0
+    # the driving operator's phase clock (runtime/tracing.py): the stripe
+    # flush opens ``window.flush`` on it wherever the flush is set off
+    # (trigger, growth, snapshot, or span overflow inside ``accumulate``)
+    phases = NULL_CLOCK
 
     @property
     def strategy_name(self) -> str:
@@ -508,13 +513,15 @@ class _HostPartialMixin:
             remaining &= ~chunk
 
     def flush_pending(self) -> None:
-        taken = self._stripe.take_packed(self._pending_base_mod)
-        if taken is None:
+        if self._stripe.is_empty():
             return
-        packed, a_pad, _u_base, lean, dense = taken
-        self.bytes_h2d += int(packed.nbytes)
-        self._merge(packed, a_pad, lean, dense)
-        self.merges += 1
+        with self.phases.phase("flush", rows=self._stripe.rows):
+            packed, a_pad, _u_base, lean, dense = self._stripe.take_packed(
+                self._pending_base_mod
+            )
+            self.bytes_h2d += int(packed.nbytes)
+            self._merge(packed, a_pad, lean, dense)
+            self.merges += 1
 
 
 class PartialMergeWindowState(_HostPartialMixin, SingleDeviceWindowState):

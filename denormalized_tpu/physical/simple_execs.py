@@ -27,7 +27,17 @@ from denormalized_tpu.physical.base import (
     StreamItem,
     WatermarkHint,
 )
+from denormalized_tpu.runtime.prefetch import PrefetchPump, add_reader_ms
+from denormalized_tpu.runtime.tracing import phase_clock
 from denormalized_tpu.sources.base import Source
+
+#: the fetch + decode layer's span counters in ``SourceExec.metrics()``:
+#: 0 where no pump (bounded or single-partition source) or no Kafka reader
+#: runs
+SOURCE_PHASE_KEYS = (
+    "prefetch_read_ms", "prefetch_blocked_ms", "kafka_fetch_ms",
+    "kafka_decode_ms", "queue_wait_ms",
+)
 
 
 #: per-process ordinal per source NAME: two sources sharing a name (the
@@ -268,6 +278,10 @@ class SourceExec(ExecOperator):
         # same query-scoped registry regardless of which thread drives
         # the generator or when a supervised rebuild happens
         self._obs_reg = obs.current_registry()
+        # ``source.queue_wait``: the pull thread blocked in the pump's get
+        # with nothing ready — truly starved, unlike the operator-side
+        # input wait, which also holds this thread's own upstream work
+        self._phases = phase_clock("source", ("queue_wait",))
         # collision-free series label (see _source_series_label): two
         # same-named sources in one plan get distinct series
         self._obs_source_label = _source_series_label(str(source.name))
@@ -375,6 +389,8 @@ class SourceExec(ExecOperator):
 
     def metrics(self):
         m = dict(self._metrics)
+        m.update(dict.fromkeys(SOURCE_PHASE_KEYS, 0.0))
+        m["queue_wait_ms"] = self._phases.ms.get("queue_wait", 0.0)
         # per-partition Python-decode fallback counts, aggregated: a
         # schema shape that silently routes to the ~30x-slower Python
         # decoder must be observable, not a quiet perf cliff.  Reading an
@@ -401,6 +417,7 @@ class SourceExec(ExecOperator):
             m["prefetch_restarted_partitions"] = rs["restarted_partitions"]
             if rs["last_errors"]:
                 m["prefetch_last_errors"] = dict(rs["last_errors"])
+            m.update(self._pump.phase_ms())
         else:
             m["decode_fallback_rows"] = sum(
                 r.decode_fallback_rows() for r in (self._readers or [])
@@ -409,6 +426,8 @@ class SourceExec(ExecOperator):
                 int(getattr(r, "salvaged_rows", 0) or 0)
                 for r in (self._readers or [])
             )
+            for r in self._readers or []:
+                add_reader_ms(m, r)
         return m
 
     def _label(self):
@@ -518,8 +537,6 @@ class SourceExec(ExecOperator):
         # persistence reflects only yielded batches; backpressure is the
         # per-partition bounded buffer inside the pump, released only
         # after downstream fully processed the batch.
-        from denormalized_tpu.runtime.prefetch import PrefetchPump
-
         with obs.bound_registry(self._obs_reg):
             pump = PrefetchPump(
                 readers,
@@ -553,7 +570,11 @@ class SourceExec(ExecOperator):
                 # liveness-checked get: a worker that died without its
                 # sentinel surfaces as a structured error instead of
                 # wedging the stream in an untimed queue wait
-                item = pump.get_live()
+                item = pump.get_ready()
+                if item is None:
+                    # nothing ready: only now is the pull thread waiting
+                    with self._phases.phase("queue_wait"):
+                        item = pump.get_live()
                 if isinstance(item, BaseException):
                     raise item
                 idx, snap, batch = item

@@ -69,6 +69,7 @@ from denormalized_tpu.physical.window_exec import (
     watermark_floor,
     window_output_low_watermark,
 )
+from denormalized_tpu.runtime.tracing import logger, span
 
 #: aggregate kinds whose windows fold exactly from slice partials —
 #: the sketch kinds fold within their documented error bounds via
@@ -1439,8 +1440,6 @@ class SliceWindowExec(ExecOperator):
         # adoption when the (replayed) live registration re-attaches
         self._orphans = by_tag
         if by_tag:
-            from denormalized_tpu.runtime.tracing import logger
-
             logger.info(
                 "slice restore retained %d orphan cursor(s) awaiting "
                 "re-attachment: %s", len(by_tag),
@@ -1481,8 +1480,6 @@ class SliceWindowExec(ExecOperator):
 
     # -- stream loop -----------------------------------------------------
     def run(self) -> Iterator[StreamItem]:
-        from denormalized_tpu.runtime.tracing import span
-
         for item in self._doctor_input():
             if isinstance(item, RecordBatch):
                 # dnzlint: allow(unguarded) boundary fast-path peek: truthiness load is atomic and _drain_ops re-checks _pending_ops under _ops_lock; a stale miss just defers the op to the next batch boundary

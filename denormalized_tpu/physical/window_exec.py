@@ -55,6 +55,17 @@ from denormalized_tpu.physical.base import (
     StreamItem,
     WatermarkHint,
 )
+from denormalized_tpu.runtime.tracing import logger, phase_clock, span
+
+#: keys of the window operator's phase clock, surfaced by ``metrics()`` as
+#: ``phase_ms_<key>``: exclusive host milliseconds, so they add up to the
+#: wall the operator's outer spans covered (docs/observability.md, Spans).
+#: ``other`` is the self time of the outer spans (``window.process_batch``,
+#: ``window.hint``, ``window.marker``, ``window.eos``).
+WINDOW_PHASES = (
+    "project", "intern", "statewatch", "reduce", "acc_wait", "update",
+    "trigger", "flush", "gather", "d2h_wait", "finalize", "other",
+)
 
 
 def _next_pow2(n: int) -> int:
@@ -254,8 +265,6 @@ class _WindowTier:
                             self.node_id, block_id, blob
                         )
                     except StateError as e:
-                        from denormalized_tpu.runtime.tracing import logger
-
                         logger.warning(
                             "spill: window eviction put failed (%s) — "
                             "window %d stays resident this pass", e, j,
@@ -465,11 +474,10 @@ class StreamingWindowExec(ExecOperator):
             accum_dtype=accum_dtype,
             compensated=compensated_sums,
         )
-        from denormalized_tpu.parallel.sharded_state import make_sharded_state
-
-        self._backend = make_sharded_state(
-            self._spec, mesh, shard_strategy, device_strategy
-        )
+        # bound like the registry instruments below: the shared falsy null
+        # under metrics_enabled=False
+        self._phases = phase_clock("window", WINDOW_PHASES)
+        self._backend = self._new_backend()
         # on-device finalization: emission ships final output planes + an
         # active bitmask instead of raw component planes (see
         # segment_agg._finals_and_reset).  Only when every aggregate is
@@ -552,7 +560,9 @@ class StreamingWindowExec(ExecOperator):
             "device_steps": 0,
             "partial_merges": 0,
             "grow_events": 0,
-            "host_prep_s": 0.0,
+            # whole duration of the spans around hints, markers and
+            # end-of-stream: the hint path's counterpart of dnz_op_batch_ms
+            "hint_path_ms": 0.0,
         }
         # registry instruments (obs subsystem), pre-bound so the per-
         # batch path is attribute adds only
@@ -593,6 +603,9 @@ class StreamingWindowExec(ExecOperator):
             m["scatter_updates"] = self._backend.scatter_updates
         m["bytes_h2d"] = self._backend.bytes_h2d
         m["bytes_d2h"] = self._backend.bytes_d2h
+        ms = self._phases.ms
+        for key in WINDOW_PHASES:
+            m[f"phase_ms_{key}"] = ms.get(key, 0.0)
         # what 'auto' actually chose AND what actually dispatched (a
         # report must RECORD the resolved strategy, not just the
         # request) — each backend labels itself
@@ -675,9 +688,16 @@ class StreamingWindowExec(ExecOperator):
         ]
 
     # -- capacity management --------------------------------------------
-    def _grow(self, *, window_slots: int | None = None, group_capacity: int | None = None):
+    def _new_backend(self):
         from denormalized_tpu.parallel.sharded_state import make_sharded_state
 
+        backend = make_sharded_state(
+            self._spec, self._mesh, self._shard_strategy, self._device_strategy
+        )
+        backend.phases = self._phases  # window.flush is opened in there
+        return backend
+
+    def _grow(self, *, window_slots: int | None = None, group_capacity: int | None = None):
         # host-accumulated partials are bound to the old G/W layout —
         # merge them into device state before exporting it
         self._join_acc()
@@ -716,9 +736,7 @@ class StreamingWindowExec(ExecOperator):
                 remapped[label] = nbuf
             host = remapped
         old_backend = self._backend
-        self._backend = make_sharded_state(
-            self._spec, self._mesh, self._shard_strategy, self._device_strategy
-        )
+        self._backend = self._new_backend()
         self._carry_counters(old_backend)
         if self._finals_specs is not None:
             self._backend.prepare_finals(self._finals_specs)
@@ -754,196 +772,203 @@ class StreamingWindowExec(ExecOperator):
 
     # -- per-batch processing -------------------------------------------
     def _process_batch(self, batch: RecordBatch) -> Iterator[RecordBatch]:
-        t0 = time.perf_counter()
         n = batch.num_rows
         if n == 0:
             return
-        # perf_counter at the first accepted batch: everything before it
-        # (plan build, prewarm ladders, restore) is set-up
-        self._metrics.setdefault("first_batch_at", t0)
+        if "first_batch_at" not in self._metrics:
+            # perf_counter at the first accepted batch: everything before
+            # it (plan build, prewarm ladders, restore) is set-up
+            self._metrics["first_batch_at"] = time.perf_counter()
         self._metrics["rows_in"] += n
+        # ordinal of this batch: the identifier its spans share
+        bno = self._metrics["batches_in"]
         self._metrics["batches_in"] += 1
         self._obs_rows_in.add(n)
-        S = self.slide_ms
-        ts = np.asarray(batch.column(CANONICAL_TIMESTAMP_COLUMN), dtype=np.int64)
-        units, rem64 = np.divmod(ts, S)  # one pass for quotient+remainder
-        rem = rem64.astype(np.int32)
+        ph = self._phases
+        with ph.phase("project", batch=bno, rows=n):
+            S = self.slide_ms
+            ts = np.asarray(batch.column(CANONICAL_TIMESTAMP_COLUMN), dtype=np.int64)
+            units, rem64 = np.divmod(ts, S)  # one pass for quotient+remainder
+            rem = rem64.astype(np.int32)
 
-        anchor = int(units.min()) - self._spec.length_units + 1
-        if self._first_open is None:
-            # windows overlapping the first data: back to units.min() - k + 1
-            self._first_open = anchor
-        elif self._src_watermarks and anchor < self._first_open:
-            # per-partition watermarks: the first batch anchored first_open
-            # to ITS partition's windows, but a slower partition's earlier
-            # windows are still legitimate until the (min-driven) watermark
-            # closes them.  Rebase down to the watermark floor — the ring
-            # addresses slots by absolute window index, so this only
-            # widens the logical span (capacity grows below).  Triggers
-            # advance first_open exactly to the wm floor, so anything
-            # below it was genuinely closed and stays late.
-            wm_floor = (
-                watermark_floor(
-                    self._watermark_ms, self.length_ms, self.slide_ms
+            anchor = int(units.min()) - self._spec.length_units + 1
+            if self._first_open is None:
+                # windows overlapping the first data: back to units.min() - k + 1
+                self._first_open = anchor
+            elif self._src_watermarks and anchor < self._first_open:
+                # per-partition watermarks: the first batch anchored first_open
+                # to ITS partition's windows, but a slower partition's earlier
+                # windows are still legitimate until the (min-driven) watermark
+                # closes them.  Rebase down to the watermark floor — the ring
+                # addresses slots by absolute window index, so this only
+                # widens the logical span (capacity grows below).  Triggers
+                # advance first_open exactly to the wm floor, so anything
+                # below it was genuinely closed and stays late.
+                wm_floor = (
+                    watermark_floor(
+                        self._watermark_ms, self.length_ms, self.slide_ms
+                    )
+                    if self._watermark_ms is not None
+                    else anchor
                 )
-                if self._watermark_ms is not None
-                else anchor
-            )
-            new_first = max(anchor, int(wm_floor))
-            if new_first < self._first_open:
-                if self._backend.accumulates_host:
-                    # the pending stripe's units are relative to the OLD
-                    # first_open (via its captured base_mod) — fold it
-                    # into the device ring before the base moves
-                    self._flush()
-                # the widened span (new_first.._max_win_seen) needs ring
-                # capacity, and the grow must run BEFORE the base moves:
-                # _grow attributes old ring slots to windows
-                # first_open..first_open+old_W-1, so lowering first would
-                # alias a re-admitted low window with a live high one and
-                # the remap would credit the high window's accumulators
-                # to the low one (found by hypothesis: L=1000/S=100,
-                # span 17 over a 16-slot ring lost window 7's content).
-                # No sentinel guard: reaching this branch means a batch
-                # was seen, and _max_win_seen's -1 floor (negative
-                # event-time streams pin it there) only OVERestimates
-                # the span — a larger-than-needed grow is safe, a
-                # skipped one aliases slots.
-                self._ensure_capacity(self._max_win_seen - new_first)
-                self._first_open = new_first
-        if self._tier is not None:
-            # reload-on-touch BEFORE win_rel is computed: a spilled
-            # window this batch's rows can land in comes back into the
-            # ring (first_open lowers with it), so nothing reads as late
-            # that the all-resident run would have accepted
-            self._tier.touch_and_reload(
-                int(units.min()) - self._spec.length_units + 1,
-                int(units.max()),
-            )
-        first = self._first_open
-        win_rel64 = units - first
-        self._max_win_seen = max(self._max_win_seen, int(units.max()))
-        late = int((win_rel64 < 0).sum())
-        if late:
-            self._metrics["late_rows"] += late
-            self._obs_late.add(late)
+                new_first = max(anchor, int(wm_floor))
+                if new_first < self._first_open:
+                    if self._backend.accumulates_host:
+                        # the pending stripe's units are relative to the OLD
+                        # first_open (via its captured base_mod) — fold it
+                        # into the device ring before the base moves
+                        self._flush()
+                    # the widened span (new_first.._max_win_seen) needs ring
+                    # capacity, and the grow must run BEFORE the base moves:
+                    # _grow attributes old ring slots to windows
+                    # first_open..first_open+old_W-1, so lowering first would
+                    # alias a re-admitted low window with a live high one and
+                    # the remap would credit the high window's accumulators
+                    # to the low one (found by hypothesis: L=1000/S=100,
+                    # span 17 over a 16-slot ring lost window 7's content).
+                    # No sentinel guard: reaching this branch means a batch
+                    # was seen, and _max_win_seen's -1 floor (negative
+                    # event-time streams pin it there) only OVERestimates
+                    # the span — a larger-than-needed grow is safe, a
+                    # skipped one aliases slots.
+                    self._ensure_capacity(self._max_win_seen - new_first)
+                    self._first_open = new_first
+            if self._tier is not None:
+                # reload-on-touch BEFORE win_rel is computed: a spilled
+                # window this batch's rows can land in comes back into the
+                # ring (first_open lowers with it), so nothing reads as late
+                # that the all-resident run would have accepted
+                self._tier.touch_and_reload(
+                    int(units.min()) - self._spec.length_units + 1,
+                    int(units.max()),
+                )
+            first = self._first_open
+            win_rel64 = units - first
+            self._max_win_seen = max(self._max_win_seen, int(units.max()))
+            late = int((win_rel64 < 0).sum())
+            if late:
+                self._metrics["late_rows"] += late
+                self._obs_late.add(late)
 
-        # group ids — intern BEFORE the capacity check so G always covers
-        # every id this batch scatters
-        if self._grouped:
-            key_cols = [g.eval(batch) for g in self.group_exprs]
-            gid = self._interner.intern(key_cols)
-        else:
-            gid = np.zeros(n, dtype=np.int32)
-        self._sw.update(gid)
-        self._ensure_capacity(int(win_rel64.max()))
+            # group ids — intern BEFORE the capacity check so G always covers
+            # every id this batch scatters
+            with ph.phase("intern", batch=bno):
+                if self._grouped:
+                    key_cols = [g.eval(batch) for g in self.group_exprs]
+                    gid = self._interner.intern(key_cols)
+                else:
+                    gid = np.zeros(n, dtype=np.int32)
+            with ph.phase("statewatch", batch=bno):
+                self._sw.update(gid)
+            self._ensure_capacity(int(win_rel64.max()))
 
-        # value matrix + per-column validity: f64 only when the backend
-        # accumulates on host (partial_merge keeps f64 precision); the
-        # row-shipping paths fill f32 directly — no second full-matrix copy
-        V = self._spec.num_value_cols
-        from denormalized_tpu.logical.expr import column_validity
+            # value matrix + per-column validity: f64 only when the backend
+            # accumulates on host (partial_merge keeps f64 precision); the
+            # row-shipping paths fill f32 directly — no second full-matrix copy
+            V = self._spec.num_value_cols
+            from denormalized_tpu.logical.expr import column_validity
 
-        host_dtype = (
-            np.float64 if self._backend.accumulates_host else np.float32
-        )
-        single_untransformed = (
-            V == 1 and self._value_transforms[0] is None
-        )
-        if single_untransformed:
-            # single untransformed value column (the common case): the
-            # evaluated column IS the value matrix — skip the zeros
-            # allocation and the per-column copy.  The host reducer and
-            # the device paths only read it, so aliasing the batch
-            # column (host path, already f64) is safe.
-            e = self._value_exprs[0]
-            values64 = np.asarray(e.eval(batch), dtype=host_dtype).reshape(
-                n, 1
+            host_dtype = (
+                np.float64 if self._backend.accumulates_host else np.float32
             )
-            colvalid = np.ones((n, 1), dtype=bool)
-            m = column_validity(e, batch)
-            any_invalid = False
-            if m is not None:
-                colvalid[:, 0] = m
-                any_invalid = not m.all()
-        else:
-            values64 = np.zeros((n, max(V, 1)), dtype=host_dtype)
-            colvalid = np.ones((n, max(V, 1)), dtype=bool)
-            any_invalid = False
-            for j, e in enumerate(self._value_exprs):
-                raw = np.asarray(e.eval(batch), dtype=np.float64)
+            single_untransformed = (
+                V == 1 and self._value_transforms[0] is None
+            )
+            if single_untransformed:
+                # single untransformed value column (the common case): the
+                # evaluated column IS the value matrix — skip the zeros
+                # allocation and the per-column copy.  The host reducer and
+                # the device paths only read it, so aliasing the batch
+                # column (host path, already f64) is safe.
+                e = self._value_exprs[0]
+                values64 = np.asarray(e.eval(batch), dtype=host_dtype).reshape(
+                    n, 1
+                )
+                colvalid = np.ones((n, 1), dtype=bool)
                 m = column_validity(e, batch)
+                any_invalid = False
                 if m is not None:
-                    colvalid[:, j] = m
-                    any_invalid = any_invalid or not colvalid[:, j].all()
-                tr = self._value_transforms[j]
-                if tr is not None:
-                    # variance moment columns: shift by a pivot K taken
-                    # from the first valid value ever seen for this
-                    # expression, so the s2 − s²/c finalize never
-                    # catastrophically cancels (exact for any constant K)
-                    key = repr(e)
-                    K = self._var_shift.get(key)
-                    if K is None:
-                        valid_vals = (
-                            raw[colvalid[:, j]] if m is not None else raw
-                        )
-                        finite = valid_vals[np.isfinite(valid_vals)]
-                        if len(finite):
-                            K = float(finite[0])
-                            self._var_shift[key] = K
-                        else:
-                            # no finite value yet (all-null warm-up
-                            # batch): use 0 transiently but do NOT cache
-                            # it — a later batch with real data must
-                            # still set a magnitude-matched pivot, or
-                            # the cancellation guard is lost
-                            K = 0.0
-                    raw = raw - K
-                    if tr == "shift_sq":
-                        raw = raw * raw
-                values64[:, j] = raw
+                    colvalid[:, 0] = m
+                    any_invalid = not m.all()
+            else:
+                values64 = np.zeros((n, max(V, 1)), dtype=host_dtype)
+                colvalid = np.ones((n, max(V, 1)), dtype=bool)
+                any_invalid = False
+                for j, e in enumerate(self._value_exprs):
+                    raw = np.asarray(e.eval(batch), dtype=np.float64)
+                    m = column_validity(e, batch)
+                    if m is not None:
+                        colvalid[:, j] = m
+                        any_invalid = any_invalid or not colvalid[:, j].all()
+                    tr = self._value_transforms[j]
+                    if tr is not None:
+                        # variance moment columns: shift by a pivot K taken
+                        # from the first valid value ever seen for this
+                        # expression, so the s2 − s²/c finalize never
+                        # catastrophically cancels (exact for any constant K)
+                        key = repr(e)
+                        K = self._var_shift.get(key)
+                        if K is None:
+                            valid_vals = (
+                                raw[colvalid[:, j]] if m is not None else raw
+                            )
+                            finite = valid_vals[np.isfinite(valid_vals)]
+                            if len(finite):
+                                K = float(finite[0])
+                                self._var_shift[key] = K
+                            else:
+                                # no finite value yet (all-null warm-up
+                                # batch): use 0 transiently but do NOT cache
+                                # it — a later batch with real data must
+                                # still set a magnitude-matched pivot, or
+                                # the cancellation guard is lost
+                                K = 0.0
+                        raw = raw - K
+                        if tr == "shift_sq":
+                            raw = raw * raw
+                    values64[:, j] = raw
 
-        if any_invalid:
-            self._any_nulls_seen = True
+            if any_invalid:
+                self._any_nulls_seen = True
 
-        if self._backend.accumulates_host:
-            # partial_merge: reduce the batch on host; the device sees a
-            # merged stripe later (flush on trigger/growth/snapshot).
-            # Late-drop against the WATERMARK (windows already closable),
-            # not first_open: emission deferral must not make drop
-            # semantics wall-clock-dependent — this is exactly where the
-            # scatter path's first_open would sit, since it emits every
-            # closable window immediately.
-            closable_pre = self._closable()
-            if late or closable_pre:
-                keep = win_rel64 >= closable_pre
-                if closable_pre and self._spec.length_units > 1:
-                    # A kept row's unit partial feeds EVERY window
-                    # containing that unit — including closable windows
-                    # whose emission is merely deferred.  The stripe is
-                    # per-unit, so that stale contribution cannot be
-                    # subtracted per-window later; the only sound order is
-                    # freeze-then-accumulate: emit every closable window
-                    # now, then rebase against the advanced first_open.
-                    # Only rows strictly BEHIND the watermark can straddle
-                    # (a row at ts ≥ wm has no closable window), so a
-                    # sorted feed never takes this path.
-                    lows = win_rel64 - (self._spec.length_units - 1)
-                    if bool((keep & (lows < closable_pre)).any()):
-                        yield from self._trigger(force=True)
-                        first = self._first_open
-                        win_rel64 = units - first
-                        closable_pre = self._closable()  # 0 post-emission
-                        keep = win_rel64 >= closable_pre
-                n_drop = int((~keep).sum())
-                if n_drop:
-                    self._metrics["late_rows"] += n_drop - late
-                    self._obs_late.add(n_drop - late)
+            if self._backend.accumulates_host:
+                # partial_merge: reduce the batch on host; the device sees a
+                # merged stripe later (flush on trigger/growth/snapshot).
+                # Late-drop against the WATERMARK (windows already closable),
+                # not first_open: emission deferral must not make drop
+                # semantics wall-clock-dependent — this is exactly where the
+                # scatter path's first_open would sit, since it emits every
+                # closable window immediately.
+                closable_pre = self._closable()
+                if late or closable_pre:
+                    keep = win_rel64 >= closable_pre
+                    if closable_pre and self._spec.length_units > 1:
+                        # A kept row's unit partial feeds EVERY window
+                        # containing that unit — including closable windows
+                        # whose emission is merely deferred.  The stripe is
+                        # per-unit, so that stale contribution cannot be
+                        # subtracted per-window later; the only sound order is
+                        # freeze-then-accumulate: emit every closable window
+                        # now, then rebase against the advanced first_open.
+                        # Only rows strictly BEHIND the watermark can straddle
+                        # (a row at ts ≥ wm has no closable window), so a
+                        # sorted feed never takes this path.
+                        lows = win_rel64 - (self._spec.length_units - 1)
+                        if bool((keep & (lows < closable_pre)).any()):
+                            yield from self._trigger(force=True)
+                            first = self._first_open
+                            win_rel64 = units - first
+                            closable_pre = self._closable()  # 0 post-emission
+                            keep = win_rel64 >= closable_pre
+                    n_drop = int((~keep).sum())
+                    if n_drop:
+                        self._metrics["late_rows"] += n_drop - late
+                        self._obs_late.add(n_drop - late)
+                    else:
+                        keep = None
                 else:
                     keep = None
-            else:
-                keep = None
+        if self._backend.accumulates_host:
             if (
                 self._acc_future is None or self._acc_future.done()
             ) and self._backend.pending_rows == 0:
@@ -958,10 +983,10 @@ class StreamingWindowExec(ExecOperator):
                 first % self._spec.window_slots,
             )
             if self._host_pipeline:
-                self._submit_acc(*acc_args)
+                self._submit_acc(bno, acc_args)
             else:
-                self._backend.accumulate(*acc_args)
-            self._metrics["host_prep_s"] += time.perf_counter() - t0
+                with ph.phase("reduce", batch=bno):
+                    self._backend.accumulate(*acc_args)
         else:
             values = values64  # already f32 (see allocation above)
             win_rel = np.clip(
@@ -982,25 +1007,25 @@ class StreamingWindowExec(ExecOperator):
                 out[:n] = a
                 return out
 
-            self._metrics["host_prep_s"] += time.perf_counter() - t0
-            self._backend.update(
-                pad(values),
-                pad(colvalid),
-                pad(win_rel, fill=-1),
-                pad(rem),
-                pad(gid),
-                row_valid,
-                first % self._spec.window_slots,
-                # span of the ON-TIME rows only: late rows (win_rel < 0)
-                # are dropped by both kernels and must not widen the
-                # dense-path span
-                min_win_rel=int(
-                    win_rel64[win_rel64 >= 0].min()
-                    if (win_rel64 >= 0).any()
-                    else 0
-                ),
-                max_win_rel=int(win_rel64.max()),
-            )
+            with ph.phase("update", batch=bno):
+                self._backend.update(
+                    pad(values),
+                    pad(colvalid),
+                    pad(win_rel, fill=-1),
+                    pad(rem),
+                    pad(gid),
+                    row_valid,
+                    first % self._spec.window_slots,
+                    # span of the ON-TIME rows only: late rows (win_rel < 0)
+                    # are dropped by both kernels and must not widen the
+                    # dense-path span
+                    min_win_rel=int(
+                        win_rel64[win_rel64 >= 0].min()
+                        if (win_rel64 >= 0).any()
+                        else 0
+                    ),
+                    max_win_rel=int(win_rel64.max()),
+                )
             self._metrics["device_steps"] += 1
 
         # watermark: monotonic max of batch min-ts (reference semantics) —
@@ -1029,7 +1054,8 @@ class StreamingWindowExec(ExecOperator):
         err = None
         if f is not None:
             try:
-                f.result()  # re-raises a worker failure on this thread
+                with self._phases.phase("acc_wait"):
+                    f.result()  # re-raises a worker failure on this thread
             finally:
                 # read the flag only AFTER the wait: an EARLIER task
                 # (future superseded by a later submission) may set it
@@ -1045,7 +1071,7 @@ class StreamingWindowExec(ExecOperator):
             # half-updated stripe
             raise err
 
-    def _submit_acc(self, *args) -> None:
+    def _submit_acc(self, bno: int, args: tuple) -> None:
         if self._acc_error is not None:
             err, self._acc_error = self._acc_error, None
             raise err
@@ -1057,10 +1083,12 @@ class StreamingWindowExec(ExecOperator):
             )
 
         backend = self._backend
+        ph = self._phases
 
         def run():
             try:
-                backend.accumulate(*args)
+                with ph.phase("reduce", batch=bno):
+                    backend.accumulate(*args)
             except BaseException as e:  # surfaced via _join_acc/_submit_acc
                 self._acc_error = e
                 raise
@@ -1088,41 +1116,52 @@ class StreamingWindowExec(ExecOperator):
         if not self._pending_emit:
             return
         pending, self._pending_emit = self._pending_emit, []
-        ngroups = len(self._interner) if self._grouped else 1
+        ph = self._phases
         for j0, n, handle, is_finals in pending:
-            block = self._backend.read_reset_block_finish(handle)
-            if is_finals:
-                # finals block: one plane per output aggregate + packed
-                # active bitmask; no host-side finalize needed
-                bits = np.unpackbits(block[sa.ACTIVE_BITS], axis=1)
-                for i in range(n):
-                    active = bits[i].astype(bool)
-                    active[ngroups:] = False
-                    if not active.any():
-                        continue
-                    gids = np.nonzero(active)[0].astype(np.int32)
-                    finals = [
-                        block[f"__final_{k}__"][i][gids]
-                        for k in range(len(self.aggr_exprs))
-                    ]
-                    self._metrics["windows_emitted"] += 1
-                    yield self._build_emission_finals(j0 + i, gids, finals)
-                continue
-            # lean gathers omit per-column count planes (null-free stream:
-            # they equal the row-count plane) — alias them back
-            for c in self._spec.components:
-                if c.kind == "count" and c.label not in block:
-                    block[c.label] = block[sa.ROW_COUNT.label]
+            # the only place the pull thread waits for the device
+            with ph.phase("d2h_wait", window=j0, n=n):
+                block = self._backend.read_reset_block_finish(handle)
+            with ph.phase("finalize", window=j0, n=n):
+                out = list(self._finalize_block(j0, n, block, is_finals))
+            yield from out
+
+    def _finalize_block(
+        self, j0: int, n: int, block: dict, is_finals: bool
+    ) -> Iterator[RecordBatch]:
+        """Emission batches of one materialized block of ``n`` windows."""
+        ngroups = len(self._interner) if self._grouped else 1
+        if is_finals:
+            # finals block: one plane per output aggregate + packed
+            # active bitmask; no host-side finalize needed
+            bits = np.unpackbits(block[sa.ACTIVE_BITS], axis=1)
             for i in range(n):
-                rows = {label: arr[i] for label, arr in block.items()}
-                counts = rows[sa.ROW_COUNT.label]
-                active = counts > 0
+                active = bits[i].astype(bool)
                 active[ngroups:] = False
                 if not active.any():
                     continue
-                self._metrics["windows_emitted"] += 1
                 gids = np.nonzero(active)[0].astype(np.int32)
-                yield self._build_emission(j0 + i, gids, rows, active)
+                finals = [
+                    block[f"__final_{k}__"][i][gids]
+                    for k in range(len(self.aggr_exprs))
+                ]
+                self._metrics["windows_emitted"] += 1
+                yield self._build_emission_finals(j0 + i, gids, finals)
+            return
+        # lean gathers omit per-column count planes (null-free stream:
+        # they equal the row-count plane) — alias them back
+        for c in self._spec.components:
+            if c.kind == "count" and c.label not in block:
+                block[c.label] = block[sa.ROW_COUNT.label]
+        for i in range(n):
+            rows = {label: arr[i] for label, arr in block.items()}
+            counts = rows[sa.ROW_COUNT.label]
+            active = counts > 0
+            active[ngroups:] = False
+            if not active.any():
+                continue
+            self._metrics["windows_emitted"] += 1
+            gids = np.nonzero(active)[0].astype(np.int32)
+            yield self._build_emission(j0 + i, gids, rows, active)
 
     def _trigger(self, force: bool = False) -> Iterator[RecordBatch]:
         """Emit every window whose end ≤ watermark (trigger_windows,
@@ -1179,6 +1218,19 @@ class StreamingWindowExec(ExecOperator):
                 and self._stripe_fits_more()
             ):
                 return
+        # the trigger acts.  Only now does it open its span: it runs after
+        # every batch and every hint and mostly returns above, and the
+        # deferral test is not worth two clock reads each time (it stays
+        # in the self time of the outer span)
+        with self._phases.phase(
+            "trigger", batch=self._metrics["batches_in"], n=n_close
+        ):
+            yield from self._close_windows(n_close)
+
+    def _close_windows(self, n_close: int) -> Iterator[RecordBatch]:
+        """Flush the stripe, then gather (and, where nothing is deferred,
+        emit) the ``n_close`` windows the watermark has closed."""
+        if self._backend.accumulates_host:
             self._flush()
         if self._emission_compaction:
             while self._first_open * self.slide_ms + self.length_ms <= self._watermark_ms:
@@ -1192,27 +1244,27 @@ class StreamingWindowExec(ExecOperator):
             n = 1 << min(3, (n_close).bit_length() - 1)
             n = min(n, self._spec.window_slots)
             live = len(self._interner) if self._grouped else 1
-            handle = None
-            if self._finals_specs is not None:
-                handle = self._backend.read_reset_block_finals_start(
-                    self._first_open % self._spec.window_slots, n,
-                    live_groups=live,
-                )
-            if handle is not None:
-                self._pending_emit.append((self._first_open, n, handle, True))
-            else:
-                handle = self._backend.read_reset_block_start(
-                    self._first_open % self._spec.window_slots, n,
-                    live_groups=live,
-                    # only when the lean layout actually differs — else the
-                    # lean=True program would be a duplicate compilation of
-                    # the full one
-                    lean=(
-                        not self._any_nulls_seen
-                        and sa.lean_possible(self._spec)
-                    ),
-                )
-                self._pending_emit.append((self._first_open, n, handle, False))
+            with self._phases.phase("gather", window=self._first_open, n=n):
+                handle = None
+                if self._finals_specs is not None:
+                    handle = self._backend.read_reset_block_finals_start(
+                        self._first_open % self._spec.window_slots, n,
+                        live_groups=live,
+                    )
+                is_finals = handle is not None
+                if not is_finals:
+                    handle = self._backend.read_reset_block_start(
+                        self._first_open % self._spec.window_slots, n,
+                        live_groups=live,
+                        # only when the lean layout actually differs — else
+                        # the lean=True program would be a duplicate
+                        # compilation of the full one
+                        lean=(
+                            not self._any_nulls_seen
+                            and sa.lean_possible(self._spec)
+                        ),
+                    )
+            self._pending_emit.append((self._first_open, n, handle, is_finals))
             self._first_open += n
             n_close -= n
         if not self._backend.accumulates_host or self._emit_lag_s == 0:
@@ -1240,11 +1292,15 @@ class StreamingWindowExec(ExecOperator):
         self._backend.flush_pending()
 
     def _emit_window(self, j: int) -> RecordBatch | None:
-        from denormalized_tpu.runtime.tracing import span
+        with self._phases.phase("finalize", window=j, n=1):
+            return self._emit_window_inner(j)
 
+    def _emit_window_inner(self, j: int) -> RecordBatch | None:
         slot = j % self._spec.window_slots
         compacted = None
-        with span("window.emit", op=self.name, window=j * self.slide_ms):
+        with self._phases.phase("d2h_wait", window=j, n=1), span(
+            "window.emit", op=self.name, window=j * self.slide_ms
+        ):
             if self._emission_compaction:
                 compacted = self._backend.read_slot_compact(slot)
             if compacted is not None:
@@ -1403,7 +1459,6 @@ class StreamingWindowExec(ExecOperator):
 
     def _restore(self) -> None:
         from denormalized_tpu.state.serialization import unpack_snapshot
-        from denormalized_tpu.parallel.sharded_state import make_sharded_state
 
         coord, key = self._ckpt
         blob = coord.get_snapshot(key)
@@ -1423,9 +1478,7 @@ class StreamingWindowExec(ExecOperator):
             compensated=old.compensated,
         )
         old_backend = self._backend
-        self._backend = make_sharded_state(
-            self._spec, self._mesh, self._shard_strategy, self._device_strategy
-        )
+        self._backend = self._new_backend()
         self._carry_counters(old_backend)
         if self._finals_specs is not None:
             self._backend.prepare_finals(self._finals_specs)
@@ -1494,8 +1547,7 @@ class StreamingWindowExec(ExecOperator):
                 ex.shutdown(wait=True)
 
     def _run_inner(self) -> Iterator[StreamItem]:
-        from denormalized_tpu.runtime.tracing import span
-
+        ph = self._phases
         for item in self._doctor_input():
             if isinstance(item, RecordBatch):
                 # materialize any in-flight snapshot and release its
@@ -1507,13 +1559,15 @@ class StreamingWindowExec(ExecOperator):
                 # operator's own work, not time spent suspended while
                 # downstream consumed the yielded windows
                 t0 = time.perf_counter()
-                with span(
-                    "window.process_batch", op=self.name, rows=item.num_rows
+                with ph.phase(
+                    "process_batch", "other", op=self.name,
+                    rows=item.num_rows, batch=self._metrics["batches_in"],
                 ):
                     out = list(self._process_batch(item))
                 self._note_batch(t0, item.num_rows)
                 yield from out
-            elif isinstance(item, WatermarkHint):
+                continue
+            if isinstance(item, WatermarkHint):
                 if item.kind == "partition":
                     # authoritative per-partition watermark: from now on
                     # batch min-ts must not advance the watermark
@@ -1521,81 +1575,93 @@ class StreamingWindowExec(ExecOperator):
                     if item.is_announcement:
                         yield item  # pure mode announcement
                         continue
-                    # barrier alignment: a held marker must reach
-                    # downstream before any trigger output this hint
-                    # produces (same invariant as the batch path)
-                    yield from self._release_snapshot()
-                    if (
-                        self._watermark_ms is None
-                        or item.ts_ms > self._watermark_ms
-                    ):
-                        self._watermark_ms = item.ts_ms
-                        # normal trigger: these hints arrive continuously
-                        # (one per advancing batch), so the emit-lag
-                        # deferral keeps working — no force, no drain
-                        yield from self._trigger()
-                    yield WatermarkHint(
-                        min(item.ts_ms, self._output_low_watermark(item.ts_ms)),
-                        kind="partition",
-                    )
-                    continue
-                # idle source: advance event time and close what's ready,
-                # then forward the hint for downstream stateful operators —
-                # CLAMPED below this operator's lowest possible future
-                # emission timestamp (emissions are stamped with the
-                # window START, so an unclamped forward would make a
-                # downstream operator drop our later closed windows as
-                # late)
-                yield from self._release_snapshot()
-                if self._watermark_ms is None or item.ts_ms > self._watermark_ms:
-                    self._watermark_ms = item.ts_ms
-                    # force: the emit-lag deferral assumes another batch
-                    # (or hint) will follow, but an idle period delivers
-                    # exactly ONE hint — a deferred emission would never
-                    # run and the final windows would sit closed-but-
-                    # unemitted, defeating the feature.  Likewise drain
-                    # the async emission pipeline NOW: blocks dispatched
-                    # by this trigger normally materialize on the next
-                    # item, and there is no next item.
-                    yield from self._trigger(force=True)
-                    yield from self._drain_pending()
-                yield WatermarkHint(
-                    min(item.ts_ms, self._output_low_watermark(item.ts_ms))
-                )
+                name, handler = "hint", self._on_hint
             elif isinstance(item, Marker):
-                yield from self._drain_pending()
-                yield from self._release_snapshot()  # an earlier epoch
-                if self._ckpt is not None:
-                    self._snapshot(item.epoch)
-                    self._held_marker = item
-                else:
-                    yield item
+                name, handler = "marker", self._on_marker
             elif isinstance(item, EndOfStream):
-                # pending blocks are watermark-CLOSED windows: they emit
-                # even when the unclosed-window flush is disabled
-                yield from self._drain_pending()
-                yield from self._release_snapshot()
-                if self.emit_on_close and self._first_open is not None:
-                    self._flush()
-                    if self._tier is not None and self._tier.any_spilled:
-                        # spilled windows all sit below first_open:
-                        # flushing them first keeps ascending order
-                        for j in self._tier.due_windows(
-                            self._max_win_seen + 1
-                        ):
-                            b = self._finalize_rows(
-                                j, self._tier.emit_rows(j)
-                            )
-                            if b is not None:
-                                yield b
-                    for j in range(self._first_open, self._max_win_seen + 1):
-                        b = self._emit_window(j)
-                        if b is not None:
-                            yield b
-                    self._first_open = self._max_win_seen + 1
-                else:
-                    # no final flush ran — still fence the worker so an
-                    # async accumulate failure cannot be swallowed
-                    self._join_acc()
-                yield EOS
+                name, handler = "eos", self._on_eos
+            else:
+                continue
+            # same bracket contract as the batch path, for the items
+            # dnz_op_batch_ms does not cover: every trigger, flush, gather
+            # and emission a hint sets off is timed here
+            t0 = time.perf_counter()
+            with ph.phase(name, "other", batch=self._metrics["batches_in"]):
+                out = list(handler(item))
+            self._metrics["hint_path_ms"] += (time.perf_counter() - t0) * 1e3
+            yield from out
+            if name == "eos":
                 return
+
+    def _on_hint(self, item: WatermarkHint) -> Iterator[StreamItem]:
+        if item.kind == "partition":
+            # barrier alignment: a held marker must reach downstream
+            # before any trigger output this hint produces (same
+            # invariant as the batch path)
+            yield from self._release_snapshot()
+            if self._watermark_ms is None or item.ts_ms > self._watermark_ms:
+                self._watermark_ms = item.ts_ms
+                # normal trigger: these hints arrive continuously (one per
+                # advancing batch), so the emit-lag deferral keeps working
+                # — no force, no drain
+                yield from self._trigger()
+            yield WatermarkHint(
+                min(item.ts_ms, self._output_low_watermark(item.ts_ms)),
+                kind="partition",
+            )
+            return
+        # idle source: advance event time and close what's ready, then
+        # forward the hint for downstream stateful operators — CLAMPED
+        # below this operator's lowest possible future emission timestamp
+        # (emissions are stamped with the window START, so an unclamped
+        # forward would make a downstream operator drop our later closed
+        # windows as late)
+        yield from self._release_snapshot()
+        if self._watermark_ms is None or item.ts_ms > self._watermark_ms:
+            self._watermark_ms = item.ts_ms
+            # force: the emit-lag deferral assumes another batch (or hint)
+            # will follow, but an idle period delivers exactly ONE hint — a
+            # deferred emission would never run and the final windows would
+            # sit closed-but-unemitted, defeating the feature.  Likewise
+            # drain the async emission pipeline NOW: blocks dispatched by
+            # this trigger normally materialize on the next item, and there
+            # is no next item.
+            yield from self._trigger(force=True)
+            yield from self._drain_pending()
+        yield WatermarkHint(
+            min(item.ts_ms, self._output_low_watermark(item.ts_ms))
+        )
+
+    def _on_marker(self, item: Marker) -> Iterator[StreamItem]:
+        yield from self._drain_pending()
+        yield from self._release_snapshot()  # an earlier epoch
+        if self._ckpt is not None:
+            self._snapshot(item.epoch)
+            self._held_marker = item
+        else:
+            yield item
+
+    def _on_eos(self, item: EndOfStream) -> Iterator[StreamItem]:
+        # pending blocks are watermark-CLOSED windows: they emit even when
+        # the unclosed-window flush is disabled
+        yield from self._drain_pending()
+        yield from self._release_snapshot()
+        if self.emit_on_close and self._first_open is not None:
+            self._flush()
+            if self._tier is not None and self._tier.any_spilled:
+                # spilled windows all sit below first_open: flushing them
+                # first keeps ascending order
+                for j in self._tier.due_windows(self._max_win_seen + 1):
+                    b = self._finalize_rows(j, self._tier.emit_rows(j))
+                    if b is not None:
+                        yield b
+            for j in range(self._first_open, self._max_win_seen + 1):
+                b = self._emit_window(j)
+                if b is not None:
+                    yield b
+            self._first_open = self._max_win_seen + 1
+        else:
+            # no final flush ran — still fence the worker so an async
+            # accumulate failure cannot be swallowed
+            self._join_acc()
+        yield EOS

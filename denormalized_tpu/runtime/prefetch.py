@@ -63,7 +63,12 @@ import time
 from typing import Callable, Iterator
 
 from denormalized_tpu.common.errors import SourceError, StateError
-from denormalized_tpu.runtime.tracing import logger, span
+from denormalized_tpu.runtime.tracing import (
+    NULL_CLOCK,
+    logger,
+    phase_clock,
+    span,
+)
 from denormalized_tpu.state.tiering import (
     backpressure_pause as _backpressure_pause,
     pressure_engaged as _pressure_engaged,
@@ -110,6 +115,15 @@ class _RestartBudget:
     def remaining(self) -> int:
         with self._lock:
             return self._n
+
+
+def add_reader_ms(into: dict[str, float], reader) -> None:
+    """Add a reader's own phase clock, where it keeps one, to ``into``
+    under the counter names ``<owner>_<phase>_ms``."""
+    clock = getattr(reader, "phases", NULL_CLOCK)
+    for key, ms in clock.ms.items():
+        name = f"{clock.owner}_{key}_ms"
+        into[name] = into.get(name, 0.0) + ms
 
 
 class PrefetchWorker:
@@ -171,6 +185,9 @@ class PrefetchWorker:
         #: the count doubled or dropped mid-swap.
         self.retired_decode_fallback_rows = 0
         self.retired_salvaged_rows = 0
+        #: retired readers' own phase clocks (the Kafka reader's
+        #: ``kafka_fetch_ms`` / ``kafka_decode_ms``), carried likewise
+        self.retired_reader_ms: dict[str, float] = {}
         self._swap_lock = threading.Lock()
         # single-writer activity slots (worker writes enq_*, consumer
         # writes deq_) — see module docstring
@@ -211,6 +228,12 @@ class PrefetchWorker:
             "dnz_prefetch_queue_dwell_ms",
             source=source_name, partition=str(idx),
         )
+        # where this worker's thread spends its wall: ``read`` in
+        # reader.read() (wire fetch + decode), ``blocked`` waiting for a
+        # buffer slot because the consumer has not taken the last batch.
+        # Busy reading while the consumer waits = the source is the
+        # bottleneck; blocked while the consumer is busy = it is not.
+        self.phases = phase_clock("prefetch", ("read", "blocked"))
 
     def start(self) -> None:
         self._thread = threading.Thread(
@@ -294,6 +317,7 @@ class PrefetchWorker:
             self.retired_salvaged_rows += int(
                 getattr(old, "salvaged_rows", 0) or 0
             )
+            add_reader_ms(self.retired_reader_ms, old)
             self.reader = new
         # caught_up stays False (set when the crash was detected) until
         # the rebuilt reader's first fetch reports real backlog state
@@ -315,6 +339,14 @@ class PrefetchWorker:
                 self.reader.decode_fallback_rows()
                 + self.retired_decode_fallback_rows
             )
+
+    def reader_phase_ms(self) -> dict[str, float]:
+        """Current + retired readers' phase milliseconds by counter name,
+        glitch-free across a supervised reader swap."""
+        with self._swap_lock:
+            out = dict(self.retired_reader_ms)
+            add_reader_ms(out, self.reader)
+            return out
 
     def salvaged_total(self) -> int:
         """Current + retired salvage-skipped (undecodable, dropped)
@@ -412,6 +444,8 @@ class PrefetchWorker:
             probe = None
         if self._last_snap is None:
             self._last_snap = reader.offset_snapshot()
+        ph = self.phases
+        seq = 0
         while not self._done.is_set():
             if self._streak and (
                 time.monotonic() - self._restart_wall >= self._heal_after_s
@@ -430,7 +464,9 @@ class PrefetchWorker:
                 # watermark stalls and the pressure can never clear).
                 # Broker-side backlog absorbs what we stop fetching.
                 _backpressure_pause()
-            b = reader.read(timeout_s=self._read_timeout_s)
+            seq += 1
+            with ph.phase("read", partition=self.idx, seq=seq):
+                b = reader.read(timeout_s=self._read_timeout_s)
             self.first_read_done = True
             if b is None:
                 return  # partition exhausted (or reader died cleanly)
@@ -454,11 +490,12 @@ class PrefetchWorker:
                 self.enq_rowful += 1
                 self._obs_depth.set(self.enq_rowful - self.deq_rowful)
             snap = reader.offset_snapshot()
-            if not self._acquire_slot():
-                return  # shutdown won
-            # the enqueue stamp rides the item: the consumer observes
-            # queue dwell (enqueue → dequeue) at _strip time
-            self._q.put((self.idx, snap, b, time.perf_counter()))
+            with ph.phase("blocked", partition=self.idx, seq=seq):
+                if not self._acquire_slot():
+                    return  # shutdown won
+                # the enqueue stamp rides the item: the consumer observes
+                # queue dwell (enqueue → dequeue) at _strip time
+                self._q.put((self.idx, snap, b, time.perf_counter()))
             self._last_snap = snap
 
 
@@ -574,6 +611,20 @@ class PrefetchPump:
             "global_budget_remaining": self._global_budget.remaining(),
         }
 
+    def phase_ms(self) -> dict[str, float]:
+        """Milliseconds per span of the fetch + decode layer, summed over
+        workers (so up to ``len(workers)`` threads' worth per wall ms):
+        ``prefetch_read_ms``, ``prefetch_blocked_ms`` and, where the
+        readers keep a clock of their own, ``kafka_fetch_ms`` /
+        ``kafka_decode_ms``."""
+        out = {"prefetch_read_ms": 0.0, "prefetch_blocked_ms": 0.0}
+        for w in self.workers:
+            for key, ms in w.phases.ms.items():
+                out[f"prefetch_{key}_ms"] += ms
+            for name, ms in w.reader_phase_ms().items():
+                out[name] = out.get(name, 0.0) + ms
+        return out
+
     def _strip(self, item):
         """Normalize a queue item for consumers: observe the handoff
         dwell (enqueue stamp → now) for rowful batches and strip the
@@ -593,6 +644,13 @@ class PrefetchPump:
 
     def get(self):
         return self._strip(self._q.get())
+
+    def get_ready(self):
+        """The next item if one is ready, else None — without blocking."""
+        try:
+            return self._strip(self._q.get_nowait())
+        except queue_mod.Empty:
+            return None
 
     def get_live(self, timeout_s: float = 30.0):
         """Blocking get with a liveness backstop.  A live worker

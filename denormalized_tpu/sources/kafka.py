@@ -34,7 +34,7 @@ from denormalized_tpu.formats.json_codec import (
 from denormalized_tpu.native.build import load
 from denormalized_tpu.physical.simple_execs import Sink
 from denormalized_tpu.runtime import faults
-from denormalized_tpu.runtime.tracing import logger
+from denormalized_tpu.runtime.tracing import logger, phase_clock
 from denormalized_tpu.sources.base import (
     PartitionReader,
     Source,
@@ -563,6 +563,9 @@ class KafkaPartitionReader(PartitionReader):
             "dnz_source_salvaged_rows",
             source=self._topic, partition=str(partition),
         )
+        # inside the prefetch worker's ``prefetch.read``: the wire fetch
+        # (broker wait included) against the native decode
+        self.phases = phase_clock("kafka", ("fetch", "decode"))
         # backlog report from the last fetch response (None = unknown):
         # consumed by the prefetch engine's idleness judgment — a reader
         # that KNOWS the broker holds more records must never be judged
@@ -723,9 +726,13 @@ class KafkaPartitionReader(PartitionReader):
             and len(chunks) < self._MAX_COALESCED_FETCHES
         ):
             try:
-                n2, bptr2, optr2, ts2, off2 = self._client.fetch_ptrs(
-                    self._topic, self._partition, self._offset, max_wait_ms=0
-                )
+                with self.phases.phase(
+                    "fetch", partition=self._partition, offset=self._offset
+                ):
+                    n2, bptr2, optr2, ts2, off2 = self._client.fetch_ptrs(
+                        self._topic, self._partition, self._offset,
+                        max_wait_ms=0,
+                    )
             except SourceError:
                 # records already collected must still decode — the
                 # cursor has advanced past them; surface the transport
@@ -766,10 +773,15 @@ class KafkaPartitionReader(PartitionReader):
     def _read_once(self, native, max_wait):
         if self._client is None:
             raise SourceError("kafka client disconnected")
+        ph = self.phases
         if native is not None:
-            n, bptr, optr, kafka_ts, next_off = self._client.fetch_ptrs(
-                self._topic, self._partition, self._offset, max_wait_ms=max_wait
-            )
+            with ph.phase(
+                "fetch", partition=self._partition, offset=self._offset
+            ):
+                n, bptr, optr, kafka_ts, next_off = self._client.fetch_ptrs(
+                    self._topic, self._partition, self._offset,
+                    max_wait_ms=max_wait,
+                )
             self._consecutive_failures = 0
             self._offset = next_off
             hw = self._client.high_watermark()
@@ -786,26 +798,31 @@ class KafkaPartitionReader(PartitionReader):
                 n, bptr, optr, kafka_ts, next_off, rec_offs = (
                     self._coalesce_fetches(n, bptr, optr, kafka_ts, next_off)
                 )
-            try:
-                batch, kafka_ts = parse_fetch_arena(
-                    native, n, bptr, optr, kafka_ts
-                )
-            except FormatError as e:
-                offs = _fetch_offsets(optr, n)
-                raw = _fetch_raw_bytes(bptr, offs)
-                payloads = [
-                    raw[offs[i] : offs[i + 1]] for i in range(n)
-                ]
-                batch, kafka_ts = self._salvage_decode(payloads, kafka_ts, e)
+            with ph.phase("decode", partition=self._partition, rows=n):
+                try:
+                    batch, kafka_ts = parse_fetch_arena(
+                        native, n, bptr, optr, kafka_ts
+                    )
+                except FormatError as e:
+                    offs = _fetch_offsets(optr, n)
+                    raw = _fetch_raw_bytes(bptr, offs)
+                    payloads = [
+                        raw[offs[i] : offs[i + 1]] for i in range(n)
+                    ]
+                    batch, kafka_ts = self._salvage_decode(
+                        payloads, kafka_ts, e
+                    )
             if batch is None:
                 return RecordBatch.empty(self._src.schema)
             return self._maybe_split(
                 self._attach_ts(batch, kafka_ts), n, next_off, rec_offs
             )
 
-        payloads, kafka_ts, next_off = self._client.fetch(
-            self._topic, self._partition, self._offset, max_wait_ms=max_wait
-        )
+        with ph.phase("fetch", partition=self._partition, offset=self._offset):
+            payloads, kafka_ts, next_off = self._client.fetch(
+                self._topic, self._partition, self._offset,
+                max_wait_ms=max_wait,
+            )
         self._consecutive_failures = 0
         # commit before decode (see above)
         self._offset = next_off
@@ -824,14 +841,15 @@ class KafkaPartitionReader(PartitionReader):
             payloads = [payloads[i] for i in keep]
             if not payloads:
                 return RecordBatch.empty(self._src.schema)
-        try:
-            for p in payloads:
-                self._decoder.push(p)
-            batch = self._decoder.flush()
-        except FormatError as e:
-            batch, kafka_ts = self._salvage_decode(payloads, kafka_ts, e)
-            if batch is None:
-                return RecordBatch.empty(self._src.schema)
+        with ph.phase("decode", partition=self._partition, rows=n_fetch):
+            try:
+                for p in payloads:
+                    self._decoder.push(p)
+                batch = self._decoder.flush()
+            except FormatError as e:
+                batch, kafka_ts = self._salvage_decode(payloads, kafka_ts, e)
+        if batch is None:
+            return RecordBatch.empty(self._src.schema)
         return self._maybe_split(
             self._attach_ts(batch, kafka_ts), n_fetch, next_off
         )

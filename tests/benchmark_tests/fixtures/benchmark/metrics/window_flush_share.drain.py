@@ -1,0 +1,10 @@
+"""Share of the window spent in ``window.flush``: packing the stripe,
+``jnp.asarray(packed)`` and the merge program's dispatch.  100 x the
+counters' delta over the window's milliseconds; nothing where the program
+has no such counter."""
+
+from benchmark.harness.host_spans import PHASE_SHARES, share
+
+
+def read(obs):
+    return share(obs, *PHASE_SHARES["window_flush_share.drain"])

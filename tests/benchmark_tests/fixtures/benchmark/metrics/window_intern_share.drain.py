@@ -1,0 +1,9 @@
+"""Share of the window the pull thread spent in ``window.intern``: group
+expressions and ``Interner.intern``.  100 x the counters' delta over the
+window's milliseconds; nothing where the program has no such counter."""
+
+from benchmark.harness.host_spans import PHASE_SHARES, share
+
+
+def read(obs):
+    return share(obs, *PHASE_SHARES["window_intern_share.drain"])

@@ -62,11 +62,20 @@ def _by_class(metrics_by_node):
 #: a conscious diff here
 SOURCE_KEYS = {
     "rows_out", "batches_out", "decode_fallback_rows", "salvaged_rows",
+    # span counters of the fetch + decode layer (docs/observability.md,
+    # Spans): 0 where no pump or no Kafka reader runs
+    "prefetch_read_ms", "prefetch_blocked_ms", "kafka_fetch_ms",
+    "kafka_decode_ms", "queue_wait_ms",
 }
 WINDOW_KEYS = {
     "rows_in", "batches_in", "late_rows", "windows_emitted",
-    "device_steps", "partial_merges", "grow_events", "host_prep_s",
+    "device_steps", "partial_merges", "grow_events", "hint_path_ms",
     "bytes_h2d", "bytes_d2h", "strategy_resolved", "first_batch_at",
+    # exclusive host milliseconds per phase of the operator
+    "phase_ms_project", "phase_ms_intern", "phase_ms_statewatch",
+    "phase_ms_reduce", "phase_ms_acc_wait", "phase_ms_update",
+    "phase_ms_trigger", "phase_ms_flush", "phase_ms_gather",
+    "phase_ms_d2h_wait", "phase_ms_finalize", "phase_ms_other",
 }
 SESSION_KEYS = {
     "rows_in", "sessions_emitted", "late_rows", "salvage_rows_scanned",
