@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import os
 import subprocess
 import threading
 from pathlib import Path
@@ -99,15 +100,22 @@ def _compile_locked(
     ).hexdigest()
     have = stamp.read_text().strip() if stamp.exists() else ""
     if not so.exists() or have != want:
+        # the module lock is per process: several processes of a fresh
+        # checkout (test workers, cluster workers) build at once, so each
+        # links into a file of its own and renames it into place — a
+        # loader never maps a library another process is still writing
+        tmp = _DIR / f".{name}{flavor}.{os.getpid()}.tmp.so"
         cmd = [
             "g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-            str(src), "-o", str(so),
+            str(src), "-o", str(tmp),
         ] + build_flags
         proc = subprocess.run(cmd, capture_output=True, text=True)
         if proc.returncode != 0:
+            tmp.unlink(missing_ok=True)
             raise RuntimeError(
                 f"native build of {name} failed:\n{proc.stderr[-2000:]}"
             )
+        os.replace(tmp, so)
         stamp.write_text(want)
     return so
 

@@ -4,8 +4,9 @@
 // detection/sanitizers: none"); this is our answer for the native runtime.
 //
 // Exercises: LSM store (put/get/delete/recovery/compaction), string
-// interner (growth, duplicates, width changes), JSON parser (escapes,
-// nulls, duplicates, malformed rows).
+// interner (growth, duplicates, width changes, keys around the inline
+// width, block boundaries, the id store), JSON parser (escapes, nulls,
+// duplicates, malformed rows).
 
 #include <atomic>
 #include <cassert>
@@ -57,6 +58,57 @@ static void test_lsm(const char* dir) {
   printf("lsm ok\n");
 }
 
+// -- interner helpers: keys in exactly-sized heap buffers, so ASAN sees
+// any read past a caller's last byte
+struct OffsetsBatch {
+  uint8_t* bytes;
+  std::vector<uint64_t> offsets;
+  explicit OffsetsBatch(const std::vector<std::string>& keys) {
+    size_t total = 0;
+    offsets.push_back(0);
+    for (auto& k : keys) {
+      total += k.size();
+      offsets.push_back(total);
+    }
+    bytes = (uint8_t*)malloc(total ? total : 1);
+    size_t at = 0;
+    for (auto& k : keys) {
+      memcpy(bytes + at, k.data(), k.size());
+      at += k.size();
+    }
+  }
+  ~OffsetsBatch() { free(bytes); }
+  std::vector<int32_t> intern(void* h, const uint8_t* valid = nullptr) {
+    uint64_t n = offsets.size() - 1;
+    std::vector<int32_t> ids(n ? n : 1, -7);
+    intern_offsets(h, bytes, offsets.data(), valid, n, ids.data());
+    ids.resize(n);
+    return ids;
+  }
+};
+
+static std::vector<int32_t> intern_fixed(
+    void* h, const std::vector<std::string>& keys, uint32_t w) {
+  size_t n = keys.size();
+  uint8_t* buf = (uint8_t*)calloc(n * w ? n * w : 1, 1);
+  for (size_t i = 0; i < n; i++) {
+    assert(keys[i].size() <= w);
+    memcpy(buf + i * w, keys[i].data(), keys[i].size());
+  }
+  std::vector<int32_t> ids(n ? n : 1, -7);
+  intern_many(h, buf, n, w, ids.data());
+  free(buf);
+  ids.resize(n);
+  return ids;
+}
+
+static std::string key_of_len(size_t len, int salt) {
+  std::string k(len, 'a');
+  for (size_t i = 0; i < len; i++)
+    k[i] = (char)('a' + (i * 7 + (size_t)salt * 5 + len) % 26);
+  return k;
+}
+
 static void test_interner() {
   void* h = intern_create();
   const uint32_t w = 12;
@@ -72,6 +124,8 @@ static void test_interner() {
   }
   intern_many(h, buf.data(), N, w, ids.data());
   assert(intern_count(h) == 7000);
+  // dense, first seen first
+  for (int i = 0; i < 7000; i++) assert(ids[i] == i);
   // stability: same keys → same ids
   std::vector<int32_t> ids2(N);
   intern_many(h, buf.data(), N, w, ids2.data());
@@ -91,6 +145,170 @@ static void test_interner() {
   uint32_t kl = intern_key(h, ids[0], key, sizeof key);
   assert(kl == 2 && memcmp(key, "k0", 2) == 0);
   intern_destroy(h);
+
+  // (a) keys on both sides of the inline width (23) and exactly at it,
+  // (b) through the offsets lane, the fixed-width lane, and read back
+  // from the id store
+  const size_t lens[] = {0, 1, 2, 3, 4, 7, 8, 9, 15, 16, 17, 22,
+                         23, 24, 25, 31, 32, 33, 200};
+  std::vector<std::string> keys;
+  for (size_t len : lens)
+    for (int salt = 0; salt < (len ? 3 : 1); salt++)
+      keys.push_back(key_of_len(len, salt));
+  // same first 23 bytes, different tails: the arena compare decides
+  keys.push_back(key_of_len(40, 0));
+  keys.push_back(key_of_len(40, 0));
+  keys.back()[39] ^= 1;
+  // same bytes, one NUL apart in length and inside: distinct keys
+  keys.push_back(std::string("a\0b", 3));
+  keys.push_back(std::string("a\0\0b", 4));
+  const size_t K = keys.size();
+  h = intern_create();
+  OffsetsBatch ob(keys);
+  std::vector<int32_t> a = ob.intern(h);
+  for (size_t i = 0; i < K; i++) assert(a[i] == (int32_t)i);
+  assert(intern_count(h) == K);
+  assert(ob.intern(h) == a);
+  assert(intern_fixed(h, keys, 200) == a);
+  assert(intern_fixed(h, keys, 233) == a);
+  assert(intern_count(h) == K);
+  // trailing NULs strip in the offsets lane too
+  {
+    std::vector<std::string> padded;
+    for (auto& k : keys) padded.push_back(k + std::string(3, '\0'));
+    assert(OffsetsBatch(padded).intern(h) == a);
+  }
+  // a second interner fed through the fixed-width lane first agrees
+  {
+    void* h2 = intern_create();
+    assert(intern_fixed(h2, keys, 200) == a);
+    assert(ob.intern(h2) == a);
+    intern_destroy(h2);
+  }
+  // the id store: one key at a time, and in bulk
+  for (size_t i = 0; i < K; i++) {
+    std::vector<uint8_t> out(keys[i].size() + 1);
+    uint32_t n = intern_key(h, i, out.data(), (uint32_t)out.size());
+    assert(n == keys[i].size());
+    assert(memcmp(out.data(), keys[i].data(), n) == 0);
+  }
+  {
+    uint8_t* bytes = nullptr;
+    uint64_t* offs = nullptr;
+    assert(intern_keys_range(h, 0, K, &bytes, &offs) == (int64_t)K);
+    for (size_t i = 0; i < K; i++) {
+      assert(offs[i + 1] - offs[i] == keys[i].size());
+      assert(memcmp(bytes + offs[i], keys[i].data(), keys[i].size()) == 0);
+    }
+    intern_free(bytes);
+    intern_free(offs);
+    assert(intern_keys_range(h, 3, 5, &bytes, &offs) == 2);
+    assert(offs[2] == keys[3].size() + keys[4].size());
+    intern_free(bytes);
+    intern_free(offs);
+    assert(intern_keys_range(h, 0, K + 1, &bytes, &offs) == -1);
+  }
+  // NULL rows intern the 0xFF key, whatever bytes sit in their slot
+  {
+    std::vector<std::string> two = {keys[5], keys[5], "\xff"};
+    const uint8_t valid[3] = {1, 0, 1};
+    std::vector<int32_t> v = OffsetsBatch(two).intern(h, valid);
+    assert(v[0] == a[5] && v[1] == (int32_t)K && v[2] == v[1]);
+  }
+  // the tallies: every row counted once, overflow rows are the long ones
+  {
+    uint64_t st[3];
+    intern_stats(h, st);
+    size_t long_keys = 0;
+    for (auto& k : keys) long_keys += k.size() > 23;
+    assert(st[0] == 5 * K + 3);
+    assert(st[2] == 5 * long_keys);
+  }
+  intern_destroy(h);
+
+  // (c) block boundaries: n = 0, 1, one short of a block, a block, one
+  // over, several blocks and a tail — each against a row-at-a-time twin
+  for (size_t n : {0, 1, 15, 16, 17, 33, 100}) {
+    std::vector<std::string> rows;
+    for (size_t i = 0; i < n; i++)
+      rows.push_back(key_of_len(5 + (i * 11) % 30, (int)(i % 9)));
+    void* hb = intern_create();
+    void* h1 = intern_create();
+    std::vector<int32_t> blocked = OffsetsBatch(rows).intern(hb);
+    assert(blocked.size() == n);
+    for (size_t i = 0; i < n; i++) {
+      std::vector<int32_t> one = OffsetsBatch({rows[i]}).intern(h1);
+      assert(one[0] == blocked[i]);
+    }
+    assert(intern_count(hb) == intern_count(h1));
+    assert(intern_fixed(hb, rows, 40) == blocked);
+    intern_destroy(hb);
+    intern_destroy(h1);
+  }
+
+  // (d) a first-seen key repeated inside one block takes the first
+  // occurrence's id, short and long alike
+  {
+    std::string s = key_of_len(9, 1), l = key_of_len(50, 1);
+    std::vector<std::string> rows = {s, l, s, "x", l, s, "y", "x", l};
+    void* hd = intern_create();
+    std::vector<int32_t> got = OffsetsBatch(rows).intern(hd);
+    const int32_t want[] = {0, 1, 0, 2, 1, 0, 3, 2, 1};
+    for (size_t i = 0; i < rows.size(); i++) assert(got[i] == want[i]);
+    assert(intern_count(hd) == 4);
+    intern_destroy(hd);
+  }
+
+  // (e) growth across several doublings (1024 → 32768 slots), keys of
+  // both kinds arriving inside the blocks that grow the table; every id
+  // re-found afterwards, and none taken twice
+  {
+    const int G = 20000;
+    std::vector<std::string> rows;
+    for (int i = 0; i < G; i++) {
+      char tmp[48];
+      int len = i % 3 == 2
+          ? snprintf(tmp, sizeof tmp, "grow-a-rather-long-key-%012d", i)
+          : snprintf(tmp, sizeof tmp, "g%d", i);
+      rows.emplace_back(tmp, (size_t)len);
+    }
+    void* hg = intern_create();
+    OffsetsBatch gb(rows);
+    std::vector<int32_t> first = gb.intern(hg);
+    for (int i = 0; i < G; i++) assert(first[i] == i);
+    assert(intern_count(hg) == (uint64_t)G);
+    assert(gb.intern(hg) == first);
+    assert(intern_fixed(hg, rows, 36) == first);
+    uint64_t st[3];
+    intern_stats(hg, st);
+    assert(st[0] == 3 * (uint64_t)G);
+    assert(st[2] == 3 * (uint64_t)(G / 3));
+    // collision pressure stays that of a table at most 3/4 full
+    assert(st[1] < st[0] * 4);
+    intern_destroy(hg);
+  }
+
+  // (f) keys that differ in their last bytes alone (the top of the last
+  // hashed word) still spread over the table: no probe chain through
+  // all of them
+  for (size_t len : {7, 8, 23, 24, 47}) {
+    std::vector<std::string> rows;
+    for (int i = 0; i < 600; i++) {
+      std::string k(len, 'q');
+      k[len - 1] = (char)('0' + i % 25);
+      k[len - 2] = (char)('0' + i / 25);
+      rows.push_back(k);
+    }
+    void* hc = intern_create();
+    OffsetsBatch cb(rows);
+    cb.intern(hc);
+    cb.intern(hc);
+    uint64_t st[3];
+    intern_stats(hc, st);
+    assert(intern_count(hc) == 600 && st[0] == 1200);
+    assert(st[1] < 2 * st[0]);  // 600 keys in 1024 slots
+    intern_destroy(hc);
+  }
   printf("interner ok\n");
 }
 

@@ -7,9 +7,12 @@ indices so accumulators can be flat vectors.  Here the dense id doubles as the
 row index into the device-resident ``(windows, groups)`` state buffers, so
 interning is the bridge between host strings and HBM tensors.
 
-Vectorized via ``np.unique`` per batch: only first-seen values take the Python
-dict path.  A C++ fast path can replace `_lookup_batch` without changing the
-interface.
+String keys take the native table (``native/interner.cpp``): a
+``StringColumn`` interns straight off its offsets + bytes in one foreign call
+a batch, an object array through the PyObject lane.  Numeric keys and
+environments without a compiler stay in Python: one ``np.unique`` a batch, and
+only first-seen values take the dict path.  Ids are dense, int32 and in
+first-seen order on every path.
 """
 
 from __future__ import annotations
@@ -80,18 +83,19 @@ def _load_native_lib():
                 ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8)),
                 ctypes.POINTER(ctypes.POINTER(ctypes.c_uint64)),
             ]
-            # offsets+bytes lane (StringColumn) — probe for a stale .so
-            # without the symbol; srchash rebuilds make this moot, but a
-            # cheap guard beats an AttributeError mid-stream
-            if hasattr(lib, "intern_offsets"):
-                lib.intern_offsets.argtypes = [
-                    ctypes.c_void_p,
-                    ctypes.c_void_p,  # utf-8 byte buffer
-                    ctypes.POINTER(ctypes.c_uint64),
-                    ctypes.c_void_p,  # validity (u8) or NULL
-                    ctypes.c_uint64,
-                    ctypes.POINTER(ctypes.c_int32),
-                ]
+            # offsets+bytes lane (StringColumn)
+            lib.intern_offsets.argtypes = [
+                ctypes.c_void_p,
+                ctypes.c_void_p,  # utf-8 byte buffer
+                ctypes.c_void_p,  # u64 offsets, n + 1
+                ctypes.c_void_p,  # validity (u8) or NULL
+                ctypes.c_uint64,
+                ctypes.c_void_p,  # int32 ids out, n
+            ]
+            lib.intern_stats.argtypes = [
+                ctypes.c_void_p,
+                ctypes.POINTER(ctypes.c_uint64),  # out[3]
+            ]
             lib.intern_free.argtypes = [ctypes.c_void_p]
             lib._in_configured = True
         return lib
@@ -115,11 +119,10 @@ _NAN_KEY = ("__nan__",)
 class ColumnInterner:
     """value -> id for one column.
 
-    String columns take the native path: the object column is converted to a
-    fixed-width numpy ``S`` array (one vectorized pass) and the raw buffer is
-    hashed by the C++ open-addressing interner — no per-object Python work at
-    steady state.  Numeric columns and environments without a compiler use
-    the np.unique+dict fallback.
+    String columns take the native table: a ``StringColumn`` hands over its
+    offsets + bytes, an object column its ``PyObject*`` slots — no per-row
+    Python work at steady state.  Numeric columns and environments without
+    a compiler use the np.unique+dict fallback.
     """
 
     def __init__(self) -> None:
@@ -209,12 +212,8 @@ class ColumnInterner:
             # slots intern the 0xFF NULL key, the same id the PyObject
             # lane gives None, so a column mixing columnar and legacy
             # batches groups identically.
-            fn = (
-                getattr(self._lib, "intern_offsets", None)
-                if self._h is not None else None
-            )
-            if fn is not None:
-                return self._intern_string_column(arr, fn)
+            if self._h is not None:
+                return self._intern_string_column(arr)
             arr = arr.as_object()  # no native lib: dict fallback below
         if arr.dtype.kind in "ifbM":
             # numeric key column: unique per batch, dict on uniques only
@@ -435,32 +434,45 @@ class ColumnInterner:
             self._to_id_synced = len(values)
         return to_id
 
-    def _intern_string_column(self, col, fn) -> np.ndarray:
+    def _intern_string_column(self, col) -> np.ndarray:
         """offsets+bytes native intern (pinned hot path: one foreign call
         per batch, no per-row Python)."""
-        import ctypes
-
         n = len(col)
         ids = np.empty(n, dtype=np.int32)
         if n == 0:
             return ids
-        offsets = np.ascontiguousarray(col.offsets, dtype=np.uint64)
+        # int64 offsets are never negative: the native side reads the same
+        # buffer as u64, no copy
+        offsets = np.ascontiguousarray(col.offsets, dtype=np.int64)
         data = np.ascontiguousarray(col.data)
-        validity = col.validity
-        vptr = (
-            0 if validity is None
-            else np.ascontiguousarray(validity).ctypes.data
+        # a contiguous copy, where one is made, has to outlive the call
+        validity = (
+            None if col.validity is None
+            else np.ascontiguousarray(col.validity, dtype=np.bool_)
         )
-        fn(
+        self._lib.intern_offsets(
             self._h,
             data.ctypes.data if data.size else 0,
-            offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-            vptr,
+            offsets.ctypes.data,
+            0 if validity is None else validity.ctypes.data,
             n,
-            ids.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+            ids.ctypes.data,
         )
         self._native_active = True
         return ids
+
+    def native_stats(self) -> tuple[int, int, int]:
+        """The native table's tallies since this interner was made: rows
+        interned, slots visited beyond a row's first (collision pressure),
+        rows whose key was too long for a slot and paid the arena compare.
+        Zeros where no native table runs (numeric keys, no compiler)."""
+        if self._h is None:
+            return (0, 0, 0)
+        import ctypes
+
+        out = (ctypes.c_uint64 * 3)()
+        self._lib.intern_stats(self._h, out)
+        return (int(out[0]), int(out[1]), int(out[2]))
 
     def value_of(self, ids: np.ndarray) -> np.ndarray:
         if self._native_active:
@@ -587,6 +599,11 @@ def _dedup_rows(per_col: list[np.ndarray]) -> tuple[list[tuple], np.ndarray]:
     return rows, inv
 
 
+#: keys of :meth:`GroupInterner.stats`, in the order of
+#: :meth:`ColumnInterner.native_stats`
+INTERN_STATS = ("intern_rows", "intern_extra_probes", "intern_overflow_rows")
+
+
 class GroupInterner:
     """Composite (multi-column) key -> dense group id.
 
@@ -632,6 +649,12 @@ class GroupInterner:
                 self._gid_rows.append(row)
             gids_for_uniq[i] = g
         return gids_for_uniq[inv]
+
+    def stats(self) -> dict[str, int]:
+        """The key columns' native tallies, summed (docs/observability.md,
+        Spans: a row counts once per string key column)."""
+        per_col = [it.native_stats() for it in self._col_interners]
+        return dict(zip(INTERN_STATS, map(sum, zip(*per_col))))
 
     def keys_of(self, gids: np.ndarray) -> list[np.ndarray]:
         """Reconstruct each key column's values for the given group ids."""

@@ -45,7 +45,7 @@ from denormalized_tpu.common.schema import DataType, Field, Schema
 from denormalized_tpu.logical.expr import AggregateExpr, Expr
 from denormalized_tpu.logical.plan import WindowType
 from denormalized_tpu.ops import segment_agg as sa
-from denormalized_tpu.ops.interner import GroupInterner
+from denormalized_tpu.ops.interner import INTERN_STATS, GroupInterner
 from denormalized_tpu.physical.base import (
     EOS,
     WM_ANNOUNCE,
@@ -606,6 +606,12 @@ class StreamingWindowExec(ExecOperator):
         ms = self._phases.ms
         for key in WINDOW_PHASES:
             m[f"phase_ms_{key}"] = ms.get(key, 0.0)
+        # what the intern phase's native table did: intern_rows,
+        # intern_extra_probes, intern_overflow_rows (0 when ungrouped)
+        m.update(
+            self._interner.stats() if self._interner is not None
+            else dict.fromkeys(INTERN_STATS, 0)
+        )
         # what 'auto' actually chose AND what actually dispatched (a
         # report must RECORD the resolved strategy, not just the
         # request) — each backend labels itself

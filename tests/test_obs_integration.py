@@ -76,6 +76,8 @@ WINDOW_KEYS = {
     "phase_ms_reduce", "phase_ms_acc_wait", "phase_ms_update",
     "phase_ms_trigger", "phase_ms_flush", "phase_ms_gather",
     "phase_ms_d2h_wait", "phase_ms_finalize", "phase_ms_other",
+    # the native interner's tallies (docs/observability.md, Spans)
+    "intern_rows", "intern_extra_probes", "intern_overflow_rows",
 }
 SESSION_KEYS = {
     "rows_in", "sessions_emitted", "late_rows", "salvage_rows_scanned",
