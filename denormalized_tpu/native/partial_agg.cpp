@@ -21,10 +21,11 @@
 
 #include <cstdint>
 #include <cmath>
+#include <cstring>
 
 extern "C" {
 
-// One pass over n rows.  Arrays are dense C-order:
+// One pass over n rows.  Inputs are dense C-order:
 //   win_rel: (n) int64  — slide-unit index rebased to the stripe window;
 //            rows outside [0, U) are skipped (late / overflow, the caller
 //            pre-rebased against u_lo)
@@ -32,12 +33,22 @@ extern "C" {
 //   gid:     (n) int32  — dense group ids in [0, G)
 //   values:  (n, V) f64 — value matrix (row-major)
 //   colvalid:(n, V) uint8 or NULL — per-cell validity; NULL = all valid
-// Outputs (all (U * SUB * G) flat, indexed ((u*SUB)+s)*G+g):
-//   row_cnt: int64  — rows per cell (count(*))
-//   cnt:     (V, U*SUB*G) int64 — valid values per cell per column
-//   sum:     (V, U*SUB*G) f64
-//   mn:      (V, U*SUB*G) f64 (caller inits to +inf)
-//   mx:      (V, U*SUB*G) f64 (caller inits to -inf)
+// Output: rec, (U * SUB * G, 1 + 4 * V) f64 — ONE RECORD PER CELL, cell
+//   ((u*SUB)+s)*G+g, fields
+//     [0]            rows in the cell (count(*))
+//     [1 + 4*v + 0]  valid values of column v
+//     [1 + 4*v + 1]  their sum
+//     [1 + 4*v + 2]  their min (caller inits to +inf)
+//     [1 + 4*v + 3]  their max (caller inits to -inf)
+//   Counts are f64 (exact far beyond a stripe's 2^24 rows).  A record is
+//   40 bytes for one column: a row costs one cache line where five
+//   parallel planes cost five — at ten million groups every one a miss —
+//   and the cells of the next rows are prefetched while this one folds.
+//   touched: int64 — the flat index of every cell whose row count this call
+//            raised from 0, appended at touched[*n_touched] in the order met
+//            (the caller keeps room for n more).  Between two resets the
+//            list holds each written cell once: packing and resetting a
+//            stripe then cost what it touched, not what it could hold.
 // Returns number of rows folded (excludes skipped).
 int64_t partial_window_agg(
     const int64_t* win_rel,
@@ -50,35 +61,101 @@ int64_t partial_window_agg(
     int32_t U,
     int32_t SUB,
     int32_t G,
-    int64_t* row_cnt,
-    int64_t* cnt,
-    double* sum,
-    double* mn,
-    double* mx) {
-  const int64_t cells = (int64_t)U * SUB * G;
+    double* rec,
+    int64_t* touched,
+    int64_t* n_touched) {
+  const int64_t R = 1 + 4 * (int64_t)V;
+  constexpr int64_t AHEAD = 16;
   int64_t folded = 0;
-  for (int64_t i = 0; i < n; ++i) {
+  int64_t nt = *n_touched;
+  auto cell_of = [&](int64_t i) -> int64_t {
     const int64_t u = win_rel[i];
-    if (u < 0 || u >= U) continue;
     const int32_t g = gid[i];
-    if (g < 0 || g >= G) continue;
+    if (u < 0 || u >= U || g < 0 || g >= G) return -1;
     const int32_t s = sub ? (int32_t)sub[i] : 0;
-    const int64_t cell = ((u * SUB) + s) * G + g;
-    ++row_cnt[cell];
+    return ((u * SUB) + s) * G + g;
+  };
+  for (int64_t i = 0; i < n; ++i) {
+    if (i + AHEAD < n) {
+      const int64_t ahead = cell_of(i + AHEAD);
+      if (ahead >= 0) {  // a record may straddle two lines
+        __builtin_prefetch(rec + ahead * R, 1);
+        __builtin_prefetch(rec + ahead * R + R - 1, 1);
+      }
+    }
+    const int64_t cell = cell_of(i);
+    if (cell < 0) continue;
+    double* r = rec + cell * R;
+    if (r[0] == 0.0) touched[nt++] = cell;
+    r[0] += 1.0;
     ++folded;
     for (int32_t v = 0; v < V; ++v) {
       if (colvalid && !colvalid[i * V + v]) continue;
       const double x = values[i * V + v];
-      const int64_t off = (int64_t)v * cells + cell;
-      ++cnt[off];
-      sum[off] += x;
+      double* f = r + 1 + 4 * v;
+      f[0] += 1.0;
+      f[1] += x;
       // NaN propagates (parity with the device scatter path and numpy
       // fallback): a plain `x < mn` comparison would silently skip NaN
-      if (x != x || x < mn[off]) mn[off] = x;
-      if (x != x || x > mx[off]) mx[off] = x;
+      if (x != x || x < f[2]) f[2] = x;
+      if (x != x || x > f[3]) f[3] = x;
     }
   }
+  *n_touched = nt;
   return folded;
+}
+
+// Pack and reset the active cells of ONE slide unit in one pass over
+// their records (host_partial.py, compact layout): for cell i of the n
+// ascending flat indices `cells`, write
+//   packed[0][i]              = cells[i] - cell_base   (index in the unit)
+//   packed[1 + row][i]        = the record's fields as f32 bit patterns —
+//     field fields[k] for each of the n_fields planes walked; a plane with
+//     split[k] != 0 is a sum and takes TWO rows, (hi, lo) with
+//     hi = f32(x), lo = f32(x - hi): the f64 host sum survives transit;
+//     a sum that is not finite in f32 ships lo = 0 (inf - inf would be NaN)
+// then put the record back to `neutral`.  `stride` is packed's row length
+// in int32s.  Returns how many finite f64 sums overflowed f32 (the caller
+// refuses them for f64 accumulators).
+int64_t partial_pack_cells(
+    const int64_t* cells,
+    int64_t n,
+    int64_t cell_base,
+    double* rec,
+    int32_t R,
+    const int32_t* fields,
+    const uint8_t* split,
+    int32_t n_fields,
+    int32_t* packed,
+    int64_t stride,
+    const double* neutral) {
+  constexpr int64_t AHEAD = 16;
+  int64_t overflowed = 0;
+  auto bits = [](float f) { int32_t b; std::memcpy(&b, &f, 4); return b; };
+  for (int64_t i = 0; i < n; ++i) {
+    if (i + AHEAD < n) {
+      __builtin_prefetch(rec + cells[i + AHEAD] * R, 1);
+      __builtin_prefetch(rec + cells[i + AHEAD] * R + R - 1, 1);
+    }
+    double* r = rec + cells[i] * R;
+    packed[i] = (int32_t)(cells[i] - cell_base);
+    int64_t row = 1;
+    for (int32_t k = 0; k < n_fields; ++k) {
+      const double x = r[fields[k]];
+      const float hi = (float)x;
+      packed[row++ * stride + i] = bits(hi);
+      if (split[k]) {
+        float lo = (float)(x - (double)hi);
+        if (!std::isfinite(hi)) {
+          lo = 0.0f;
+          if (std::isfinite(x)) ++overflowed;
+        }
+        packed[row++ * stride + i] = bits(lo);
+      }
+    }
+    for (int32_t f = 0; f < R; ++f) r[f] = neutral[f];
+  }
+  return overflowed;
 }
 
 }  // extern "C"

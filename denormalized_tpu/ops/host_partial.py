@@ -27,6 +27,7 @@ import ctypes
 import numpy as np
 
 from denormalized_tpu.ops import segment_agg as sa
+from denormalized_tpu.runtime.tracing import NULL_CLOCK
 
 _LIB = None
 _LIB_TRIED = False
@@ -52,11 +53,23 @@ def _native():
                 ctypes.c_int32,   # U
                 ctypes.c_int32,   # SUB
                 ctypes.c_int32,   # G
-                ctypes.c_void_p,  # row_cnt int64
-                ctypes.c_void_p,  # cnt int64
-                ctypes.c_void_p,  # sum f64
-                ctypes.c_void_p,  # mn f64
-                ctypes.c_void_p,  # mx f64
+                ctypes.c_void_p,  # rec f64 (cells, 1 + 4V)
+                ctypes.c_void_p,  # touched int64
+                ctypes.c_void_p,  # n_touched int64[1]
+            ]
+            lib.partial_pack_cells.restype = ctypes.c_int64
+            lib.partial_pack_cells.argtypes = [
+                ctypes.c_void_p,  # cells int64
+                ctypes.c_int64,   # n
+                ctypes.c_int64,   # cell_base
+                ctypes.c_void_p,  # rec f64
+                ctypes.c_int32,   # R
+                ctypes.c_void_p,  # fields int32
+                ctypes.c_void_p,  # split uint8
+                ctypes.c_int32,   # n_fields
+                ctypes.c_void_p,  # packed int32
+                ctypes.c_int64,   # stride
+                ctypes.c_void_p,  # neutral f64
             ]
             _LIB = lib
         except Exception as e:  # dnzlint: allow(broad-except) numpy partial-agg is the designed fallback on no-compiler boxes; logged so the downgrade is visible, gated by test_native_build_gate where g++ exists
@@ -92,16 +105,51 @@ class HostPartialStripe:
     ``u_base .. u_base + U - 1``.  ``SUB`` is 2 when ``length % slide != 0``
     (rows near the end of a unit belong to one fewer window — see
     partial_agg.cpp), else 1.
+
+    The partials live as ONE RECORD PER CELL (``rec``, a row of ``1 + 4V``
+    f64: rows; per value column valid count, sum, min, max — the layout of
+    partial_agg.cpp), so a row folds into one cache line, and what a flush
+    costs follows the cells the stripe touched, not the ``G`` it could
+    hold: the reducer notes every cell it writes for the first time
+    (``_touched``); packing gathers those records and the reset rewrites
+    them, and nothing else of the stripe is read or written.
     """
 
-    # stripe capacity in slide units; a span wider than this forces a flush
+    # most slide units a stripe spans; a wider batch forces a flush
     U_MAX = 16
+    # counts per cell are shipped as exact-in-f32 integers, so a stripe
+    # may never exceed 2^24 rows between merges (backend flushes earlier)
+    MAX_STRIPE_ROWS = 1 << 24
+    # cap on the U*SUB*G cells a stripe allocates (40 bytes a cell and
+    # value column: 20 MB at the cap), one slide unit at the least: host
+    # memory follows the units a stripe really spans, not U_MAX units of
+    # any G
+    MAX_STRIPE_CELLS = 1 << 19
+    # smallest padded transfer: below it a merge costs its dispatch
+    MIN_BUCKET = 1024
+    # the touched cells are put in order by a sort, or — from one touched
+    # cell in MAP_RATIO of the span — by marking a byte a cell and reading
+    # the marks back (a byte a cell of the span against ~log2(A) compares
+    # a touched cell)
+    MAP_RATIO = 256
+    # header unit of a pack that must fold into nothing (prewarm no-ops, the
+    # padding of the stack of a flush's dense units): far below any ring
+    NOOP_UNIT = -(1 << 24)
+    F64_OVERFLOW = (
+        "partial_merge cannot transport f64 sums beyond float32 range "
+        "(~3.4e38); use device_strategy='scatter' for this workload"
+    )
 
     def __init__(self, spec: sa.WindowKernelSpec, group_capacity: int):
         self.spec = spec
         self.G = group_capacity
         self.V = max(spec.num_value_cols, 1)
         self.SUB = 1 if spec.length_ms % spec.slide_ms == 0 else 2
+        self.unit_cells = self.SUB * self.G
+        self.U = max(
+            1, min(self.U_MAX, self.MAX_STRIPE_CELLS // self.unit_cells)
+        )
+        self._buckets = self.buckets_for(self.unit_cells)
         self.u_base: int | None = None
         self.u_hi = 0  # highest stripe-relative unit written (span - 1)
         self.rows = 0
@@ -110,15 +158,38 @@ class HostPartialStripe:
         # to the row-count plane — valid because no-null means they are
         # equal) and the full layout
         self.nulls_seen = False
-        self._alloc()
+        # work counters (docs/observability.md): cells with rows / cells
+        # sent (padding included) / host bytes scanned and rewritten by
+        # pack and reset, all summed over the stripes taken so far
+        self.cells_active = 0
+        self.cells_shipped = 0
+        self.bytes_touched = 0
+        # a cell without rows holds the fold-neutral record
+        self._neutral = np.array(
+            [0.0] + [0.0, 0.0, np.inf, -np.inf] * self.V
+        )
+        self.rec = np.empty(
+            (self.U * self.unit_cells, len(self._neutral)), np.float64
+        )
+        self.rec[:] = self._neutral
+        # flat indices of the cells written since the last reset, each
+        # once, in the order first met; grown to hold a batch more
+        self._touched = np.empty(1 << 16, np.int64)
+        self._n_touched = np.zeros(1, np.int64)
+        self._dirty: list = []  # (index or slice, cells) still to reset
 
-    def _alloc(self):
-        U, S, G, V = self.U_MAX, self.SUB, self.G, self.V
-        self.row_cnt = np.zeros((U, S, G), np.int64)
-        self.cnt = np.zeros((V, U, S, G), np.int64)
-        self.sum = np.zeros((V, U, S, G), np.float64)
-        self.mn = np.full((V, U, S, G), np.inf)
-        self.mx = np.full((V, U, S, G), -np.inf)
+    #: the work counters above, as ``metrics()`` surfaces them (``stripe_<name>``)
+    COUNTERS = ("cells_active", "cells_shipped", "bytes_touched")
+
+    def carry_counters(self, old: "HostPartialStripe") -> None:
+        """Take over the counts of the stripe this one replaces (capacity
+        growth, restore), so they stay sums over the operator's life."""
+        for name in self.COUNTERS:
+            setattr(self, name, getattr(self, name) + getattr(old, name))
+
+    def host_bytes(self) -> int:
+        """Bytes of host memory the stripe's records hold."""
+        return self.rec.nbytes
 
     # -- ingestion -----------------------------------------------------
     def add_batch(
@@ -157,6 +228,11 @@ class HostPartialStripe:
             # window (see partial_agg.cpp header)
             edge = self.spec.length_ms - (self.spec.length_units - 1) * self.spec.slide_ms
             sub = (np.asarray(rem) >= edge).astype(np.uint8)
+        need = int(self._n_touched[0]) + n
+        if need > len(self._touched):
+            grown = np.empty(max(need, 2 * len(self._touched)), np.int64)
+            grown[: self._n_touched[0]] = self._touched[: self._n_touched[0]]
+            self._touched = grown
         lib = _native()
         if lib is not None:
             rel = np.ascontiguousarray(rel, np.int64)
@@ -169,207 +245,269 @@ class HostPartialStripe:
             )
             lib.partial_window_agg(
                 _ptr(rel), _ptr(sub), _ptr(gid_c), _ptr(vals_c), _ptr(cv),
-                n, self.V, self.U_MAX, self.SUB, self.G,
-                _ptr(self.row_cnt), _ptr(self.cnt), _ptr(self.sum),
-                _ptr(self.mn), _ptr(self.mx),
+                n, self.V, self.U, self.SUB, self.G, _ptr(self.rec),
+                _ptr(self._touched), _ptr(self._n_touched),
             )
         else:
             self._add_numpy(rel, sub, gid, values64, colvalid)
         self.rows += n
 
     def _add_numpy(self, rel, sub, gid, values64, colvalid):
-        """Vectorized fallback: bincount for counts/sums, sort+reduceat for
-        extrema."""
-        ok = (rel >= 0) & (rel < self.U_MAX) & (gid >= 0) & (gid < self.G)
+        """Vectorized fallback: one stable sort of the batch by cell, then
+        ``reduceat`` per field over each cell's run of rows."""
+        ok = (rel >= 0) & (rel < self.U) & (gid >= 0) & (gid < self.G)
         rel = rel[ok]
         gid = np.asarray(gid)[ok]
         vals = values64[ok]
         s = (sub[ok].astype(np.int64) if sub is not None else 0)
         cell = (rel * self.SUB + s) * self.G + gid
-        cells = self.U_MAX * self.SUB * self.G
-        self.row_cnt.reshape(-1)[:] += np.bincount(cell, minlength=cells)
-        cv = colvalid[ok] if colvalid is not None else None
         order = np.argsort(cell, kind="stable")
         cell_s = cell[order]
+        starts = np.flatnonzero(np.r_[True, cell_s[1:] != cell_s[:-1]])
+        uc = cell_s[starts]  # the batch's distinct cells, ascending
+        rec = self.rec
+        fresh = uc[rec[uc, 0] == 0]
+        nt = int(self._n_touched[0])
+        self._touched[nt : nt + len(fresh)] = fresh
+        self._n_touched[0] = nt + len(fresh)
+        rec[uc, 0] += np.diff(np.r_[starts, len(cell_s)])
+        cv = colvalid[ok] if colvalid is not None else None
         for v in range(self.V):
-            x = vals[:, v]
-            m = cv[:, v] if cv is not None else None
-            cm = cell if m is None else cell[m]
-            xm = x if m is None else x[m]
-            self.cnt[v].reshape(-1)[:] += np.bincount(cm, minlength=cells)
-            self.sum[v].reshape(-1)[:] += np.bincount(
-                cm, weights=xm, minlength=cells
-            )
-            xs = x[order]
-            ms = None if m is None else m[order]
-            if ms is not None:
-                cs2, xs2 = cell_s[ms], xs[ms]
+            xs = vals[:, v][order]
+            if cv is None:
+                cs2, xs2, st2, uc2 = cell_s, xs, starts, uc
             else:
-                cs2, xs2 = cell_s, xs
-            if len(cs2):
-                starts = np.flatnonzero(np.r_[True, cs2[1:] != cs2[:-1]])
-                mins = np.minimum.reduceat(xs2, starts)
-                maxs = np.maximum.reduceat(xs2, starts)
-                uc = cs2[starts]
-                flat_mn = self.mn[v].reshape(-1)
-                flat_mx = self.mx[v].reshape(-1)
-                flat_mn[uc] = np.minimum(flat_mn[uc], mins)
-                flat_mx[uc] = np.maximum(flat_mx[uc], maxs)
+                ms = cv[:, v][order]
+                cs2, xs2 = cell_s[ms], xs[ms]
+                st2 = np.flatnonzero(np.r_[True, cs2[1:] != cs2[:-1]])
+                uc2 = cs2[st2]
+            if len(cs2) == 0:
+                continue
+            f = 1 + 4 * v
+            rec[uc2, f] += np.diff(np.r_[st2, len(cs2)])
+            rec[uc2, f + 1] += np.add.reduceat(xs2, st2)
+            rec[uc2, f + 2] = np.minimum(
+                rec[uc2, f + 2], np.minimum.reduceat(xs2, st2)
+            )
+            rec[uc2, f + 3] = np.maximum(
+                rec[uc2, f + 3], np.maximum.reduceat(xs2, st2)
+            )
 
     # -- hand-off ------------------------------------------------------
     def is_empty(self) -> bool:
         return self.rows == 0
 
-    def _component_plane(self, c: sa.AggComponent) -> np.ndarray:
+    def _field(self, c: sa.AggComponent) -> int:
+        """Index of a component's field in a cell's record."""
         if c.kind == "count" and c.col is None:
-            return self.row_cnt
-        if c.kind == "count":
-            return self.cnt[c.col]
-        if c.kind == "sum":
-            return self.sum[c.col]
-        if c.kind == "min":
-            return self.mn[c.col]
-        if c.kind == "max":
-            return self.mx[c.col]
-        raise ValueError(c.kind)
+            return 0
+        return 1 + 4 * c.col + ("count", "sum", "min", "max").index(c.kind)
 
-    # counts per cell are shipped as exact-in-f32 integers, so a stripe
-    # may never exceed 2^24 rows between merges (backend flushes earlier)
-    MAX_STRIPE_ROWS = 1 << 24
-    # cap on U*SUB*G cells per stripe: bounds the compacted-transfer
-    # bucket so high-cardinality stripes converge on ONE compiled merge
-    # program instead of walking a ladder of pow2 sizes
-    MAX_STRIPE_CELLS = 1 << 19
-
-    def transfer_buckets(self) -> list[int]:
-        """The FIXED set of padded transfer sizes this stripe will ever
-        use: {1024, bound/4, bound/2, bound} (deduped, pow2) where bound
-        covers the largest possible active-cell count.  A fixed spec-
-        derived set — instead of pow2-of-observed-A — means every merge
-        program can be compiled at construction: observed sizes vary with
-        pacing, and an unseen size mid-stream is a multi-second compile."""
-        # at least one slide unit's worth of cells: the backend chunks
-        # batches so a stripe never exceeds max(one unit, the cell cap)
-        bound_cells = min(
-            max(self.MAX_STRIPE_CELLS, self.G * self.SUB),
-            self.G * self.SUB * self.U_MAX,
-        )
-        bound = 1 << max(0, (bound_cells - 1)).bit_length()
-        out = sorted({1024, max(1024, bound // 4), max(1024, bound // 2), bound})
+    @classmethod
+    def buckets_for(cls, unit_cells: int) -> list[int]:
+        out, b = [], cls.MIN_BUCKET
+        while b < unit_cells:
+            out.append(b)
+            b *= 2
         return out
 
-    def take_packed(
-        self, base_mod: int
-    ) -> tuple[np.ndarray, int, int, bool, bool] | None:
-        """Compact the stripe into the single int32 matrix the device
-        merge op consumes, then reset.
+    def transfer_buckets(self) -> list[int]:
+        """Every padded size a compact pack of this stripe can have: the
+        powers of two from ``MIN_BUCKET`` up to the last one below a slide
+        unit's cells (a pack is of ONE unit, and one that would need a
+        bucket as wide as the unit goes dense — fewer bytes, see
+        ``take_packed``).  A set fixed by the spec, so every merge program
+        is compiled at construction: the sizes a run meets vary with its
+        pacing, and an unseen one mid-stream is a compile.  Padding is
+        under two cells a cell above ``MIN_BUCKET``."""
+        return list(self._buckets)
 
-        Returns ``(packed, a_pad, u_base, lean, dense)`` or None when
-        empty — ``lean`` says per-column count planes were omitted
-        (null-free stripe; the device merge aliases them to the row-count
-        plane).  ``packed`` is **int32** — an int32 carrier is immune to
-        jnp's x64-off canonicalization, which would silently round an f64
-        matrix to f32 and corrupt cell indices beyond 2^24.  Value planes
-        are f32 bitcast to int32: one plane per count/min/max component
-        (counts are exact in f32 under the MAX_STRIPE_ROWS cap) and TWO
-        planes per sum — the f64 host sum split into (hi, lo) f32 so no
-        precision is lost in transit.  ``u_base`` and ``base_mod`` ride in
-        the two tail slots of row 0.  One matrix → ONE host→device
-        transfer per merge.
+    def layout_for(self, A: int, n_planes: int) -> tuple[int, bool]:
+        """``(a_pad, dense)`` of a unit with ``A`` active cells: the
+        compact bucket that covers them, or the dense layout (``a_pad`` =
+        the unit's cells) where that moves fewer bytes — the index row
+        counted — or no bucket covers them."""
+        a_pad = next((b for b in self._buckets if b >= A), None)
+        if a_pad is None or (
+            n_planes * self.unit_cells < (n_planes + 1) * a_pad
+        ):
+            return self.unit_cells, True
+        return a_pad, False
 
-        Two layouts, chosen per stripe by exact transferred-byte count:
-
-        * **compact** (``dense=False``): ``(P + 1, a_pad + 2)`` — row 0
-          holds the active flat cell indices ``((u*SUB)+s)*G + g``
-          (pad = −1), value planes follow.  Wins when active cells are
-          sparse in the stripe's span.
-        * **dense** (``dense=True``): ``(P, a_pad + 2)`` — NO index row;
-          cell i is flat index i over the first ``used`` units, pad cells
-          carry fold-neutral values (count 0, sum 0, min +inf, max −inf).
-          Wins at high density (e.g. 100K live keys in a 131072-wide
-          ring: 4 planes × active vs 3 planes × span), and skips the
-          host-side gather entirely."""
-        if self.rows == 0:
-            return None
-        used = self.u_hi + 1
-        active = np.flatnonzero(self.row_cnt[:used].reshape(-1) > 0)
-        A = len(active)
-        # lean layout: a null-free stripe's per-column counts equal the
-        # row count cell-for-cell, so their planes need not cross the
-        # link — the device merge aliases them to the row-count plane
-        lean = not self.nulls_seen and sa.lean_possible(self.spec)
-        n_planes = self.n_planes(lean)
-        # smallest member of the FIXED bucket set that covers A (see
-        # transfer_buckets — all merge programs precompiled); the backend's
-        # chunking keeps A within the largest bucket, but never crash the
-        # stream if an invariant slips — pay a one-off compile instead
-        buckets = self.transfer_buckets()
-        a_pad = next(
-            (b for b in buckets if b >= A),
-            1 << (A - 1).bit_length(),
-        )
-        cells_d = used * self.SUB * self.G
-        a_pad_d = next((b for b in buckets if b >= cells_d), None)
-        # dense only when a precompiled bucket covers the span AND it
-        # moves fewer bytes than compact (index row included)
-        if a_pad_d is not None and n_planes * a_pad_d < (n_planes + 1) * a_pad:
-            return self._take_packed_dense(
-                base_mod, used, a_pad_d, lean, n_planes
-            )
-        rows: list[np.ndarray] = []
+    def _planes_walk(self, lean: bool):
+        """(component, plane index) of the packed value planes, in order:
+        the one walk the real packs and the prewarm no-ops share."""
+        pi = 0
         for c in self.spec.components:
-            if c.kind == "sumc":
+            if c.kind == "sumc" or (lean and sa.lean_skippable(c)):
                 continue
-            if lean and sa.lean_skippable(c):
-                continue
-            src = self._component_plane(c)[:used].reshape(-1)[active]
-            if c.kind == "sum":
-                hi, lo = self._split_sum(src)
-                rows.append(hi)
-                rows.append(lo)
-            else:
-                rows.append(
-                    np.ascontiguousarray(src, np.float64)
-                    .astype(np.float32)
-                    .view(np.int32)
-                )
-        packed = np.zeros((len(rows) + 1, a_pad + 2), np.int32)
-        packed[0, :A] = active
-        packed[0, A:a_pad] = -1
-        packed[0, a_pad] = self.u_base
-        packed[0, a_pad + 1] = base_mod
-        for i, r in enumerate(rows):
-            packed[i + 1, :A] = r
-        u_base = self._reset_after_take(used)
-        return packed, a_pad, u_base, lean, False
+            yield c, pi
+            pi += 2 if c.kind == "sum" else 1
 
     def n_planes(self, lean: bool) -> int:
         """Value planes in a packed stripe of this spec: two per sum
         (hi/lo split), one per other component; lean omits per-column
         count planes (aliased to row count device-side)."""
         return sum(
-            2 if c.kind == "sum" else 1
-            for c in self.spec.components
-            if c.kind != "sumc" and not (lean and sa.lean_skippable(c))
+            2 if c.kind == "sum" else 1 for c, _ in self._planes_walk(lean)
         )
 
-    def dense_noop(self, a_pad: int, lean: bool) -> np.ndarray:
-        """An all-padding DENSE packed matrix (for merge-program prewarm):
-        every cell fold-neutral — count/sum planes zero, min/max planes
-        +inf/−inf bit patterns.  Must stay in lockstep with
-        ``_take_packed_dense``'s plane order (it is derived from the same
-        component walk)."""
-        packed = np.zeros((self.n_planes(lean), a_pad + 2), np.int32)
-        pi = 0
-        for c in self.spec.components:
-            if c.kind == "sumc" or (lean and sa.lean_skippable(c)):
+    def take_packed(
+        self, base_mod: int, clock=NULL_CLOCK
+    ) -> list[tuple[np.ndarray, int, bool, bool]]:
+        """Pack the stripe for the device merge op, one int32 matrix per
+        slide unit with rows, then reset it (the native pack puts each
+        record back to neutral in the pass that packs it).  ``clock`` (the
+        operator's phase clock) gets both as ``flush_pack``.
+
+        Returns ``[(packed, a_pad, lean, dense), ...]`` in unit order, empty
+        for an empty stripe — ``lean`` says per-column count planes were
+        omitted (null-free stripe; the device merge aliases them to the
+        row-count plane).  ``packed`` is **int32** — an int32 carrier is
+        immune to jnp's x64-off canonicalization, which would silently
+        round an f64 matrix to f32.  Value planes are f32 bitcast to
+        int32: one plane per count/min/max component (counts are exact in
+        f32 under the MAX_STRIPE_ROWS cap) and TWO planes per sum — the
+        f64 host sum split into (hi, lo) f32 so no precision is lost in
+        transit.  The unit's index (relative to ``first_open``, as the
+        operator handed units in) and ``base_mod`` ride in the two tail
+        slots of row 0.  One matrix per unit; the backend sends each compact
+        one on its own and a flush's dense ones stacked in one call.
+
+        Two layouts, chosen per unit by transferred bytes:
+
+        * **compact** (``dense=False``): ``(P + 1, a_pad + 2)`` — row 0
+          holds the active cells' indices in the unit, ``s*G + g``,
+          ascending (pad = −1), value planes follow; ``a_pad`` is the
+          bucket of ``transfer_buckets`` that covers them.
+        * **dense** (``dense=True``): ``(P, SUB*G + 2)`` — NO index row;
+          cell i is index i of the unit, cells without rows carry
+          fold-neutral values (count 0, sum 0, min +inf, max −inf).  Wins
+          once most of a unit is active (e.g. 100K live keys in a ring
+          131,072 wide: 5 planes × 131,072 against 6 × 131,072), and the
+          device folds it without a scatter."""
+        if self.rows == 0:
+            return []
+        with clock.phase("flush_pack"):
+            out = self._pack(base_mod)
+            self._reset()
+        return out
+
+    def _active_cells(self) -> np.ndarray:
+        """Flat indices of the cells with rows — the touched list, put in
+        ascending order."""
+        nt = int(self._n_touched[0])
+        cells = self._touched[:nt]
+        span = (self.u_hi + 1) * self.unit_cells
+        if nt * self.MAP_RATIO <= span:
+            self.bytes_touched += nt * 8
+            return np.sort(cells)
+        marks = np.zeros(span, np.bool_)
+        marks[cells] = True
+        self.bytes_touched += nt * 8 + span
+        return np.flatnonzero(marks)
+
+    def _pack(self, base_mod: int) -> list[tuple[np.ndarray, int, bool, bool]]:
+        used = self.u_hi + 1
+        active = self._active_cells()
+        self._dirty = []  # what _reset still has to put back to neutral
+        # lean layout: a null-free stripe's per-column counts equal the
+        # row count cell-for-cell, so their planes need not cross the
+        # link — the device merge aliases them to the row-count plane
+        lean = not self.nulls_seen and sa.lean_possible(self.spec)
+        n_planes = self.n_planes(lean)
+        cells = self.unit_cells
+        bounds = np.searchsorted(active, np.arange(used + 1) * cells)
+        out = []
+        for u in range(used):
+            lo, hi = int(bounds[u]), int(bounds[u + 1])
+            A = hi - lo
+            if A == 0:
                 continue
-            if c.kind == "sum":
-                pi += 2
-                continue
+            a_pad, dense = self.layout_for(A, n_planes)
+            if dense:
+                packed = self._pack_dense(u, lean, n_planes)
+            else:
+                packed = self._pack_compact(
+                    u, active[lo:hi], a_pad, lean, n_planes
+                )
+            packed[0, a_pad] = self.u_base + u
+            packed[0, a_pad + 1] = base_mod
+            self.cells_active += A
+            self.cells_shipped += a_pad
+            out.append((packed, a_pad, lean, dense))
+        return out
+
+    def _f32_bits(self, c: sa.AggComponent, src: np.ndarray) -> list[np.ndarray]:
+        """A component's cells as the int32-bitcast f32 rows it ships as:
+        (hi, lo) for a sum, one row otherwise."""
+        if c.kind == "sum":
+            return list(self._split_sum(src))
+        return [src.astype(np.float32).view(np.int32)]
+
+    def _pack_compact(self, u, cells_u, a_pad, lean, n_planes) -> np.ndarray:
+        A = len(cells_u)
+        packed = np.empty((n_planes + 1, a_pad + 2), np.int32)
+        packed[:, A:] = 0
+        packed[0, A:a_pad] = -1
+        walk = list(self._planes_walk(lean))
+        lib = _native()
+        if lib is not None:
+            # one pass over the records: pack them and put them back to
+            # neutral (nothing is left for _reset to do for these cells)
+            fields = np.array([self._field(c) for c, _ in walk], np.int32)
+            split = np.array([c.kind == "sum" for c, _ in walk], np.uint8)
+            overflowed = lib.partial_pack_cells(
+                _ptr(cells_u), A, u * self.unit_cells, _ptr(self.rec),
+                self.rec.shape[1], _ptr(fields), _ptr(split), len(walk),
+                _ptr(packed), packed.shape[1], _ptr(self._neutral),
+            )
+            if overflowed and self.spec.accum_dtype == sa.jnp.float64:
+                raise OverflowError(self.F64_OVERFLOW)
+            self.bytes_touched += 2 * A * self.rec.shape[1] * 8
+            return packed
+        packed[0, :A] = cells_u - u * self.unit_cells
+        recs = self.rec[cells_u]  # one gather: the active records
+        for c, pi in walk:
+            for k, row in enumerate(self._f32_bits(c, recs[:, self._field(c)])):
+                packed[1 + pi + k, :A] = row
+        self.bytes_touched += recs.nbytes
+        self._dirty.append((cells_u, A))
+        return packed
+
+    def _pack_dense(self, u, lean, n_planes) -> np.ndarray:
+        """Dense (index-free) pack of unit ``u``: plane p at row p, cell i
+        = index i of the unit.  No host gather — a strided read of each
+        field; cells without rows already hold the fold-neutral values."""
+        cells = self.unit_cells
+        unit = self.rec[u * cells : (u + 1) * cells]
+        packed = np.empty((n_planes, cells + 2), np.int32)
+        packed[:, cells:] = 0
+        for c, pi in self._planes_walk(lean):
+            for k, row in enumerate(self._f32_bits(c, unit[:, self._field(c)])):
+                packed[pi + k, :cells] = row
+        self.bytes_touched += unit.nbytes
+        self._dirty.append((slice(u * cells, (u + 1) * cells), cells))
+        return packed
+
+    def dense_noop(self, lean: bool) -> np.ndarray:
+        """An all-neutral DENSE packed matrix addressed to no window (merge
+        prewarm, padding of a stack of dense units): count/sum planes zero,
+        min/max planes +inf/−inf bit patterns — the planes of a freshly
+        reset unit, by the same walk."""
+        cells = self.unit_cells
+        packed = np.zeros((self.n_planes(lean), cells + 2), np.int32)
+        for c, pi in self._planes_walk(lean):
             if c.kind in NEUTRAL_BITS:
-                packed[pi, :a_pad] = NEUTRAL_BITS[c.kind]
-            pi += 1
+                packed[pi, :cells] = NEUTRAL_BITS[c.kind]
+        packed[0, cells] = self.NOOP_UNIT
+        return packed
+
+    def compact_noop(self, a_pad: int, lean: bool) -> np.ndarray:
+        """An all-padding COMPACT packed matrix (for prewarm)."""
+        packed = np.zeros((self.n_planes(lean) + 1, a_pad + 2), np.int32)
+        packed[0, :a_pad] = -1
+        packed[0, a_pad] = self.NOOP_UNIT
         return packed
 
     def _split_sum(self, src: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -390,11 +528,7 @@ class HostPartialStripe:
         if nonfin.any():
             over = nonfin & np.isfinite(src)
             if over.any() and self.spec.accum_dtype == sa.jnp.float64:
-                raise OverflowError(
-                    "partial_merge cannot transport f64 sums "
-                    "beyond float32 range (~3.4e38); use "
-                    "device_strategy='scatter' for this workload"
-                )
+                raise OverflowError(self.F64_OVERFLOW)
             # overflow (finite src) and genuine ±inf/NaN sums both leave
             # lo meaningless (inf - inf = NaN): zero it so the device fold
             # yields ±inf/NaN parity with the scatter path instead of
@@ -402,54 +536,17 @@ class HostPartialStripe:
             lo[nonfin] = 0.0
         return hi.view(np.int32), lo.view(np.int32)
 
-    def _take_packed_dense(
-        self, base_mod: int, used: int, a_pad: int, lean: bool, n_planes: int
-    ) -> tuple[np.ndarray, int, int, bool, bool]:
-        """Dense (index-free) pack: plane p at row p, cell i = flat index
-        i over the first ``used`` units, pad cells fold-neutral.  No host
-        gather — straight reshape + dtype conversion."""
-        cells = used * self.SUB * self.G
-        packed = np.zeros((n_planes, a_pad + 2), np.int32)
-        pi = 0
-        for c in self.spec.components:
-            if c.kind == "sumc":
-                continue
-            if lean and sa.lean_skippable(c):
-                continue
-            src = self._component_plane(c)[:used].reshape(-1)
-            if c.kind == "sum":
-                hi, lo = self._split_sum(src)
-                packed[pi, :cells] = hi
-                packed[pi + 1, :cells] = lo
-                pi += 2
-                continue
-            packed[pi, :cells] = (
-                np.ascontiguousarray(src, np.float64)
-                .astype(np.float32)
-                .view(np.int32)
-            )
-            if c.kind in NEUTRAL_BITS and cells < a_pad:
-                packed[pi, cells:a_pad] = NEUTRAL_BITS[c.kind]
-            pi += 1
-        packed[0, a_pad] = self.u_base
-        packed[0, a_pad + 1] = base_mod
-        u_base = self._reset_after_take(used)
-        return packed, a_pad, u_base, lean, True
-
-    def _reset_after_take(self, used: int) -> int:
-        """Shared post-pack stripe reset; returns the taken u_base."""
-        u_base = self.u_base
+    def _reset(self) -> None:
+        """Back to the state of a freshly allocated stripe: every written
+        cell fold-neutral again (the native compact pack has already done
+        its cells; here go dense units, whole, and the numpy pack's cells),
+        the touched list emptied."""
+        for where, n in self._dirty:
+            self.rec[where] = self._neutral
+            self.bytes_touched += n * self.rec.shape[1] * 8
+        self._dirty = []
+        self._n_touched[0] = 0
         self.u_base = None
         self.u_hi = 0
         self.rows = 0
-        # reset in place, touching only the unit rows this stripe used:
-        # re-zeroing the full (V, U_MAX, SUB, G) planes costs ~100ms per
-        # flush at 100K-key cardinality, while a stripe typically spans
-        # 1-2 slide units
-        self.row_cnt[:used] = 0
-        self.cnt[:, :used] = 0
-        self.sum[:, :used] = 0.0
-        self.mn[:, :used] = np.inf
-        self.mx[:, :used] = -np.inf
         self.nulls_seen = False
-        return u_base

@@ -131,7 +131,9 @@ class ColumnInterner:
         self._lib, self._py_intern = _load_native()
         self._h = self._lib.intern_create() if self._lib else None
         self._native_active = False
-        self._values_arr: np.ndarray | None = None  # object-array mirror
+        # object-array mirror of _values and how much of it is filled
+        self._values_arr: np.ndarray | None = None
+        self._values_arr_n = 0
         # numeric fast-path mirror: known keys sorted + their ids, valid
         # only while _num_mirror_n == len(_values) (any dict-path or
         # restore mutation invalidates it → lazily rebuilt)
@@ -478,13 +480,19 @@ class ColumnInterner:
         if self._native_active:
             self._sync_native_values()
             # fancy-index the object-array mirror: C-speed gather even for
-            # 100k-group emissions
-            if self._values_arr is None or len(self._values_arr) != len(
-                self._values
-            ):
-                self._values_arr = np.empty(len(self._values), dtype=object)
-                self._values_arr[:] = self._values
-            return self._values_arr[np.asarray(ids)]
+            # 100k-group emissions.  The mirror grows by doubling and takes
+            # the new keys alone: an emission costs the keys that appeared
+            # since the last one, not every key ever seen
+            n = len(self._values)
+            arr, have = self._values_arr, self._values_arr_n
+            if arr is None or have > n:
+                arr, have = np.empty(n, dtype=object), 0
+            elif len(arr) < n:
+                arr = np.empty(max(n, 2 * len(arr)), dtype=object)
+                arr[:have] = self._values_arr[:have]
+            arr[have:n] = self._values[have:n]
+            self._values_arr, self._values_arr_n = arr, n
+            return arr[np.asarray(ids)]
         nb = self._num_by_id
         if nb is not None and len(nb) > len(self._values):
             # numeric fast path with an un-flushed suffix: gather from

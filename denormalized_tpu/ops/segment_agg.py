@@ -327,29 +327,28 @@ def merge_partials(
     state: dict[str, jax.Array],
     packed: jax.Array,  # int32, (P+1, a_pad+2) compact / (P, a_pad+2) dense
 ) -> dict[str, jax.Array]:
-    """Fold host-side partial aggregates into the window ring — the device
-    half of the ``partial_merge`` strategy (host edge-reduction +
-    accelerator merge; see ops/host_partial.py).
+    """Fold one slide unit's host-side partial aggregates (or, with a
+    leading axis, a stack of units of one layout) into the window ring — the device half of the ``partial_merge`` strategy (host
+    edge-reduction + accelerator merge; see ops/host_partial.py).
 
     ``packed`` is an **int32 carrier** (immune to x64-off canonicalization):
-    row 0 holds flat cell indices ``((u*SUB)+s)*G + g`` (−1 = padding) plus
-    ``u_base_rel`` (stripe unit 0 relative to first_open) and ``base_mod``
-    (first_open % W) in its tail slots; value planes are f32 (or f64-pair)
+    row 0 holds the unit's active cell indices ``s*G + g``, ascending (−1 =
+    padding), plus ``u_rel`` (the unit relative to first_open) and
+    ``base_mod`` (first_open % W) in its tail slots; value planes are f32
     bitcasts — sums arrive as (hi, lo) so the host's f64 accumulation
-    survives transit.  The k-way sliding fan-out happens HERE: unit u's
+    survives transit.  The k-way sliding fan-out happens HERE: the unit's
     partial feeds windows u-k+1..u, with sub-bucket 1 (rows past the
     L-(k-1)S edge) excluded from the oldest window.  Compensated mode
     routes lo into the 'sumc' buffer — one rounding per merge per cell
     instead of one per row.
 
-    ``dense`` selects the index-free layout (host_partial.take_packed
-    dense branch): cell i IS flat index i, the index plane is omitted
-    (plane p sits at row p, header ints still in row 0's tail slots), and
-    padding carries fold-neutral values — the high-density win (≥~75%
-    of cells active, e.g. 100K live keys in a 131K ring)."""
+    ``dense`` selects the index-free layout (host_partial.take_packed):
+    ``a_pad == SUB*G``, cell i IS index i of the unit, the index plane is
+    omitted (plane p sits at row p, header ints still in row 0's tail
+    slots) and cells without rows carry fold-neutral values."""
     return merge_partials_body(
-        spec, SUB, a_pad, state, packed, spec.group_capacity,
-        jnp.asarray(0, jnp.int32), lean, dense,
+        spec, SUB, a_pad, state, packed, spec.group_capacity, None, lean,
+        dense,
     )
 
 
@@ -379,38 +378,47 @@ def merge_partials_body(
     lean: bool = False,
     dense: bool = False,
 ) -> dict[str, jax.Array]:
-    """Shared fold: ``state`` holds the contiguous group slice
-    ``[g_shift, g_shift + cap)`` of a ``G_total``-wide group space (single
-    device: the whole space, shift 0; key-sharded mesh: one shard per
-    device, shift = axis_index * G_local).
+    """Shared fold of one slide unit: ``state`` holds the contiguous group
+    slice ``[g_shift, g_shift + cap)`` of a ``G_total``-wide group space
+    (single device: the whole space, ``g_shift`` None; key-sharded mesh:
+    one shard per device, shift = axis_index * G_local).
+
+    Each window the unit feeds is ONE ring row: the row is sliced out,
+    folded and written back, so a merge reads and writes ``k`` rows of
+    each component and nothing else of the ring.  (Scattering into the
+    two-dimensional buffer made XLA:TPU re-lay the whole ``(W, G)`` plane
+    out as one dimension and back around every scatter: 10M groups put
+    12.8 GB of copies behind a merge of a thousand cells.)
 
     ``lean`` selects the null-free packed layout: per-column count planes
     are omitted from ``packed`` and aliased to the row-count plane — a
     null-free stripe's per-column counts equal its row counts
     cell-for-cell (host_partial.take_packed).
 
-    ``dense`` selects the index-free layout: no index plane (value plane p
-    is row p, header stays in row 0's tail slots), cell i is flat index i,
-    and pad cells beyond the stripe's span hold fold-neutral values (count
-    0, sum 0, min +inf, max −inf) so no validity mask is needed for them."""
+    Compact: the cells' (s, g) come from the index row; on one device
+    with ``SUB == 1`` they are the row's scatter indices as they stand —
+    ascending and distinct, which the scatter is told.  Dense: plane ``p``
+    is ``SUB`` rows of ``G_total`` cells and folds elementwise, no
+    scatter."""
+    if packed.ndim == 3:
+        # a stripe's dense units in one call (the backend stacks them, padded
+        # with no-op units to the stripe's span: one transfer and one
+        # dispatch for them all).  A loop, not an unrolling: one body
+        # whatever the span
+        def one(j, st):
+            unit = jax.lax.dynamic_index_in_dim(packed, j, 0, keepdims=False)
+            return merge_partials_body(
+                spec, SUB, a_pad, st, unit, G_total, g_shift, lean, dense
+            )
+
+        if packed.shape[0] == 1:
+            return one(0, state)
+        return jax.lax.fori_loop(0, packed.shape[0], one, state)
     W = spec.window_slots
-    u_base_rel = packed[0, a_pad]
+    k = spec.length_units
+    u_rel = packed[0, a_pad]
     base_mod = packed[0, a_pad + 1]
-    if dense:
-        safe = jnp.arange(a_pad, dtype=jnp.int32)
-        valid = jnp.ones((a_pad,), bool)
-    else:
-        idx = packed[0, :a_pad]
-        valid = idx >= 0
-        safe = jnp.maximum(idx, 0)
-    g_glob = safe % G_total
-    us = safe // G_total
-    s = us % SUB
-    u = us // SUB
     cap = next(iter(state.values())).shape[1]
-    g = g_glob - g_shift
-    valid = valid & (g >= 0) & (g < cap)
-    g = jnp.clip(g, 0, cap - 1)
     plane0 = 0 if dense else 1
 
     def f32_plane(pi):
@@ -418,47 +426,87 @@ def merge_partials_body(
             packed[plane0 + pi, :a_pad], jnp.float32
         )
 
-    for i in range(spec.length_units):
-        ok = valid
-        if SUB == 2 and i == spec.length_units - 1:
-            ok = ok & (s == 0)
-        w_rel = u_base_rel + u - i
-        ok = ok & (w_rel >= 0) & (w_rel < W)
-        slot = jnp.where(ok, (base_mod + w_rel) % W, W).astype(jnp.int32)
+    if dense:
+        def sub_rows(pv):
+            """The plane's SUB rows of this device's ``cap`` groups."""
+            rows = pv.reshape(SUB, G_total)
+            if g_shift is None:
+                return rows
+            return jax.lax.dynamic_slice(rows, (0, g_shift), (SUB, cap))
+    else:
+        idx = packed[0, :a_pad]
+        s = idx // G_total
+        g = idx % G_total - (0 if g_shift is None else g_shift)
+        valid = (idx >= 0) & (g >= 0) & (g < cap)
+        # what the host guarantees, where this device sees it unchanged
+        flags = dict(
+            indices_are_sorted=g_shift is None and SUB == 1,
+            unique_indices=SUB == 1,
+        )
+        # dropped entries scatter out of range, each to a place of its own
+        out_of_range = cap + jnp.arange(a_pad, dtype=jnp.int32)
+
+    def fold(kind, row, pv, ok_sub):
+        """``row`` (cap,) with one plane of the unit folded in; ``ok_sub``
+        says whether sub-bucket 1 belongs to this window."""
+        pv = pv.astype(row.dtype)
+        if dense:
+            rows = sub_rows(pv)
+            for sub in range(SUB if ok_sub else 1):
+                r = rows[sub]
+                row = (
+                    row + r if kind in ("count", "sum")
+                    else jnp.minimum(row, r) if kind == "min"
+                    else jnp.maximum(row, r)
+                )
+            return row
+        ok = valid if ok_sub or SUB == 1 else valid & (s == 0)
+        at = row.at[jnp.where(ok, g, out_of_range)]
+        if kind in ("count", "sum"):
+            return at.add(pv, mode="drop", **flags)
+        if kind == "min":
+            return at.min(pv, mode="drop", **flags)
+        return at.max(pv, mode="drop", **flags)
+
+    for i in range(k):
+        w_rel = u_rel - i
+        # a window outside the ring takes nothing (skew overflow is guarded
+        # host-side; late units were dropped there)
+        in_ring = (w_rel >= 0) & (w_rel < W)
+        slot = (base_mod + w_rel) % W
+        ok_sub = not (SUB == 2 and i == k - 1)
+
+        def apply(label, kind, planes):
+            buf = state[label]
+            row = jax.lax.dynamic_slice(buf, (slot, 0), (1, cap)).reshape(cap)
+            new = row
+            for pv in planes:
+                new = fold(kind, new, pv, ok_sub)
+            state[label] = jax.lax.dynamic_update_slice(
+                buf, jnp.where(in_ring, new, row).reshape(1, cap), (slot, 0)
+            )
+
         pi = 0
         for comp in spec.components:
             if comp.kind == "sumc":
                 continue
-            buf = state[comp.label]
-            at = buf.at[slot, g]
             if comp.kind == "sum":
-                hi = f32_plane(pi).astype(buf.dtype)
-                lo = f32_plane(pi + 1).astype(buf.dtype)
+                hi, lo = f32_plane(pi), f32_plane(pi + 1)
+                pi += 2
                 if spec.compensated:
-                    state[comp.label] = at.add(hi, mode="drop")
-                    lo_label = AggComponent("sumc", comp.col).label
-                    state[lo_label] = state[lo_label].at[slot, g].add(
-                        lo, mode="drop"
-                    )
+                    apply(comp.label, "sum", [hi])
+                    apply(AggComponent("sumc", comp.col).label, "sum", [lo])
                 else:
                     # two adds keep most of the host f64 precision even in
                     # a plain f32 buffer
-                    state[comp.label] = at.add(hi, mode="drop").at[
-                        slot, g
-                    ].add(lo, mode="drop")
-                pi += 2
+                    apply(comp.label, "sum", [hi, lo])
                 continue
             if lean and lean_skippable(comp):
                 pv = f32_plane(0)  # alias the row-count plane
             else:
                 pv = f32_plane(pi)
                 pi += 1
-            if comp.kind == "count":
-                state[comp.label] = at.add(pv.astype(buf.dtype), mode="drop")
-            elif comp.kind == "min":
-                state[comp.label] = at.min(pv.astype(buf.dtype), mode="drop")
-            else:
-                state[comp.label] = at.max(pv.astype(buf.dtype), mode="drop")
+            apply(comp.label, comp.kind, [pv])
     return state
 
 
@@ -499,19 +547,31 @@ def _read_and_reset_slots(
     prefix) of EVERY component, and re-initialization of those slots in
     the (donated) state — the shared read+reset core of both emission
     paths (_gather_and_reset and _finals_and_reset), so the ':g_bucket
-    prefix only' reset invariant cannot diverge between them."""
+    prefix only' reset invariant cannot diverge between them.
+
+    One dynamic slice and one dynamic update per slot and component, not
+    a gather and a scatter over ``n`` computed row indices: the ring
+    wraps, so the rows are not one slice, but each is — and XLA:TPU
+    compiles the slices in well under a second at any width, where the
+    gather form took time in proportion to ``n * g_bucket`` (half a
+    minute at 200K groups, minutes at 10M)."""
     W = spec.window_slots
-    slots = (first_slot + jnp.arange(n, dtype=jnp.int32)) % W
+    rows = {c.label: [] for c in spec.components}
+    for i in range(n):
+        slot = (first_slot + i) % W
+        for c in spec.components:
+            buf = state[c.label]
+            rows[c.label].append(
+                jax.lax.dynamic_slice(buf, (slot, 0), (1, g_bucket))
+            )
+            # only the transferred prefix needs resetting: cells beyond
+            # the live-group prefix were never written
+            init = jnp.full((1, g_bucket), spec.init_value(c)).astype(buf.dtype)
+            state[c.label] = jax.lax.dynamic_update_slice(buf, init, (slot, 0))
     comp = {
-        c.label: state[c.label][slots, :g_bucket] for c in spec.components
+        label: r[0] if n == 1 else jnp.concatenate(r, axis=0)
+        for label, r in rows.items()
     }
-    for c in spec.components:
-        # only the transferred prefix needs resetting: cells beyond the
-        # live-group prefix were never written
-        init = jnp.full((n, g_bucket), spec.init_value(c))
-        state[c.label] = state[c.label].at[slots, :g_bucket].set(
-            init.astype(state[c.label].dtype)
-        )
     return state, comp
 
 
@@ -521,6 +581,28 @@ BASIC_FINAL_KINDS = ("count", "sum", "min", "max", "avg")
 
 # key of the packed active-group bitmask in a finals emission block
 ACTIVE_BITS = "__active_bits__"
+
+
+def pack_active(active: jax.Array) -> jax.Array:
+    """(n, g) bool → (n, g // 8) uint8 with bit ``j`` of byte ``k`` =
+    ``active[:, j * (g // 8) + k]``: the eight bits of a byte are taken a
+    stride of ``g // 8`` apart, so the long axis stays on the lanes
+    (``jnp.packbits`` packs eight NEIGHBOURS, a minor dimension of 8, and
+    its compile time grew with ``n * g``: two minutes at 10M groups).
+    ``g`` is a multiple of 8 (group widths are multiples of 128).
+    :func:`unpack_active` is the host's inverse."""
+    n, g = active.shape
+    a = active.reshape(n, 8, g // 8).astype(jnp.int32)
+    shifts = jnp.arange(8, dtype=jnp.int32).reshape(1, 8, 1)
+    return jnp.sum(a << shifts, axis=1, dtype=jnp.int32).astype(jnp.uint8)
+
+
+def unpack_active(packed: np.ndarray) -> np.ndarray:
+    """Host inverse of :func:`pack_active`: (n, g // 8) uint8 → (n, g)
+    bool."""
+    n, w = packed.shape
+    bits = np.unpackbits(packed[:, :, None], axis=2, bitorder="little")
+    return bits.transpose(0, 2, 1).reshape(n, 8 * w).astype(bool)
 
 
 def finals_possible(agg_specs: tuple) -> bool:
@@ -556,7 +638,7 @@ def _finals_and_reset(
     (grouped_window_agg_stream.rs:609-629) run device-side."""
     state, comp = _read_and_reset_slots(spec, n, g_bucket, state, first_slot)
     rc = comp[ROW_COUNT.label]
-    out = {ACTIVE_BITS: jnp.packbits(rc > 0, axis=1)}
+    out = {ACTIVE_BITS: pack_active(rc > 0)}
 
     def cnt_of(col):
         lbl = AggComponent("count", col).label

@@ -39,6 +39,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from denormalized_tpu.ops import segment_agg as sa
+from denormalized_tpu.ops.host_partial import HostPartialStripe
 from denormalized_tpu.parallel.mesh import KEY_AXIS, SLICE_AXIS, shard_map
 from denormalized_tpu.runtime.tracing import NULL_CLOCK
 
@@ -60,6 +61,21 @@ class WindowStateBackend:
     # flush opens ``window.flush`` on it wherever the flush is set off
     # (trigger, growth, snapshot, or span overflow inside ``accumulate``)
     phases = NULL_CLOCK
+
+    def stripe_counters(self) -> dict[str, int]:
+        """What the host stripe's flushes cost, as ``metrics()`` names it:
+        ``stripe_cells_active``, ``stripe_cells_shipped``,
+        ``stripe_bytes_touched`` — all 0 for a row-shipping backend."""
+        stripe = getattr(self, "_stripe", None)
+        return {
+            f"stripe_{name}": getattr(stripe, name, 0)
+            for name in HostPartialStripe.COUNTERS
+        }
+
+    def carry_stripe_counters(self, old: "WindowStateBackend") -> None:
+        """Take over the stripe counts of the backend this one replaces."""
+        if self.accumulates_host and old.accumulates_host:
+            self._stripe.carry_counters(old._stripe)
 
     @property
     def strategy_name(self) -> str:
@@ -108,6 +124,11 @@ class WindowStateBackend:
 
     def read_reset_block_finish(self, handle) -> dict[str, "np.ndarray"]:
         return handle
+
+    # -- emission prewarm: the operator calls the one its plan can reach --
+    def prepare_gather(self) -> None:
+        """Pre-compile the component-gather emission programs.  No-op for
+        backends that read slots one by one."""
 
     # -- on-device finalization (optional) -----------------------------
     def prepare_finals(self, agg_specs: tuple) -> None:
@@ -164,6 +185,8 @@ class SingleDeviceWindowState(WindowStateBackend):
         self.dense_updates = 0
         self.scatter_updates = 0
         self._pallas_interpret = jax.default_backend() != "tpu"
+
+    def prepare_gather(self) -> None:
         if not self._pallas_interpret:
             # pre-compile emission gather programs for the block sizes and
             # group buckets the trigger will actually request: an unseen
@@ -176,11 +199,12 @@ class SingleDeviceWindowState(WindowStateBackend):
             # XLA cache on any later run).  Both layout variants are
             # warmed when they differ: a stream flips lean→full on its
             # first null, and a restored stream starts full.
+            spec = self.spec
             variants = {False, sa.lean_possible(spec)}
             for n in (1, 2, 4, 8):
                 if n <= spec.window_slots:
-                    for g_bucket in {min(1024, spec.group_capacity),
-                                     spec.group_capacity}:
+                    for g_bucket in {min(1024, self.group_capacity),
+                                     self.group_capacity}:
                         for lean in variants:
                             self._state, _ = sa._gather_and_reset(
                                 spec, n, g_bucket, self._state,
@@ -333,6 +357,13 @@ class SingleDeviceWindowState(WindowStateBackend):
             # in __init__: an unseen (n, bucket) pair compiling mid-stream
             # stalls the stream for seconds at a wide ring.  group_capacity
             # is the property — the GLOBAL width on sharded layouts.
+            # Every n the operator's block sizes reach (_close_windows:
+            # powers of two up to 8) is reachable on any plan: one step of
+            # the watermark can pass several window ends (a feed gap, an
+            # idle partition timing out, a stripe of several windows at
+            # replay speed).  Each program runs once here, so the device's
+            # peak memory includes one n = 8 block the traffic may never ask
+            # for (1.6 GB at 10M groups, freed at once).
             for n in (1, 2, 4, 8):
                 if n <= self.spec.window_slots:
                     for g_bucket in {min(1024, self.group_capacity),
@@ -397,38 +428,31 @@ class _HostPartialMixin:
     accumulates_host = True
 
     def _init_host_partial(self, stripe_group_capacity: int) -> None:
-        from denormalized_tpu.ops.host_partial import HostPartialStripe
-
         self._stripe = HostPartialStripe(self.spec, stripe_group_capacity)
         self._pending_base_mod = 0
         self.merges = 0
         if jax.default_backend() == "tpu":
-            # pre-compile every merge bucket with a no-op (all-padding)
-            # stripe: which bucket a flush lands in depends on runtime
-            # pacing, and an unseen size mid-stream is a multi-second
-            # compile.  Both packed layouts
-            # are warmed when the spec has per-column counts: lean (the
-            # null-free steady state) and full (the moment a null shows
-            # up).
+            # pre-compile every merge program with a no-op stripe: which
+            # bucket a flush lands in depends on runtime pacing, and an
+            # unseen size mid-stream is a multi-second compile.  Both
+            # packed layouts are warmed when the spec has per-column
+            # counts: lean (the null-free steady state) and full (the
+            # moment a null shows up).
             variants = [False]
             if sa.lean_possible(self.spec):
                 variants.append(True)
             stripe = self._stripe
-            dense_floor = stripe.G * stripe.SUB  # smallest dense span
             for lean in variants:
-                n_planes = stripe.n_planes(lean)
                 for a_pad in stripe.transfer_buckets():
-                    noop = np.zeros((n_planes + 1, a_pad + 2), np.int32)
-                    noop[0, :a_pad] = -1
-                    self._merge(noop, a_pad, lean)
-                    if a_pad >= dense_floor:
-                        # dense no-op: fold-neutral planes (a zeroed min
-                        # plane would clobber state with 0.0 — dense has
-                        # no validity mask); layout owned by the stripe
-                        self._merge(
-                            stripe.dense_noop(a_pad, lean), a_pad, lean,
-                            dense=True,
-                        )
+                    self._merge(stripe.compact_noop(a_pad, lean), a_pad, lean)
+                # dense no-op: fold-neutral planes (a zeroed min plane
+                # would clobber state with 0.0 — dense has no validity
+                # mask); layout owned by the stripe.  A flush's dense
+                # units go stacked, U to a call
+                self._merge(
+                    np.stack([stripe.dense_noop(lean)] * stripe.U),
+                    stripe.unit_cells, lean, dense=True,
+                )
 
     @property
     def pending_rows(self) -> int:
@@ -450,15 +474,7 @@ class _HostPartialMixin:
         equivalent of the scatter path's W growth."""
         units_rel = np.asarray(units_rel, np.int64)
         stripe = self._stripe
-        # units a stripe may span: both the U_MAX ring and the transfer
-        # cell cap (at least one unit — transfer_buckets covers G*SUB)
-        span_u = max(
-            1,
-            min(
-                stripe.U_MAX,
-                stripe.MAX_STRIPE_CELLS // max(1, stripe.G * stripe.SUB),
-            ),
-        )
+        span_u = stripe.U  # units a stripe holds
         if keep is None and len(units_rel):
             # fast path for the steady state: no late/keep mask and the
             # whole batch fits the CURRENT stripe as-is — fold it in one
@@ -515,12 +531,33 @@ class _HostPartialMixin:
     def flush_pending(self) -> None:
         if self._stripe.is_empty():
             return
-        with self.phases.phase("flush", rows=self._stripe.rows):
-            packed, a_pad, _u_base, lean, dense = self._stripe.take_packed(
-                self._pending_base_mod
-            )
-            self.bytes_h2d += int(packed.nbytes)
-            self._merge(packed, a_pad, lean, dense)
+        with self.phases.phase(
+            "flush", key="flush_send", rows=self._stripe.rows
+        ):
+            # one transfer and one merge program per compact unit, and one
+            # for all the dense units together (stacked, the stack padded
+            # with no-op units to the stripe's span): a stripe of many small
+            # units costs one dispatch, not one each
+            stripe = self._stripe
+            dense_units = []
+            for packed, a_pad, lean, dense in stripe.take_packed(
+                self._pending_base_mod, self.phases
+            ):
+                if dense:
+                    dense_units.append(packed)
+                    continue
+                self.bytes_h2d += int(packed.nbytes)
+                self._merge(packed, a_pad, lean, dense)
+            if dense_units:
+                pad = stripe.U - len(dense_units)
+                dense_units += [stripe.dense_noop(lean)] * pad
+                stripe.cells_shipped += pad * stripe.unit_cells
+                stacked = (
+                    dense_units[0][None] if stripe.U == 1
+                    else np.stack(dense_units)
+                )
+                self.bytes_h2d += int(stacked.nbytes)
+                self._merge(stacked, stripe.unit_cells, lean, True)
             self.merges += 1
 
 
@@ -763,6 +800,7 @@ class KeyShardedPartialMergeWindowState(_HostPartialMixin, KeyShardedWindowState
     read_reset_block_finish = SingleDeviceWindowState.read_reset_block_finish
     _live_bucket = SingleDeviceWindowState._live_bucket
     _count_compact_d2h = SingleDeviceWindowState._count_compact_d2h
+    prepare_gather = SingleDeviceWindowState.prepare_gather
     prepare_finals = SingleDeviceWindowState.prepare_finals
     read_reset_block_finals_start = (
         SingleDeviceWindowState.read_reset_block_finals_start

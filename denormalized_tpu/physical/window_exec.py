@@ -27,6 +27,7 @@ when the interner or the event-time skew outgrow them (bucketed static shapes
 
 from __future__ import annotations
 
+import sys
 import time
 from typing import Iterator
 
@@ -45,6 +46,7 @@ from denormalized_tpu.common.schema import DataType, Field, Schema
 from denormalized_tpu.logical.expr import AggregateExpr, Expr
 from denormalized_tpu.logical.plan import WindowType
 from denormalized_tpu.ops import segment_agg as sa
+from denormalized_tpu.ops.host_partial import HostPartialStripe
 from denormalized_tpu.ops.interner import INTERN_STATS, GroupInterner
 from denormalized_tpu.physical.base import (
     EOS,
@@ -57,6 +59,43 @@ from denormalized_tpu.physical.base import (
 )
 from denormalized_tpu.runtime.tracing import logger, phase_clock, span
 
+#: group ids an emission is built from at a time: the gathers' and casts'
+#: temporaries then stay in the cache, and the allocator hands the same
+#: pages back, where whole-window temporaries of 25–50 MB each are mapped,
+#: and every page of them faulted in, afresh at every window close
+EMIT_CHUNK_GROUPS = 1 << 18
+
+
+class _EmissionColumns:
+    """The output columns of past emissions, handed out again once nothing
+    else refers to them.
+
+    A window of millions of rows costs eight fresh 50 MB columns, every page
+    of them faulted in.  A column goes out as a view of its buffer, and every
+    view, slice or memoryview of it that a consumer keeps holds a reference
+    to that buffer: a buffer whose reference count says that this pool alone
+    holds it can be seen by nobody, and is written again.  ``KEEP`` sets
+    alternate, because a consumer usually still holds the last batch while
+    the next is built."""
+
+    KEEP = 2
+
+    def __init__(self, dtypes: list[np.dtype]) -> None:
+        self._dtypes = dtypes
+        self._sets: list[list[np.ndarray]] = []
+
+    def take(self, m: int) -> list[np.ndarray]:
+        """One column of ``m`` rows per dtype, contents undefined."""
+        for bufs in self._sets:
+            # 2 = the set's reference + getrefcount's own argument
+            if len(bufs[0]) >= m and all(
+                sys.getrefcount(bufs[i]) == 2 for i in range(len(bufs))
+            ):
+                return [b[:m] for b in bufs]
+        bufs = [np.empty(m + m // 8, dt) for dt in self._dtypes]
+        self._sets = (self._sets + [bufs])[-self.KEEP:]
+        return [b[:m] for b in bufs]
+
 #: keys of the window operator's phase clock, surfaced by ``metrics()`` as
 #: ``phase_ms_<key>``: exclusive host milliseconds, so they add up to the
 #: wall the operator's outer spans covered (docs/observability.md, Spans).
@@ -64,7 +103,8 @@ from denormalized_tpu.runtime.tracing import logger, phase_clock, span
 #: ``window.hint``, ``window.marker``, ``window.eos``).
 WINDOW_PHASES = (
     "project", "intern", "statewatch", "reduce", "acc_wait", "update",
-    "trigger", "flush", "gather", "d2h_wait", "finalize", "other",
+    "trigger", "flush_send", "flush_pack", "gather",
+    "d2h_wait", "finalize", "other",
 )
 
 
@@ -492,8 +532,7 @@ class StreamingWindowExec(ExecOperator):
             and sa.finals_possible(tuple(self._agg_specs))
             else None
         )
-        if self._finals_specs is not None:
-            self._backend.prepare_finals(self._finals_specs)
+        self._prepare_emission()
 
         # schema: group cols + agg cols + window bounds (+ canonical ts)
         fields = [g.out_field(in_schema) for g in self.group_exprs]
@@ -504,6 +543,10 @@ class StreamingWindowExec(ExecOperator):
             Field(CANONICAL_TIMESTAMP_COLUMN, DataType.TIMESTAMP_MS, nullable=False),
         ]
         self.schema = Schema(fields)
+        self._emit_cols = _EmissionColumns(
+            [f.dtype.to_numpy() if f.dtype.is_numeric else np.dtype(object)
+             for f in fields]
+        )
 
         # streaming state
         self._ckpt: tuple | None = None
@@ -603,9 +646,18 @@ class StreamingWindowExec(ExecOperator):
             m["scatter_updates"] = self._backend.scatter_updates
         m["bytes_h2d"] = self._backend.bytes_h2d
         m["bytes_d2h"] = self._backend.bytes_d2h
+        # what the stripe's flushes cost: cells with rows, cells sent
+        # (padding included), host bytes scanned and rewritten (0 for a
+        # row-shipping backend)
+        m.update(self._backend.stripe_counters())
         ms = self._phases.ms
         for key in WINDOW_PHASES:
             m[f"phase_ms_{key}"] = ms.get(key, 0.0)
+        # the whole of ``window.flush``: its self time (transfer and
+        # dispatch) and its child phase
+        m["phase_ms_flush"] = (
+            m["phase_ms_flush_send"] + m["phase_ms_flush_pack"]
+        )
         # what the intern phase's native table did: intern_rows,
         # intern_extra_probes, intern_overflow_rows (0 when ungrouped)
         m.update(
@@ -703,6 +755,16 @@ class StreamingWindowExec(ExecOperator):
         backend.phases = self._phases  # window.flush is opened in there
         return backend
 
+    def _prepare_emission(self) -> None:
+        """Pre-compile the emission programs this plan can reach: the
+        finals ladder or the component gather's, never both (the trigger
+        takes the gather only where no finals were prepared), and neither
+        under compaction, which reads slot by slot."""
+        if self._finals_specs is not None:
+            self._backend.prepare_finals(self._finals_specs)
+        elif not self._emission_compaction:
+            self._backend.prepare_gather()
+
     def _grow(self, *, window_slots: int | None = None, group_capacity: int | None = None):
         # host-accumulated partials are bound to the old G/W layout —
         # merge them into device state before exporting it
@@ -744,8 +806,7 @@ class StreamingWindowExec(ExecOperator):
         old_backend = self._backend
         self._backend = self._new_backend()
         self._carry_counters(old_backend)
-        if self._finals_specs is not None:
-            self._backend.prepare_finals(self._finals_specs)
+        self._prepare_emission()
         self._backend.import_(host)
         self._metrics["grow_events"] += 1
 
@@ -756,6 +817,7 @@ class StreamingWindowExec(ExecOperator):
         exactly wrong for high-cardinality runs that grow repeatedly."""
         self._backend.bytes_h2d += old_backend.bytes_h2d
         self._backend.bytes_d2h += old_backend.bytes_d2h
+        self._backend.carry_stripe_counters(old_backend)
         for counter in ("merges", "dense_updates", "scatter_updates"):
             if hasattr(self._backend, counter) and hasattr(old_backend, counter):
                 setattr(
@@ -765,8 +827,11 @@ class StreamingWindowExec(ExecOperator):
                 )
 
     def _ensure_capacity(self, max_win_rel: int):
+        # gids are interner-dense and this runs after interning, before the
+        # batch is folded: the ring is too small only once an id does not
+        # fit (growing at nine tenths doubled a ring sized for its keys)
         cap = self._backend.group_capacity
-        if self._grouped and len(self._interner) > 0.9 * cap:
+        if self._grouped and len(self._interner) > cap:
             n_dev = 1 if self._mesh is None else self._mesh.devices.size
             self._grow(
                 group_capacity=_round_capacity(
@@ -1139,19 +1204,20 @@ class StreamingWindowExec(ExecOperator):
         if is_finals:
             # finals block: one plane per output aggregate + packed
             # active bitmask; no host-side finalize needed
-            bits = np.unpackbits(block[sa.ACTIVE_BITS], axis=1)
+            bits = sa.unpack_active(block[sa.ACTIVE_BITS])
+            planes = [
+                block[f"__final_{k}__"] for k in range(len(self.aggr_exprs))
+            ]
             for i in range(n):
-                active = bits[i].astype(bool)
-                active[ngroups:] = False
-                if not active.any():
-                    continue
-                gids = np.nonzero(active)[0].astype(np.int32)
-                finals = [
-                    block[f"__final_{k}__"][i][gids]
-                    for k in range(len(self.aggr_exprs))
-                ]
-                self._metrics["windows_emitted"] += 1
-                yield self._build_emission_finals(j0 + i, gids, finals)
+                b = self._emit_window_batch(
+                    j0 + i,
+                    bits[i, :ngroups],
+                    lambda lo, hi, local, i=i: [
+                        p[i, lo:hi][local] for p in planes
+                    ],
+                )
+                if b is not None:
+                    yield b
             return
         # lean gathers omit per-column count planes (null-free stream:
         # they equal the row-count plane) — alias them back
@@ -1159,15 +1225,49 @@ class StreamingWindowExec(ExecOperator):
             if c.kind == "count" and c.label not in block:
                 block[c.label] = block[sa.ROW_COUNT.label]
         for i in range(n):
-            rows = {label: arr[i] for label, arr in block.items()}
-            counts = rows[sa.ROW_COUNT.label]
-            active = counts > 0
-            active[ngroups:] = False
-            if not active.any():
+            b = self._finalize_rows(
+                j0 + i, {label: arr[i] for label, arr in block.items()}
+            )
+            if b is not None:
+                yield b
+
+    def _emit_window_batch(
+        self, j: int, active: np.ndarray, finals_of, gids=None
+    ) -> RecordBatch | None:
+        """Window ``j``'s emission batch — the rows of the positions
+        ``active`` marks, ascending — or None when it marks none.  Position
+        ``p`` is group ``p``, or ``gids[p]`` where the planes are compacted;
+        ``finals_of(lo, hi, local)`` gives the output columns of the
+        positions ``lo + local``; the batch is filled ``EMIT_CHUNK_GROUPS``
+        positions at a time.  ONE batch a window: a consumer may take a
+        window's first row for the whole window delivered (the benchmark's
+        harness does)."""
+        m = int(np.count_nonzero(active))
+        if m == 0:
+            return None
+        cols = self._emit_cols.take(m)
+        n_keys = len(self.group_exprs)
+        off = 0
+        for lo in range(0, len(active), EMIT_CHUNK_GROUPS):
+            hi = min(lo + EMIT_CHUNK_GROUPS, len(active))
+            local = np.flatnonzero(active[lo:hi])
+            end = off + len(local)
+            if end == off:
                 continue
-            self._metrics["windows_emitted"] += 1
-            gids = np.nonzero(active)[0].astype(np.int32)
-            yield self._build_emission(j0 + i, gids, rows, active)
+            if self._grouped:
+                g = local + lo if gids is None else gids[lo:hi][local]
+                keys = self._interner.keys_of(g.astype(np.int32))
+                for c, kv in zip(cols, keys):
+                    c[off:end] = kv
+            for c, arr in zip(cols[n_keys:], finals_of(lo, hi, local)):
+                c[off:end] = arr
+            off = end
+        # window bounds + canonical timestamp
+        cols[-3][:] = j * self.slide_ms
+        cols[-2][:] = j * self.slide_ms + self.length_ms
+        cols[-1][:] = j * self.slide_ms
+        self._window_emitted(j)
+        return RecordBatch(self.schema, cols)
 
     def _trigger(self, force: bool = False) -> Iterator[RecordBatch]:
         """Emit every window whose end ≤ watermark (trigger_windows,
@@ -1287,8 +1387,6 @@ class StreamingWindowExec(ExecOperator):
     def _stripe_fits_more(self) -> bool:
         """Can the stripe still absorb the next slide unit without
         overflowing its span? (else defer no further — flush and emit)"""
-        from denormalized_tpu.ops.host_partial import HostPartialStripe
-
         span_now = self._max_win_seen - self._first_open + 1
         return span_now + 1 < HostPartialStripe.U_MAX
 
@@ -1324,79 +1422,49 @@ class StreamingWindowExec(ExecOperator):
             if not in_bounds.all():
                 gids32 = gids32[in_bounds]
                 rows = {label: arr[in_bounds] for label, arr in rows.items()}
-            if len(gids32) == 0:
-                return None
-            gids = gids32.astype(np.int32)
-            active = np.ones(len(gids), dtype=bool)
-            self._metrics["windows_emitted"] += 1
-            return self._build_emission(j, gids, rows, active)
+            return self._emit_window_batch(
+                j, np.ones(len(gids32), dtype=bool),
+                self._finals_of(rows), gids=gids32,
+            )
         return self._finalize_rows(j, rows)
 
     def _finalize_rows(self, j: int, rows: dict) -> RecordBatch | None:
         """Finalize one window's component planes into an emission batch
-        — shared by the ring slot path and the cold tier's emit-from-
-        store path (identical output either way)."""
-        counts = rows[sa.ROW_COUNT.label]
+        — shared by the ring's block and slot paths and the cold tier's
+        emit-from-store path (identical output either way)."""
         ngroups = len(self._interner) if self._grouped else 1
-        active = counts > 0
-        active[ngroups:] = False
-        if not active.any():
-            return None
-        self._metrics["windows_emitted"] += 1
-        gids = np.nonzero(active)[0].astype(np.int32)
-        return self._build_emission(j, gids, rows, active)
+        return self._emit_window_batch(
+            j, rows[sa.ROW_COUNT.label][:ngroups] > 0, self._finals_of(rows)
+        )
 
-    def _assemble_emission(
-        self, j: int, gids: np.ndarray, finals: list
-    ) -> RecordBatch:
-        """Shared emission assembly: group-key columns from the interner,
-        finalized aggregate columns (cast to output dtypes), window
-        bounds + canonical timestamp."""
-        cols: list[np.ndarray] = []
-        if self._grouped:
-            key_vals = self._interner.keys_of(gids)
-            for g, kv in zip(self.group_exprs, key_vals):
-                f = g.out_field(self.input_op.schema)
-                if f.dtype.is_numeric:
-                    kv = np.asarray(kv.tolist(), dtype=f.dtype.to_numpy())
-                cols.append(kv)
-        for a, arr in zip(self.aggr_exprs, finals):
-            f = a.out_field(self.input_op.schema)
-            cols.append(np.asarray(arr).astype(f.dtype.to_numpy()))
-        m = len(gids)
-        start = np.full(m, j * self.slide_ms, dtype=np.int64)
-        end = np.full(m, j * self.slide_ms + self.length_ms, dtype=np.int64)
-        cols += [start, end, start.copy()]
+    def _finals_of(self, rows: dict):
+        """Host finalization of a chunk of one window's component planes,
+        as ``_emit_window_batch`` asks for it."""
+        return lambda lo, hi, local: sa.finalize(
+            self._agg_specs,
+            {label: arr[lo:hi] for label, arr in rows.items()},
+            local,
+        )
+
+    def _window_emitted(self, j: int) -> None:
+        """Bookkeeping of window ``j``'s emission, once however many
+        batches carry its rows — the one place every emission path funnels
+        through."""
+        self._metrics["windows_emitted"] += 1
         self._obs_windows.add(1)
         if self._obs_emit_lag:
-            # end-to-end event-time emission latency, stamped at the one
-            # place every emission path funnels through
+            # end-to-end event-time emission latency
             self._obs_emit_lag.observe(
                 time.time() * 1000.0 - (j * self.slide_ms + self.length_ms)
             )
         if self._dr_lineage is not None:
             # sampled record lineage: close every chain whose tagged row
-            # fell inside this window (same funnel point as emit lag)
+            # fell inside this window
             self._dr_lineage.emitted(
                 self._dr_node_id,
                 j * self.slide_ms,
                 j * self.slide_ms + self.length_ms,
             )
-        return RecordBatch(self.schema, cols)
-
-    def _build_emission_finals(
-        self, j: int, gids: np.ndarray, finals: list
-    ) -> RecordBatch:
-        """Emission from device-finalized output planes (already masked to
-        the active gids, in aggr_exprs order)."""
-        return self._assemble_emission(j, gids, finals)
-
-    def _build_emission(
-        self, j: int, gids: np.ndarray, rows: dict, active: np.ndarray
-    ) -> RecordBatch:
-        return self._assemble_emission(
-            j, gids, sa.finalize(self._agg_specs, rows, active)
-        )
 
     # -- checkpointing ----------------------------------------------------
     # Snapshot = device state buffers + interner + watermark scalars, the
@@ -1486,8 +1554,7 @@ class StreamingWindowExec(ExecOperator):
         old_backend = self._backend
         self._backend = self._new_backend()
         self._carry_counters(old_backend)
-        if self._finals_specs is not None:
-            self._backend.prepare_finals(self._finals_specs)
+        self._prepare_emission()
         self._backend.import_(arrays)
         self._first_open = meta["first_open"]
         self._max_win_seen = meta["max_win_seen"]

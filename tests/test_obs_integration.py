@@ -71,11 +71,15 @@ WINDOW_KEYS = {
     "rows_in", "batches_in", "late_rows", "windows_emitted",
     "device_steps", "partial_merges", "grow_events", "hint_path_ms",
     "bytes_h2d", "bytes_d2h", "strategy_resolved", "first_batch_at",
-    # exclusive host milliseconds per phase of the operator
+    # exclusive host milliseconds per phase of the operator, and the
+    # whole flush (the sum of its two)
     "phase_ms_project", "phase_ms_intern", "phase_ms_statewatch",
     "phase_ms_reduce", "phase_ms_acc_wait", "phase_ms_update",
-    "phase_ms_trigger", "phase_ms_flush", "phase_ms_gather",
+    "phase_ms_trigger", "phase_ms_flush_send", "phase_ms_flush_pack",
+    "phase_ms_flush", "phase_ms_gather",
     "phase_ms_d2h_wait", "phase_ms_finalize", "phase_ms_other",
+    # what the host stripe's flushes touched and sent
+    "stripe_cells_active", "stripe_cells_shipped", "stripe_bytes_touched",
     # the native interner's tallies (docs/observability.md, Spans)
     "intern_rows", "intern_extra_probes", "intern_overflow_rows",
 }

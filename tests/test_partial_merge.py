@@ -454,6 +454,51 @@ def test_partial_device_finalize_parity(make_batch):
         _assert_parity(a, b, rtol=1e-5)
 
 
+@pytest.mark.parametrize("device_finalize", [True, False])
+@pytest.mark.parametrize("hold", [True, False])
+def test_partial_emission_chunks_and_buffers(
+    make_batch, monkeypatch, device_finalize, hold
+):
+    """An emission is filled ``EMIT_CHUNK_GROUPS`` group ids at a time
+    into columns that are taken again once the consumer has let the batch
+    go — and never while it holds one: ONE batch a window, the same rows as
+    a whole-window build, whether the consumer keeps every batch or none."""
+    import denormalized_tpu.physical.window_exec as we
+
+    batches = _sensor_batches(make_batch, keys=40, seed=5)
+    cfg = {"device_finalize": device_finalize}
+    whole = _run(batches, _std_aggs, 1000, strategy="partial_merge",
+                 cfg_extra=cfg)
+    monkeypatch.setattr(we, "EMIT_CHUNK_GROUPS", 8)
+    ctx = Context(EngineConfig(device_strategy="partial_merge", **cfg))
+    ds = ctx.from_source(
+        MemorySource.from_batches(batches, timestamp_column="occurred_at_ms")
+    ).window([col("sensor_name")], _std_aggs(), 1000)
+    seen, starts, held, buffers = {}, [], [], []
+    for b in ds.stream():
+        ws = np.asarray(b.column(WINDOW_START_COLUMN))
+        assert len(np.unique(ws)) == 1
+        starts.append(int(ws[0]))
+        buffers.append(b.column("sm").base.ctypes.data)
+        if hold:
+            held.append(b)
+            continue
+        for i in range(b.num_rows):
+            key = (int(ws[i]), b.column("sensor_name")[i])
+            seen[key] = {n: b.column(n)[i] for n in whole[key]}
+        del b, ws
+    for b in held:
+        ws = np.asarray(b.column(WINDOW_START_COLUMN))
+        for i in range(b.num_rows):
+            key = (int(ws[i]), b.column("sensor_name")[i])
+            seen[key] = {n: b.column(n)[i] for n in whole[key]}
+    assert starts == sorted(set(starts)) and len(starts) > 3
+    _assert_parity(whole, seen, rtol=0)
+    # held batches never share a buffer; let go, two buffers alternate
+    assert (len(set(buffers)) == len(buffers)) == hold
+    assert hold or len(set(buffers)) <= 3
+
+
 def test_partial_device_finalize_sharded(make_batch):
     """Finals emission over the 8-device mesh (borrowed single-device
     machinery, GSPMD-partitioned) matches scatter."""
@@ -496,11 +541,10 @@ def test_partial_dense_upload_layout(make_batch):
     layouts = []
     orig = HostPartialStripe.take_packed
 
-    def spy(self, base_mod):
-        r = orig(self, base_mod)
-        if r is not None:
-            layouts.append(r[4])
-        return r
+    def spy(self, *args):
+        packs = orig(self, *args)
+        layouts.extend(dense for _packed, _a_pad, _lean, dense in packs)
+        return packs
 
     HostPartialStripe.take_packed = spy
     try:
@@ -509,8 +553,8 @@ def test_partial_dense_upload_layout(make_batch):
         b = _run(batches, _std_aggs, 1000, strategy="partial_merge")
     finally:
         HostPartialStripe.take_packed = orig
-    # small G (128) in a 1024 bucket: dense (3-5 planes x 1024) always
-    # beats compact ((P+1) x 1024) — every flush should have gone dense
+    # small G (128): a unit's 128 cells are fewer than the smallest
+    # compact bucket (1024) — every unit should have gone dense
     assert layouts and all(layouts), layouts
     _assert_parity(a, b)
 
@@ -523,11 +567,10 @@ def test_partial_compact_upload_layout(make_batch):
     layouts = []
     orig = HostPartialStripe.take_packed
 
-    def spy(self, base_mod):
-        r = orig(self, base_mod)
-        if r is not None:
-            layouts.append(r[4])
-        return r
+    def spy(self, *args):
+        packs = orig(self, *args)
+        layouts.extend(dense for _packed, _a_pad, _lean, dense in packs)
+        return packs
 
     HostPartialStripe.take_packed = spy
     try:
@@ -539,8 +582,8 @@ def test_partial_compact_upload_layout(make_batch):
         a = _run(batches, _std_aggs, 1000, strategy="scatter")
     finally:
         HostPartialStripe.take_packed = orig
-    # G=16384 forces cells_d >= 16384 -> its bucket dwarfs the ~5-cell
-    # compact bucket (1024): compact must win every flush
+    # G=16384: a dense unit is 16384 cells a plane, the ~5 active cells
+    # fit the 1024 bucket — compact must win every unit
     assert layouts and not any(layouts), layouts
     _assert_parity(a, b)
 

@@ -126,14 +126,61 @@ def test_scatter_update_compiles(v5e, slide_ms):
 @pytest.mark.parametrize("dense", [False, True])
 def test_merge_partials_compiles(v5e, dense):
     spec, _ = _simple_spec()
+    spec = dataclasses.replace(spec, group_capacity=4096)
     stripe = HostPartialStripe(spec, spec.group_capacity)
-    a_pad = stripe.transfer_buckets()[-1]
+    a_pad = stripe.unit_cells if dense else stripe.transfer_buckets()[-1]
     lean = sa.lean_possible(spec)
     rows = stripe.n_planes(lean) + (0 if dense else 1)
+    # a flush's dense units go stacked, the stripe's span to a call
+    shape = (stripe.U, rows, a_pad + 2) if dense else (rows, a_pad + 2)
+    assert stripe.U == 16
     sa.merge_partials.lower(
         spec, stripe.SUB, a_pad, lean, dense, _state(spec, v5e),
-        _sds(v5e, (rows, a_pad + 2), jnp.int32),
+        _sds(v5e, shape, jnp.int32),
     ).compile()
+
+
+def _keyed_10m_spec():
+    # benchmark/configs/keyed_10m.json: five aggregates of one column, 10 s
+    # tumbling, a ring of 16 x 10,000,000 x 5 planes = 3.2 GB
+    aggs = [("count", 0), ("sum", 0), ("min", 0), ("max", 0), ("avg", 0)]
+    return sa.WindowKernelSpec(
+        components=tuple(sa.components_for(aggs)),
+        num_value_cols=1,
+        window_slots=16,
+        group_capacity=10_000_000,
+        length_ms=10_000,
+        slide_ms=10_000,
+    ), tuple(aggs)
+
+
+def test_keyed_10m_programs_compile_and_leave_the_ring_alone(v5e):
+    """The merge and the finals emission at the keyed_10m ring's real size:
+    they compile (in seconds: the finals program once took minutes, for
+    ``jnp.packbits`` and a gather over computed rows), fit the chip beside
+    the ring, and keep no ring-sized scratch (the merge once re-laid every
+    ``(16, G)`` plane out as one dimension around each scatter)."""
+    spec, aggs = _keyed_10m_spec()
+    stripe = HostPartialStripe(spec, spec.group_capacity)
+    assert stripe.U == 1 and stripe.transfer_buckets()[-1] == 1 << 23
+    ring = 16 * spec.group_capacity * 4 * len(spec.components)
+    row = spec.group_capacity * 4
+    slot = _sds(v5e, (), jnp.int32)
+    for a_pad, dense in ((1 << 22, False), (stripe.unit_cells, True)):
+        rows = stripe.n_planes(True) + (0 if dense else 1)
+        shape = (1, rows, a_pad + 2) if dense else (rows, a_pad + 2)
+        m = sa.merge_partials.lower(
+            spec, 1, a_pad, True, dense, _state(spec, v5e),
+            _sds(v5e, shape, jnp.int32),
+        ).compile().memory_analysis()
+        assert m.alias_size_in_bytes == ring  # folded in place
+        assert m.temp_size_in_bytes < 8 * row + 8 * 4 * a_pad
+    for n in (1, 8):
+        m = sa._finals_and_reset.lower(
+            spec, aggs, n, spec.group_capacity, _state(spec, v5e), slot
+        ).compile().memory_analysis()
+        assert m.alias_size_in_bytes == ring
+        assert m.temp_size_in_bytes + m.output_size_in_bytes - ring < 16e9 - ring
 
 
 def test_emission_programs_compile(v5e):
