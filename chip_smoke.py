@@ -600,6 +600,7 @@ def native_libraries() -> dict:
     """Load (building from source where needed) every native library the
     legs use; False marks one whose caller would run its Python fallback."""
     from denormalized_tpu.formats import native_json
+    from denormalized_tpu.obs import statewatch
     from denormalized_tpu.ops import host_partial, interner
     from denormalized_tpu.sources import kafka
     from denormalized_tpu.state import lsm
@@ -619,6 +620,7 @@ def native_libraries() -> dict:
         "json_parser": loads(native_json._lib),
         "kafka_client": loads(kafka._lib),
         "lsmkv": loads(lsm._load_native),
+        "sketch_update": loads(statewatch._native),
     }
 
 

@@ -6,7 +6,9 @@
 // Exercises: LSM store (put/get/delete/recovery/compaction), string
 // interner (growth, duplicates, width changes, keys around the inline
 // width, block boundaries, the id store), JSON parser (escapes, nulls,
-// duplicates, malformed rows).
+// duplicates, malformed rows), the state observatory's sketch pass (empty
+// and refused calls, eight slots, ids at the top of their width, a table
+// as full as it gets, sampled counts, watches on threads of their own).
 
 #include <atomic>
 #include <cassert>
@@ -23,6 +25,7 @@
 #include "json_parser.cpp"
 #include "kafka_client.cpp"
 #include "lsmkv.cpp"
+#include "sketch_update.cpp"
 
 static void test_lsm(const char* dir) {
   void* s = lsm_open(dir);
@@ -90,7 +93,7 @@ struct OffsetsBatch {
 static std::vector<int32_t> intern_fixed(
     void* h, const std::vector<std::string>& keys, uint32_t w) {
   size_t n = keys.size();
-  uint8_t* buf = (uint8_t*)calloc(n * w ? n * w : 1, 1);
+  uint8_t* buf = (uint8_t*)calloc(n * w != 0 ? n * w : 1, 1);
   for (size_t i = 0; i < n; i++) {
     assert(keys[i].size() <= w);
     memcpy(buf + i * w, keys[i].data(), keys[i].size());
@@ -1106,6 +1109,114 @@ static void test_interner_hammer() {
   printf("interner hammer ok\n");
 }
 
+// one watch's arrays, as obs/statewatch.py owns them
+struct SketchWatch {
+  int64_t cap;
+  int32_t K;
+  std::vector<uint8_t> scratch, regs;
+  std::vector<int64_t> keys, counts, errs;
+  SketchWatch(int64_t cap_, int32_t K_)
+      : cap(cap_), K(K_),
+        scratch((size_t)sketch_scratch_bytes(cap_, K_), 0),
+        regs(1u << 12, 0), keys((size_t)K_, -1), counts((size_t)K_, 0),
+        errs((size_t)K_, 0) {}
+  int64_t fold(const void* ids, int32_t id_bytes, int64_t m, int64_t rows) {
+    const int64_t got =
+        sketch_update(ids, id_bytes, m, rows, regs.data(), 12, keys.data(),
+                      counts.data(), errs.data(), K, scratch.data(), cap);
+    // every call leaves the table as it found it: all zeros
+    const size_t table_bytes =
+        (size_t)((uint8_t*)sketch::Scratch(scratch.data(), cap, K).listed -
+                 scratch.data());
+    for (size_t i = 0; i < table_bytes; i++) assert(scratch[i] == 0);
+    return got;
+  }
+  int64_t count_of(int64_t key) const {
+    for (size_t k = 0; k < keys.size(); k++)
+      if (keys[k] == key) return counts[k];
+    return -1;
+  }
+};
+
+static void test_sketch_hammer() {
+  {
+    // no rows: nothing happens; arguments it will not take: refused
+    SketchWatch w(256, 8);
+    int32_t none = 0;
+    assert(w.fold(&none, 4, 0, 0) == 0);
+    assert(w.fold(&none, 4, 257, 257) == -1);  // more rows than the cap
+    assert(w.fold(&none, 3, 1, 1) == -1);      // no such id width
+    assert(w.fold(&none, 4, 2, 1) == -1);      // fewer rows than ids
+    assert(sketch_update(&none, 4, 1, 1, w.regs.data(), 3, w.keys.data(),
+                         w.counts.data(), w.errs.data(), 8,
+                         w.scratch.data(), 256) == -1);  // no such p
+    assert(sketch_scratch_bytes(0, 8) == -1);
+    for (int64_t k : w.keys) assert(k == -1);
+    for (uint8_t r : w.regs) assert(r == 0);
+  }
+  {
+    // eight slots, five ids at the top of int32: all tracked, exact
+    SketchWatch w(256, 8);
+    std::vector<int32_t> ids(250);
+    for (size_t i = 0; i < ids.size(); i++)
+      ids[i] = INT32_MAX - (int32_t)(i % 5);
+    assert(w.fold(ids.data(), 4, 250, 250) == 5);
+    for (int j = 0; j < 5; j++) assert(w.count_of(INT32_MAX - j) == 50);
+    // again, sampled at one row in three: hits, in row units
+    assert(w.fold(ids.data(), 4, 250, 750) == 5);
+    for (int j = 0; j < 5; j++) assert(w.count_of(INT32_MAX - j) == 200);
+    for (int64_t e : w.errs) assert(e == 0);
+  }
+  {
+    // as many distinct ids as the cap allows, both widths: the table is
+    // as full as it gets; every count is one, so the eight smallest ids
+    // are admitted
+    SketchWatch w(4096, 8);
+    std::vector<int32_t> a(4096);
+    std::vector<int64_t> b(4096);
+    for (size_t i = 0; i < a.size(); i++) {
+      a[i] = (int32_t)(i * 7919u + 11u);
+      b[i] = ((int64_t)1 << 40) + (int64_t)i * 7919;
+    }
+    assert(w.fold(a.data(), 4, 4096, 4096) == 4096);
+    for (int64_t j = 0; j < 8; j++) assert(w.count_of(j * 7919 + 11) == 1);
+    assert(w.fold(b.data(), 8, 4096, 4096) == 4096);
+    // each newcomer evicts a slot of count one and inherits it
+    for (int64_t j = 0; j < 8; j++)
+      assert(w.count_of(((int64_t)1 << 40) + j * 7919) == 2);
+    for (int64_t e : w.errs) assert(e == 1);
+  }
+  // watches on threads of their own, each with its scratch: two of the
+  // four fold the same stream and must end equal
+  std::vector<SketchWatch> ws(4, SketchWatch(16384, 64));
+  std::vector<std::thread> ts;
+  for (int t = 0; t < 4; t++) {
+    ts.emplace_back([t, &ws] {
+      SketchWatch& w = ws[(size_t)t];
+      uint64_t x = 88172645463325252ull + (uint64_t)(t / 2);
+      std::vector<int32_t> ids(16384);
+      for (int round = 0; round < 60; round++) {
+        const uint64_t span = round % 3 == 0 ? 10 : 100000;
+        for (auto& id : ids) {
+          x ^= x << 13, x ^= x >> 7, x ^= x << 17;
+          id = (int32_t)(x % span);
+        }
+        const int64_t m = 1 + (int64_t)(x % 16384);
+        assert(w.fold(ids.data(), 4, m, m + (round % 2) * 1000) > 0);
+      }
+    });
+  }
+  for (auto& th : ts) th.join();
+  for (int t = 0; t < 4; t += 2) {
+    assert(ws[(size_t)t].keys == ws[(size_t)t + 1].keys);
+    assert(ws[(size_t)t].counts == ws[(size_t)t + 1].counts);
+    assert(ws[(size_t)t].errs == ws[(size_t)t + 1].errs);
+    assert(ws[(size_t)t].regs == ws[(size_t)t + 1].regs);
+  }
+  assert(ws[0].counts != ws[2].counts);
+  printf("sketch hammer ok\n");
+}
+
 int main(int argc, char** argv) {
   const char* dir = argc > 1 ? argv[1] : "/tmp/native_test_lsm";
   test_lsm(dir);
@@ -1120,6 +1231,7 @@ int main(int argc, char** argv) {
   test_lsm_hammer(dir);
   test_kafka_hammer();
   test_interner_hammer();
+  test_sketch_hammer();
   printf("ALL NATIVE TESTS PASSED\n");
   return 0;
 }

@@ -17,10 +17,14 @@ hot, and when do I OOM".  This module answers it with three parts:
 2. **Streaming key-distribution sketches** — a vectorized Space-Saving
    heavy-hitter sketch (:class:`SpaceSaving`) and a HyperLogLog
    cardinality estimator (:class:`Hll`), both fed DENSE GIDS in batch
-   right after intern time.  Updates are pure numpy (bucketed
-   ``np.unique`` + scatter adds; pinned loop-free in ``hotpaths.toml``)
-   so the 49M rows/s hot path pays microseconds per batch, not per-row
-   Python.  Accuracy bounds (documented in docs/observability.md):
+   right after intern time.  An update is one native pass over the
+   batch (``native/sketch_update.cpp``), or, without a compiler or for
+   ids that are not contiguous int32 / int64, the NumPy kernels of
+   ``ops/sketches.py`` (bucketed ``np.unique`` + scatter adds): both
+   leave the same state, bit for bit (tests/test_statewatch_native.py),
+   and neither runs per-row Python (pinned loop-free in
+   ``hotpaths.toml``).  Accuracy bounds (documented in
+   docs/observability.md):
 
    - Space-Saving with K slots overestimates a key's count by at most
      its reported ``err`` (the count of the slot it evicted); any key
@@ -58,6 +62,7 @@ Health verdicts (``skewed-join-side``, ``unbounded-session-growth``,
 
 from __future__ import annotations
 
+import ctypes
 import math
 import time
 from collections import deque
@@ -180,6 +185,61 @@ SKETCH_ROW_CAP = 16_384
 JOIN_SKETCH_DECAY_ROWS = 1 << 18
 
 
+# -- the native pass -------------------------------------------------------
+# Loaded here and not in ops/sketches.py, which keeps to numpy / math /
+# hashlib (the soak parent loads it by path).
+
+_LIB = None
+_LIB_TRIED = False
+
+#: id dtypes the native pass takes (the interners hand out int32, the
+#: session and join paths int64) -> bytes an id
+_NATIVE_ID_BYTES = {np.dtype(np.int32): 4, np.dtype(np.int64): 8}
+
+
+def _native():
+    """``native/sketch_update.cpp``, built on first use; None where it
+    cannot be (no compiler): the NumPy kernels then carry every update."""
+    global _LIB, _LIB_TRIED
+    if not _LIB_TRIED:
+        _LIB_TRIED = True
+        try:
+            from denormalized_tpu.native.build import load
+
+            lib = load("sketch_update")
+            lib.sketch_scratch_bytes.restype = ctypes.c_int64
+            lib.sketch_scratch_bytes.argtypes = [
+                ctypes.c_int64,  # cap: most rows a call
+                ctypes.c_int32,  # K: Space-Saving slots
+            ]
+            lib.sketch_update.restype = ctypes.c_int64
+            lib.sketch_update.argtypes = [
+                ctypes.c_void_p,  # ids int32 | int64, contiguous
+                ctypes.c_int32,   # bytes an id
+                ctypes.c_int64,   # m: ids given
+                ctypes.c_int64,   # rows they stand for
+                ctypes.c_void_p,  # HLL registers uint8 (2^p)
+                ctypes.c_int32,   # p
+                ctypes.c_void_p,  # keys int64 (K)
+                ctypes.c_void_p,  # counts int64 (K)
+                ctypes.c_void_p,  # errs int64 (K)
+                ctypes.c_int32,   # K
+                ctypes.c_void_p,  # scratch uint8
+                ctypes.c_int64,   # cap the scratch was sized for
+            ]
+            _LIB = lib
+        except Exception as e:  # dnzlint: allow(broad-except) the NumPy sketch kernels are the designed fallback on no-compiler boxes; logged so the downgrade is visible, gated by test_native_build_gate where g++ exists
+            from denormalized_tpu.runtime.tracing import logger
+
+            logger.warning(
+                "native sketch_update unavailable (%s: %s) — the state "
+                "observatory's sketches run the numpy path",
+                type(e).__name__, e,
+            )
+            _LIB = None
+    return _LIB
+
+
 # -- the per-operator watch ----------------------------------------------
 
 
@@ -202,7 +262,8 @@ class StateWatch:
 
     __slots__ = (
         "label", "enabled", "sketch", "hll", "update_s", "update_batches",
-        "samples", "_last_sample_t", "_hot_bound", "_sample_phase",
+        "sketch_native_batches", "samples", "_last_sample_t", "_hot_bound",
+        "_sample_phase", "_lib", "_scratch",
     )
 
     def __init__(self, label: str, *, capacity: int = 64,
@@ -216,6 +277,14 @@ class StateWatch:
         self.hll = Hll()
         self.update_s = 0.0  # cumulative sketch-update cost (bench reports)
         self.update_batches = 0
+        # of those, the batches the native pass folded (all of them where
+        # the library loaded and the ids came as contiguous int32 / int64)
+        self.sketch_native_batches = 0
+        # the native pass and its scratch: False until bind_native() has
+        # looked (make_watch does at once; a watch built by hand at its
+        # first batch), then the library or None
+        self._lib = False
+        self._scratch = None
         self.samples: deque = deque(maxlen=_SAMPLE_RING)
         self._last_sample_t = 0.0
         self._sample_phase = 0
@@ -225,6 +294,23 @@ class StateWatch:
 
     def __bool__(self) -> bool:
         return True
+
+    def bind_native(self):
+        """Load the native pass (on a fresh checkout: build it) and
+        allocate its scratch — the (id, count) table and work lists, this
+        watch's own and all zeros between calls.  ``make_watch`` calls
+        this where an operator is constructed, so a g++ run is part of
+        set-up and never of the first batch."""
+        lib = _native()
+        if lib is not None:
+            self._scratch = np.zeros(
+                lib.sketch_scratch_bytes(
+                    SKETCH_ROW_CAP, len(self.sketch.keys)
+                ),
+                dtype=np.uint8,
+            )
+        self._lib = lib
+        return lib
 
     # -- hot path --------------------------------------------------------
     def update(self, gids: np.ndarray) -> None:
@@ -237,15 +323,17 @@ class StateWatch:
         (counts scaled back to row units): contiguous keeps the memory
         traffic at one block regardless of batch size, rotation keeps
         the coverage uniform across the stream even when keys cluster
-        within a batch."""
+        within a batch.
+
+        Contiguous int32 / int64 ids take one native pass
+        (``native/sketch_update.cpp``); anything else, or no library, the
+        NumPy kernels — the same state either way."""
         n = len(gids)
         if not self.enabled or n == 0:
             return
         t0 = time.perf_counter()
         g = gids if isinstance(gids, np.ndarray) else np.asarray(gids)
-        sampled = False
         if n > SKETCH_ROW_CAP:
-            sampled = True
             # wrap the phase over the VALID start range [0, n - CAP], not
             # back to 0: constant-size batches would otherwise alternate
             # start 0 -> CAP -> 0 and never sample the tail rows past the
@@ -254,15 +342,36 @@ class StateWatch:
             start = self._sample_phase % (n - SKETCH_ROW_CAP + 1)
             self._sample_phase = start + SKETCH_ROW_CAP
             g = g[start:start + SKETCH_ROW_CAP]
-        u, c = _aggregate_gids(g)
-        if sampled:
-            # rescale by the TRUE sampling ratio (n / sample size), not
-            # an integer ceiling: a 17k-row batch samples 16384 rows at
-            # ratio ~1.04 — a ceil(17000/16384)=2 multiplier would
-            # double every share and falsely trip skew verdicts
-            c = np.rint(c * (n / len(g))).astype(np.int64)
-        self.sketch.update_aggregated(u, c, n)
-        self.hll.update(u)
+        lib = self._lib
+        if lib is False:
+            lib = self.bind_native()
+        id_bytes = _NATIVE_ID_BYTES.get(g.dtype)
+        if (
+            lib is not None and id_bytes is not None
+            and g.ndim == 1 and g.flags.c_contiguous
+        ):
+            sk = self.sketch
+            sk.note_rows(n)
+            # the slot arrays are looked up on every call: a decay step
+            # replaces counts and errs
+            lib.sketch_update(
+                g.ctypes.data, id_bytes, len(g), n,
+                self.hll.registers.ctypes.data, self.hll.p,
+                sk.keys.ctypes.data, sk.counts.ctypes.data,
+                sk.errs.ctypes.data, len(sk.keys),
+                self._scratch.ctypes.data, SKETCH_ROW_CAP,
+            )
+            self.sketch_native_batches += 1
+        else:
+            u, c = _aggregate_gids(g)
+            if len(g) != n:
+                # rescale by the TRUE sampling ratio (n / sample size), not
+                # an integer ceiling: a 17k-row batch samples 16384 rows at
+                # ratio ~1.04 — a ceil(17000/16384)=2 multiplier would
+                # double every share and falsely trip skew verdicts
+                c = np.rint(c * (n / len(g))).astype(np.int64)
+            self.sketch.update_aggregated(u, c, n)
+            self.hll.update(u)
         self.update_s += time.perf_counter() - t0
         self.update_batches += 1
 
@@ -340,6 +449,7 @@ class StateWatch:
             "sketch_rows": self.sketch.total,
             "sketch_update_ms_total": round(self.update_s * 1e3, 3),
             "sketch_update_batches": self.update_batches,
+            "sketch_native_batches": self.sketch_native_batches,
             "enabled": self.enabled,
         }
 
@@ -380,11 +490,12 @@ class _NullWatch:
             "hot_keys": [], "skew_factor": None,
             "distinct_gids_estimate": 0, "sketch_rows": 0,
             "sketch_update_ms_total": 0.0, "sketch_update_batches": 0,
-            "enabled": False,
+            "sketch_native_batches": 0, "enabled": False,
         }
 
     update_s = 0.0
     update_batches = 0
+    sketch_native_batches = 0
     samples: deque = deque()
 
 
@@ -402,8 +513,10 @@ def make_watch(label: str, *, capacity: int = 64, decay_every: int = 0,
     from denormalized_tpu import obs
 
     if obs.enabled():
-        return StateWatch(
+        watch = StateWatch(
             label, capacity=capacity,
             decay_every=decay_every, decay_factor=decay_factor,
         )
+        watch.bind_native()
+        return watch
     return NULL_WATCH

@@ -319,17 +319,24 @@ class SpaceSaving:
         self.total = int(self.total * f)
         self._since_decay = 0
 
+    def note_rows(self, rows: int) -> None:
+        """What a batch of ``rows`` rows does before its keys are
+        admitted: the decay step when one is due, and the total.  Split
+        from :meth:`update_aggregated` so the state observatory's native
+        pass (obs/statewatch.py) shares it."""
+        if self.decay_every:
+            self._since_decay += int(rows)
+            if self._since_decay >= self.decay_every:
+                self.decay()
+        self.total += int(rows)
+
     def update_aggregated(
         self, u: np.ndarray, c: np.ndarray, rows: int
     ) -> None:
         """Batch update from pre-aggregated (unique gids, counts) —
         the shape :func:`_aggregate_gids` produces once per batch so the
         HLL can share the same reduction."""
-        if self.decay_every:
-            self._since_decay += int(rows)
-            if self._since_decay >= self.decay_every:
-                self.decay()
-        self.total += int(rows)
+        self.note_rows(rows)
         ss_admit(self.keys, self.counts, self.errs, u, c)
 
     def top(self, k: int = 8) -> tuple[np.ndarray, np.ndarray, np.ndarray]:

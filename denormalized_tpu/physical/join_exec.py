@@ -1125,6 +1125,10 @@ class StreamingJoinExec(ExecOperator):
                     decay_every=statewatch.JOIN_SKETCH_DECAY_ROWS,
                 )
                 self._sw_sample = 4
+                # as make_watch does: the native pass is loaded (or built)
+                # with the operator, not by its first batch
+                self._sw.bind_native()
+                self._sw_right.bind_native()
         self._obs_rows_out = obs.counter("dnz_op_rows_out_total", op="join")
         # shared-group cost attribution (runtime/multi_query.py): when a
         # join feeds a shared slice pipeline, the doctor apportions the

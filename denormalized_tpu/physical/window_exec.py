@@ -658,6 +658,10 @@ class StreamingWindowExec(ExecOperator):
         m["phase_ms_flush"] = (
             m["phase_ms_flush_send"] + m["phase_ms_flush_pack"]
         )
+        # what ``window.statewatch`` ran: batches sketched, and how many of
+        # them the native pass folded (all, where the library loaded)
+        m["sketch_update_batches"] = self._sw.update_batches
+        m["sketch_native_batches"] = self._sw.sketch_native_batches
         # what the intern phase's native table did: intern_rows,
         # intern_extra_probes, intern_overflow_rows (0 when ungrouped)
         m.update(

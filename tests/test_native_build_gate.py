@@ -37,6 +37,7 @@ _MODULES = {
     "avro_parser": [],
     "interner": [],
     "partial_agg": [],
+    "sketch_update": [],
     "kafka_client": ["-lz"],
     "lsmkv": [],
     "pyassemble": [f"-I{_PY_INC}"],

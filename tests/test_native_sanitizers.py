@@ -92,7 +92,7 @@ def test_native_components(tmp_path, flavor):
     # the hammers must actually have run in every flavor — a refactor
     # that drops them from main() would silently gut the TSan coverage
     for marker in ("lsm hammer ok", "kafka hammer ok",
-                   "interner hammer ok"):
+                   "interner hammer ok", "sketch hammer ok"):
         assert marker in run.stdout, run.stdout[-500:]
 
 
