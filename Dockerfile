@@ -23,7 +23,6 @@ COPY pyproject.toml README.md ./
 COPY denormalized_tpu ./denormalized_tpu
 COPY examples ./examples
 COPY tests ./tests
-COPY bench.py ./
 
 RUN pip install --no-cache-dir -e .[dev] "jax[cpu]==0.9.0"
 # pre-build the native components (each falls back to pure Python at

@@ -17,9 +17,7 @@ Legs:
 - ``keyed``   — 100,000 string keys, 1 s tumbling count/sum/min/max/avg
   from ``MemorySource``, under ``auto`` and under ``scatter``.
 - ``sliding`` — 1 s window sliding by 200 ms with a post-aggregation
-  filter, under ``auto`` and under ``pallas_dense``; then ``pallas_dense``
-  at the widest spec its envelope admits (tumbling, 2048 groups, 4 value
-  columns).
+  filter, under ``auto``.
 - ``restore`` — the keyed query with checkpointing, stopped after two
   committed epochs and restored through a fresh ``Context``: the union of
   both runs' emissions holds every reference window, and every emission of
@@ -57,16 +55,13 @@ REL_TOL = 1e-5
 
 # Sizes.  Full: BASELINE.json's deployments at their own widths, at 1M
 # events per event-second.  Rehearsal: the same shapes a hundred times
-# smaller (and a narrower dense-kernel edge: the kernel runs in Pallas
-# interpret mode off the chip).
+# smaller.
 FULL = {
     "rate": 1_000_000,
     "served": {"events": 4_000_000, "sensors": 10, "partitions": 4},
     "keyed": {"keys": 100_000, "rows": 8_000_000, "batch": 524_288,
               "capacity": 200_000},
     "sliding": {"keys": 10, "rows": 4_000_000, "batch": 131_072},
-    "edge": {"keys": 1_800, "rows": 2_000_000, "batch": 131_072,
-             "capacity": 2_048},
     "restore": {"batch": 131_072, "interval_s": 0.25},
     "deadline_s": 300.0,
 }
@@ -76,7 +71,6 @@ REHEARSAL = {
     "keyed": {"keys": 1_000, "rows": 80_000, "batch": 8_192,
               "capacity": 2_000},
     "sliding": {"keys": 10, "rows": 20_000, "batch": 1_024},
-    "edge": {"keys": 300, "rows": 10_000, "batch": 1_024, "capacity": 384},
     "restore": {"batch": 2_048, "interval_s": 0.05},
     "deadline_s": 60.0,
 }
@@ -156,15 +150,14 @@ class Reference:
         )
         self.n_cells = self.n_windows * self.n_keys
         self.rows_per_cell = np.bincount(self._cell, minlength=self.n_cells)
-        self._folds: dict = {}
+        self._fold: dict | None = None
         self._key_ids = {n: i for i, n in enumerate(ev.names.tolist())}
 
-    def fold(self, fn=None) -> dict:
-        """count/sum/min/max/avg per cell of ``fn(reading)`` (identity
-        when None).  Readings are never null, so count == rows."""
-        if fn not in self._folds:
-            x = self.ev.reading if fn is None else fn(self.ev.reading)
-            x = x[self._rows]
+    def fold(self) -> dict:
+        """count/sum/min/max/avg of ``reading`` per cell.  Readings are
+        never null, so count == rows."""
+        if self._fold is None:
+            x = self.ev.reading[self._rows]
             total = np.bincount(self._cell, weights=x, minlength=self.n_cells)
             lo = np.full(self.n_cells, np.inf)
             hi = np.full(self.n_cells, -np.inf)
@@ -172,11 +165,11 @@ class Reference:
             np.maximum.at(hi, self._cell, x)
             with np.errstate(invalid="ignore", divide="ignore"):
                 avg = total / self.rows_per_cell
-            self._folds[fn] = {
+            self._fold = {
                 "count": self.rows_per_cell, "sum": total, "min": lo,
                 "max": hi, "avg": avg,
             }
-        return self._folds[fn]
+        return self._fold
 
     def cells_of(self, batch) -> np.ndarray:
         """Flat cell index of every emitted row (raises on a window or key
@@ -205,7 +198,7 @@ class Reference:
 def compare(ref: Reference, batch, aggs, expected: np.ndarray,
             exact_set: bool = True) -> list[str]:
     """Problems found in one run's emitted rows.  ``aggs`` is
-    ``[(output column, kind, fn)]``; ``expected`` the mask of cells that
+    ``[(output column, kind)]``; ``expected`` the mask of cells that
     must appear.  ``exact_set`` also forbids any other cell and any cell
     twice (a bounded run emits each window once)."""
     problems: list[str] = []
@@ -222,9 +215,9 @@ def compare(ref: Reference, batch, aggs, expected: np.ndarray,
             f"{len(missing)} of {int(expected.sum())} expected (window, key)"
             " pairs missing"
         )
-    for name, kind, fn in aggs:
+    for name, kind in aggs:
         got = np.asarray(batch.column(name), dtype=np.float64)
-        want = ref.fold(fn)[kind][cells]
+        want = ref.fold()[kind][cells]
         if kind == "count":
             bad = got != want
         elif kind in ("min", "max"):
@@ -264,12 +257,6 @@ def report(leg: str, ctx, t_ctx: float, t_end: float, rows: int,
             f"strategy_resolved {m['strategy_resolved']!r}, wanted "
             f"{want_strategy!r}"
         )
-    if want_strategy == "row_shipping:pallas_dense" and not (
-        m["dense_updates"] > 0 and m["scatter_updates"] == 0
-    ):
-        # the per-batch give-way to the scatter program must not pass
-        # for the kernel
-        problems.append("batches gave way to the scatter program")
     if not m["device_steps"] > 0:
         problems.append("no device step ran")
     if m["late_rows"]:
@@ -283,7 +270,6 @@ def report(leg: str, ctx, t_ctx: float, t_end: float, rows: int,
         "bytes_h2d": m["bytes_h2d"], "bytes_d2h": m["bytes_d2h"],
         "setup_s": round(first - t_ctx, 3),
         "stream_s": round(t_end - first, 3),
-        **{k: m[k] for k in ("dense_updates", "scatter_updates") if k in m},
         **extra,
     }
     if problems:
@@ -301,7 +287,7 @@ def aggregates(*named):
     r = col("reading")
     return (
         [getattr(F, kind)(r).alias(name) for name, kind in named],
-        [(name, kind, None) for name, kind in named],
+        list(named),
     )
 
 
@@ -443,7 +429,6 @@ def leg_keyed(size, rng, keep: dict) -> list[dict]:
 
 def leg_sliding(size, rng) -> list[dict]:
     from denormalized_tpu import col
-    from denormalized_tpu.api import functions as F
     from denormalized_tpu.api.context import EngineConfig
 
     cfg = size["sliding"]
@@ -451,47 +436,13 @@ def leg_sliding(size, rng) -> list[dict]:
     ref = Reference(ev, 1000, 200)
     exprs, aggs = aggregates(("cnt", "count"), ("avg", "avg"))
     passes = (ref.rows_per_cell > 0) & (ref.fold()["avg"] > 45.0)
-    lines = []
-    for strategy, want in (("auto", "partial_merge"),
-                           ("pallas_dense", "row_shipping:pallas_dense")):
-        lines.append(run_bounded(
-            f"sliding/{strategy}", EngineConfig(device_strategy=strategy),
-            ev, ref, cfg["batch"],
-            lambda ds: ds.window(["sensor_name"], exprs, 1000, 200)
-            .filter(col("avg") > 45.0),
-            aggs, passes, want,
-        ))
-
-    # the dense kernel at the widest spec its envelope admits
-    from denormalized_tpu.ops import pallas_window as pw
-
-    cfg = size["edge"]
-    ev = Events(rng, cfg["rows"], size["rate"], cfg["keys"], "sensor_")
-    ref = Reference(ev, 1000, 1000)
-    # one aggregate over each of as many distinct value columns as the
-    # kernel takes: (kind, aggregate, engine expression, the same in numpy)
-    r = col("reading")
-    edge = [
-        ("sum", F.sum, r, None),
-        ("min", F.min, r * 2.0, lambda x: x * 2.0),
-        ("max", F.max, r + 100.0, lambda x: x + 100.0),
-        ("avg", F.avg, r * -1.0, lambda x: x * -1.0),
-    ][:pw.MAX_DENSE_VALUE_COLS]
-    exprs = [F.count(r).alias("cnt")] + [
-        make(expr).alias(kind) for kind, make, expr, _ in edge
-    ]
-    aggs = [("cnt", "count", None)] + [
-        (kind, kind, fn) for kind, _, _, fn in edge
-    ]
-    lines.append(run_bounded(
-        "sliding/pallas_dense_edge",
-        EngineConfig(device_strategy="pallas_dense",
-                     min_group_capacity=cfg["capacity"]),
+    return [run_bounded(
+        "sliding/auto", EngineConfig(device_strategy="auto"),
         ev, ref, cfg["batch"],
-        lambda ds: ds.window(["sensor_name"], exprs, 1000),
-        aggs, ref.rows_per_cell > 0, "row_shipping:pallas_dense",
-    ))
-    return lines
+        lambda ds: ds.window(["sensor_name"], exprs, 1000, 200)
+        .filter(col("avg") > 45.0),
+        aggs, passes, "partial_merge",
+    )]
 
 
 def leg_restore(size, rng, keep: dict) -> list[dict]:

@@ -108,8 +108,6 @@ class EngineConfig:
     #   'scatter'       — ship rows, device scatters them into the window
     #                     ring (general; right when host↔device bandwidth
     #                     is plentiful)
-    #   'pallas_dense'  — ship rows, dense MXU/VPU pallas kernel for
-    #                     low-cardinality aggregation (auto-falls-back)
     #   'partial_merge' — reduce each batch on host (native C++ single
     #                     pass) and ship per-(slide-unit, group) partials;
     #                     the device merges them into the ring.  Traffic
@@ -141,11 +139,6 @@ class EngineConfig:
     # with device programs for the same cores (measured 13-21% SLOWER);
     # worth A/B-ing on a real chip where device work leaves the host idle
     host_pipeline: bool = False
-    # device-side emission compaction: permute active groups to the front on
-    # device and transfer only a pow2 bucket covering them, instead of all G
-    # rows per component.  Wins when emitted windows are sparse vs the
-    # padded capacity; default off pending real-chip A/B.
-    emission_compaction: bool = False
     # on-device finalization: emission ships the FINAL output columns
     # (count/sum/min/max/avg, computed on device in accum dtype) plus an
     # active-group bitmask, instead of the raw component planes — fewer
@@ -217,8 +210,7 @@ class EngineConfig:
     # overlapping window.  This is the kernel the multi-query sharing
     # runtime (runtime/multi_query.py) always uses; setting True here
     # additionally applies it to SINGLE queries planned through the
-    # normal executor (the sliding-window fast path; A/B'd in
-    # BENCH_HISTORY.jsonl under config=multi_query).  Default False: the
+    # normal executor (the sliding-window fast path).  Default False: the
     # device ring operator stays the single-query default pending a
     # real-chip A/B — slice folds are host-side f64, so emitted floats
     # can differ from the f32 device ring in the last ulp.
@@ -267,7 +259,7 @@ def enable_compilation_cache() -> str | None:
     return the directory in use (None on the CPU backend).
 
     The one cache policy, shared by the engine (first device touch, in the
-    window-state factory), ``bench.py`` and ``chip_smoke.py``: where
+    window-state factory), the benchmark and ``chip_smoke.py``: where
     ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and no
     directory is set in code; otherwise ``<checkout>/.jax_cache`` — a fixed
     path, never a temporary name, because a cache that moves between

@@ -1,17 +1,15 @@
-"""Packaged cluster job factories for bench.py (cluster_scale) and
-tools/soak.py (--pipeline cluster).
+"""Packaged cluster job factory for tools/soak.py (--pipeline cluster).
 
 Worker processes import this by name ("denormalized_tpu.cluster.
-benchjob:<factory>"), so the factories must rebuild the identical
+benchjob:soak_job"), so the factory must rebuild the identical
 deterministic source from job_args alone — the same contract as the
-test jobs (tests/cluster_jobs.py), packaged so the committed artifacts
-(CLUSTER_SCALE.json, SOAK_CLUSTER.json) never depend on the test tree.
+test jobs (tests/cluster_jobs.py), packaged so the committed artifact
+(SOAK_CLUSTER.json) never depends on the test tree.
 
-The bench job uses int64 keys (vectorized hash lane, no per-row
-Python); the soak job uses string keys (the crc32 lane) and
-integer-valued readings so every aggregate is exact in f32
-accumulators regardless of exchange arrival order — the property the
-exactly-once comparison needs (docs/cluster.md#determinism).
+The job uses string keys (the crc32 lane) and integer-valued readings so
+every aggregate is exact in f32 accumulators regardless of exchange
+arrival order — the property the exactly-once comparison needs
+(docs/cluster.md#determinism).
 """
 
 from __future__ import annotations
@@ -31,12 +29,6 @@ from denormalized_tpu.sources.base import (
 
 T0 = 1_700_000_000_000
 
-BENCH_SCHEMA = Schema([
-    Field("k", DataType.INT64, nullable=False),
-    Field("v", DataType.FLOAT64, nullable=False),
-    Field("ts", DataType.TIMESTAMP_MS, nullable=False),
-])
-
 SOAK_SCHEMA = Schema([
     Field("k", DataType.STRING, nullable=False),
     Field("v", DataType.FLOAT64, nullable=False),
@@ -49,10 +41,9 @@ class _SynthReader(PartitionReader):
     over the key space, integer readings.  Seekable (pos-based) so
     checkpoint restore replays exactly."""
 
-    def __init__(self, part: int, args: dict, string_keys: bool) -> None:
+    def __init__(self, part: int, args: dict) -> None:
         self.part = part
         self.args = args
-        self.string_keys = string_keys
         self._pos = 0
         self._n = int(args.get("batches", 50))
         self._pace_s = float(args.get("pace_s", 0.0))
@@ -67,12 +58,8 @@ class _SynthReader(PartitionReader):
         ts = base + (i * span) // rows
         kid = (i * 7 + self.part * 3 + b) % keys
         v = ((i + self.part + b) % 16).astype(np.float64)
-        if self.string_keys:
-            k = np.array([f"s{x:05d}" for x in kid], dtype=object)
-        else:
-            k = kid
-        schema = SOAK_SCHEMA if self.string_keys else BENCH_SCHEMA
-        return RecordBatch(schema, [k, v, ts])
+        k = np.array([f"s{x:05d}" for x in kid], dtype=object)
+        return RecordBatch(SOAK_SCHEMA, [k, v, ts])
 
     def read(self, timeout_s=None):
         if self._pos >= self._n:
@@ -91,13 +78,10 @@ class _SynthReader(PartitionReader):
 
 
 class SynthSource(Source):
-    def __init__(self, args: dict, string_keys: bool) -> None:
+    def __init__(self, args: dict) -> None:
         self._args = dict(args)
-        self._string_keys = string_keys
-        self.name = "cluster_bench" if not string_keys else "cluster_soak"
-        self._schema = canonicalize_schema(
-            SOAK_SCHEMA if string_keys else BENCH_SCHEMA
-        )
+        self.name = "cluster_soak"
+        self._schema = canonicalize_schema(SOAK_SCHEMA)
 
     @property
     def schema(self) -> Schema:
@@ -109,7 +93,7 @@ class SynthSource(Source):
 
     def partitions(self) -> list[PartitionReader]:
         return [
-            _SynthReader(p, self._args, self._string_keys)
+            _SynthReader(p, self._args)
             for p in range(int(self._args.get("partitions", 4)))
         ]
 
@@ -130,23 +114,15 @@ def _pipeline(ds, args: dict):
     )
 
 
-def bench_job(args: dict) -> dict:
-    return {
-        "source": SynthSource(args, string_keys=False),
-        "pipeline": lambda ds: _pipeline(ds, args),
-        "engine": args.get("engine") or {},
-    }
-
-
 def soak_job(args: dict) -> dict:
     return {
-        "source": SynthSource(args, string_keys=True),
+        "source": SynthSource(args),
         "pipeline": lambda ds: _pipeline(ds, args),
         "engine": args.get("engine") or {},
     }
 
 
-def oracle_rows(args: dict, string_keys: bool) -> list[tuple]:
+def oracle_rows(args: dict) -> list[tuple]:
     """Uninterrupted single-process oracle → canonical sorted tuples."""
     from denormalized_tpu.api.context import Context, EngineConfig
     from denormalized_tpu.common.constants import (
@@ -157,7 +133,7 @@ def oracle_rows(args: dict, string_keys: bool) -> list[tuple]:
     config = EngineConfig()
     config.partition_watermarks = True
     ctx = Context(config)
-    src = SynthSource(args, string_keys=string_keys)
+    src = SynthSource(args)
     got = _pipeline(ctx.from_source(src), args).collect()
     out = []
     for i in range(got.num_rows):

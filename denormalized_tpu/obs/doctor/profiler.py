@@ -10,9 +10,8 @@ Opt-in and per-query: started/stopped through the doctor HTTP surface
 (``POST /queries/<id>/profile/start|stop``) or ``QueryHandle``; the
 sampler is process-wide (``_current_frames`` sees every thread) but its
 lifetime is tied to the query that asked.  Overhead is the GIL pause of
-one frame walk per tick — measured by ``bench.py run_obs_overhead``
-(``obs_profiler_ratio``) and documented in docs/observability.md; the
-default-off state costs literally nothing.
+one frame walk per tick (docs/observability.md); the default-off state
+costs literally nothing.
 """
 
 from __future__ import annotations

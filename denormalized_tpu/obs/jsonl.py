@@ -68,10 +68,10 @@ class JsonlSnapshotter:
         self._thread.join(timeout=5.0)
 
 
-# -- read side (consumers: tools/soak.py, bench.py) -----------------------
+# -- read side (consumer: tools/soak.py) ----------------------------------
 
 
 # The read-side helpers (read_stream / last_stats / merge_histogram /
 # counter_timeline) live in :mod:`denormalized_tpu.obs.readers` — a
 # stdlib-only module the soak PARENT loads by file path to stay jax-free
-# — and are re-exported here for in-process consumers (bench.py).
+# — and are re-exported here for in-process consumers.

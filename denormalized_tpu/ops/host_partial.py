@@ -2,7 +2,7 @@
 strategy.
 
 The streaming window operator can ship every decoded row to the device
-(``scatter`` / ``pallas_dense``) or reduce each batch on the host first and
+(``scatter``) or reduce each batch on the host first and
 ship only sufficient statistics (this module).  The host keeps a *stripe*:
 per-(slide-unit, sub, group) accumulators covering the slide units touched
 since the last device merge.  ``flush()`` hands the stripe to the device

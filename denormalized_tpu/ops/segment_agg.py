@@ -709,66 +709,6 @@ def read_slot(
     )
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _compact_slot(spec: WindowKernelSpec, state, slot):
-    """Device-side emission compaction: permute one window row so ACTIVE
-    groups come first, returning (active_count, permuted gids, permuted
-    component rows).  The host then transfers only a power-of-two bucket
-    covering the active prefix instead of all G entries — the win when
-    emitted windows are sparse relative to the padded group capacity."""
-    counts = jax.lax.dynamic_index_in_dim(
-        state[ROW_COUNT.label], slot, axis=0, keepdims=False
-    )
-    active = counts > 0
-    n_active = jnp.sum(active.astype(jnp.int32))
-    # stable argsort of ~active floats active gids to the front in order
-    perm = jnp.argsort(~active, stable=True)
-    out = {"__gids__": perm.astype(jnp.int32), "__count__": n_active}
-    for c in spec.components:
-        row = jax.lax.dynamic_index_in_dim(
-            state[c.label], slot, axis=0, keepdims=False
-        )
-        out[c.label] = row[perm]
-    return out
-
-
-def read_slot_compact(
-    spec: WindowKernelSpec, state: dict[str, jax.Array], slot,
-    capacity: int | None = None,
-) -> tuple[np.ndarray, dict[str, np.ndarray]]:
-    """→ (active gids ascending, component rows aligned to them).
-
-    Two-phase transfer: the scalar active count crosses first, then a
-    pow2-bucketed prefix of the compacted buffers — one compiled program
-    per bucket size, ≤ log2(G) programs total.  ``capacity`` overrides the
-    spec's group width for sharded layouts whose state is globally shaped
-    while the spec carries the per-device shard."""
-    compacted = _compact_slot(spec, state, jnp.asarray(slot, jnp.int32))
-    k = int(jax.device_get(compacted["__count__"]))
-    if k == 0:
-        return np.empty(0, dtype=np.int32), {
-            c.label: np.empty(
-                0, dtype=np.asarray(jax.device_get(spec.init_value(c))).dtype
-            )
-            for c in spec.components
-        }
-    bucket = min(
-        1 << (k - 1).bit_length(), capacity or spec.group_capacity
-    )
-    host = jax.device_get(
-        {
-            name: jax.lax.slice_in_dim(arr, 0, bucket)
-            for name, arr in compacted.items()
-            if name != "__count__"
-        }
-    )
-    gids = host.pop("__gids__")[:k]
-    rows = {label: arr[:k] for label, arr in host.items()}
-    # ascending gid order (argsort floated actives in gid order already,
-    # but make the contract explicit for callers)
-    return gids, rows
-
-
 def export_state(state: dict[str, jax.Array]) -> dict[str, np.ndarray]:
     """Full device→host snapshot (checkpointing / capacity growth)."""
     return jax.device_get(state)

@@ -44,6 +44,13 @@ from denormalized_tpu.parallel.mesh import KEY_AXIS, SLICE_AXIS, shard_map
 from denormalized_tpu.runtime.tracing import NULL_CLOCK
 
 
+def _prewarm() -> bool:
+    """Whether the backends compile their program ladders up front: on a
+    TPU, where an unseen shape compiling mid-stream stalls the stream for
+    seconds.  Elsewhere compiles are cheap and the ladders are skipped."""
+    return jax.default_backend() == "tpu"
+
+
 class WindowStateBackend:
     """Interface the window operator drives."""
 
@@ -53,8 +60,8 @@ class WindowStateBackend:
     # ``accumulate``/``flush_pending`` instead of per-batch ``update``
     accumulates_host: bool = False
     # link-traffic accounting (numpy-payload bytes handed to/from the
-    # device): these feed the bytes/s and link-saturation fields of the
-    # bench JSON and chip_smoke.py's per-leg lines
+    # device): these feed the benchmark's bytes-per-event metrics and
+    # chip_smoke.py's per-leg lines
     bytes_h2d: int = 0
     bytes_d2h: int = 0
     # the driving operator's phase clock (runtime/tracing.py): the stripe
@@ -73,14 +80,16 @@ class WindowStateBackend:
         }
 
     def carry_stripe_counters(self, old: "WindowStateBackend") -> None:
-        """Take over the stripe counts of the backend this one replaces."""
+        """Take over the stripe counts and the merge count of the backend
+        this one replaces."""
         if self.accumulates_host and old.accumulates_host:
             self._stripe.carry_counters(old._stripe)
+            self.merges += old.merges
 
     @property
     def strategy_name(self) -> str:
-        """What actually executes — defined next to each backend so a
-        rename or new subclass cannot silently mislabel the bench's
+        """What actually executes — a constant next to each backend, so a
+        rename or new subclass cannot silently mislabel ``metrics()``'s
         ``strategy_resolved`` field."""
         return type(self).__name__
 
@@ -89,10 +98,7 @@ class WindowStateBackend:
         """Total group-id capacity visible to the host interner."""
         raise NotImplementedError
 
-    def update(
-        self, values, colvalid, win_rel, rem, gid, row_valid, base_mod,
-        min_win_rel: int | None = None, max_win_rel: int | None = None,
-    ):
+    def update(self, values, colvalid, win_rel, rem, gid, row_valid, base_mod):
         raise NotImplementedError
 
     def flush_pending(self) -> None:
@@ -148,12 +154,6 @@ class WindowStateBackend:
     def read_slot(self, slot: int) -> dict[str, np.ndarray]:
         raise NotImplementedError
 
-    def read_slot_compact(self, slot: int):
-        """(active gids, aligned component rows) — or None when this layout
-        doesn't implement device-side compaction (caller falls back to the
-        full read_slot)."""
-        return None
-
     def reset_slot(self, slot: int) -> None:
         raise NotImplementedError
 
@@ -175,19 +175,14 @@ class WindowStateBackend:
 
 
 class SingleDeviceWindowState(WindowStateBackend):
-    def __init__(self, spec: sa.WindowKernelSpec, device_strategy: str = "scatter"):
+    strategy_name = "row_shipping:scatter"
+
+    def __init__(self, spec: sa.WindowKernelSpec):
         self.spec = spec
         self._state = sa.init_state(spec)
-        self.device_strategy = device_strategy
-        # actual dispatch counts: 'pallas_dense'/'auto' fall back to the
-        # scatter program per batch when the kernel doesn't support the
-        # spec or the batch shape — strategy_name reports what RAN
-        self.dense_updates = 0
-        self.scatter_updates = 0
-        self._pallas_interpret = jax.default_backend() != "tpu"
 
     def prepare_gather(self) -> None:
-        if not self._pallas_interpret:
+        if _prewarm():
             # pre-compile emission gather programs for the block sizes and
             # group buckets the trigger will actually request: an unseen
             # (n, g_bucket, lean) tuple compiling mid-stream stalls the
@@ -212,65 +207,15 @@ class SingleDeviceWindowState(WindowStateBackend):
                             )
 
     @property
-    def strategy_name(self) -> str:
-        if self.device_strategy == "scatter":
-            return "row_shipping:scatter"
-        # 'pallas_dense' / 'auto': report the dispatch that actually ran
-        if self.dense_updates and self.scatter_updates:
-            return "row_shipping:pallas_dense+scatter"
-        if self.dense_updates:
-            return "row_shipping:pallas_dense"
-        if self.scatter_updates:
-            return "row_shipping:scatter"
-        return f"row_shipping:{self.device_strategy} (no batches yet)"
-
-    @property
     def group_capacity(self) -> int:
         return self.spec.group_capacity
 
-    def update(
-        self, values, colvalid, win_rel, rem, gid, row_valid, base_mod,
-        min_win_rel: int | None = None, max_win_rel: int | None = None,
-    ):
+    def update(self, values, colvalid, win_rel, rem, gid, row_valid, base_mod):
         self.bytes_h2d += sum(
             int(np.asarray(a).nbytes)
             for a in (values, colvalid, win_rel, rem, gid, row_valid)
             if a is not None
         )
-        # 'auto' only engages the dense path on real TPU hardware: in
-        # interpret mode (CPU) the pallas kernel is orders of magnitude
-        # slower than the scatter path, so auto means scatter there.
-        # Explicit 'pallas_dense' still honors interpret for parity tests.
-        try_dense = self.device_strategy == "pallas_dense" or (
-            self.device_strategy == "auto" and not self._pallas_interpret
-        )
-        if try_dense and min_win_rel is not None:
-            from denormalized_tpu.ops import pallas_window as pw
-
-            span_ok = (
-                max_win_rel is not None
-                and max_win_rel - max(min_win_rel - (self.spec.length_units - 1), 0)
-                < pw.K_ACTIVE
-            )
-            tile_ok = np.shape(values)[0] % pw.TILE == 0
-            if pw.dense_supported(self.spec) and span_ok and tile_ok:
-                self.dense_updates += 1
-                lo = max(min_win_rel - (self.spec.length_units - 1), 0)
-                self._state = pw.dense_update(
-                    self.spec,
-                    self._state,
-                    jnp.asarray(values),
-                    jnp.asarray(colvalid),
-                    jnp.asarray(win_rel),
-                    jnp.asarray(rem),
-                    jnp.asarray(gid),
-                    jnp.asarray(row_valid),
-                    jnp.asarray(base_mod, dtype=jnp.int32),
-                    min_win_rel=lo,
-                    interpret=self._pallas_interpret,
-                )
-                return
-        self.scatter_updates += 1
         self._state = sa.update_state(
             self.spec,
             self._state,
@@ -287,25 +232,6 @@ class SingleDeviceWindowState(WindowStateBackend):
         out = sa.read_slot(self.spec, self._state, slot)
         self.bytes_d2h += sum(int(a.nbytes) for a in out.values())
         return out
-
-    def read_slot_compact(self, slot: int):
-        gids, rows = sa.read_slot_compact(self.spec, self._state, slot)
-        self._count_compact_d2h(gids, rows, self.spec.group_capacity)
-        return gids, rows
-
-    def _count_compact_d2h(self, gids, rows, capacity) -> None:
-        """Wire accounting for a compact read: the transfer is the pow2
-        BUCKET covering the k active groups (read_slot_compact truncates
-        to k on host AFTER the device_get), so counting the returned
-        arrays would undercount by up to ~2x."""
-        k = len(gids)
-        if k == 0:
-            return
-        bucket = min(1 << (k - 1).bit_length(), capacity)
-        per_elem = gids.dtype.itemsize + sum(
-            a.dtype.itemsize for a in rows.values()
-        )
-        self.bytes_d2h += bucket * per_elem
 
     def reset_slot(self, slot: int) -> None:
         self._state = sa.reset_slot(
@@ -352,7 +278,7 @@ class SingleDeviceWindowState(WindowStateBackend):
 
     def prepare_finals(self, agg_specs: tuple) -> None:
         self._finals_specs = tuple(agg_specs)
-        if not getattr(self, "_pallas_interpret", True):
+        if _prewarm():
             # pre-compile the finals ladder like the component-gather one
             # in __init__: an unseen (n, bucket) pair compiling mid-stream
             # stalls the stream for seconds at a wide ring.  group_capacity
@@ -431,7 +357,7 @@ class _HostPartialMixin:
         self._stripe = HostPartialStripe(self.spec, stripe_group_capacity)
         self._pending_base_mod = 0
         self.merges = 0
-        if jax.default_backend() == "tpu":
+        if _prewarm():
             # pre-compile every merge program with a no-op stripe: which
             # bucket a flush lands in depends on runtime pacing, and an
             # unseen size mid-stream is a multi-second compile.  Both
@@ -577,7 +503,7 @@ class PartialMergeWindowState(_HostPartialMixin, SingleDeviceWindowState):
     identical to the scatter path."""
 
     def __init__(self, spec: sa.WindowKernelSpec):
-        super().__init__(spec, "scatter")
+        super().__init__(spec)
         self._init_host_partial(spec.group_capacity)
 
     def _merge(
@@ -683,10 +609,7 @@ class KeyShardedWindowState(WindowStateBackend):
     def group_capacity(self) -> int:
         return self.spec.group_capacity * self.n
 
-    def update(
-        self, values, colvalid, win_rel, rem, gid, row_valid, base_mod,
-        min_win_rel=None, max_win_rel=None,
-    ):
+    def update(self, values, colvalid, win_rel, rem, gid, row_valid, base_mod):
         self._state = _key_sharded_update(
             self.spec,
             self.mesh,
@@ -778,7 +701,6 @@ class KeyShardedPartialMergeWindowState(_HostPartialMixin, KeyShardedWindowState
 
     def __init__(self, spec: sa.WindowKernelSpec, mesh: Mesh):
         super().__init__(spec, mesh)
-        self._pallas_interpret = jax.default_backend() != "tpu"
         # stripe spans the GLOBAL group space
         self._init_host_partial(self.group_capacity)
 
@@ -791,15 +713,14 @@ class KeyShardedPartialMergeWindowState(_HostPartialMixin, KeyShardedWindowState
             self._state, jnp.asarray(packed),
         )
 
-    # fused async gather+reset + on-device finalization + emission
-    # compaction: identical machinery to the single-device backend
+    # fused async gather+reset + on-device finalization: identical
+    # machinery to the single-device backend
     # (self.group_capacity is the global width here; GSPMD partitions the
     # programs over the key sharding)
     read_reset_block = SingleDeviceWindowState.read_reset_block
     read_reset_block_start = SingleDeviceWindowState.read_reset_block_start
     read_reset_block_finish = SingleDeviceWindowState.read_reset_block_finish
     _live_bucket = SingleDeviceWindowState._live_bucket
-    _count_compact_d2h = SingleDeviceWindowState._count_compact_d2h
     prepare_gather = SingleDeviceWindowState.prepare_gather
     prepare_finals = SingleDeviceWindowState.prepare_finals
     read_reset_block_finals_start = (
@@ -807,15 +728,6 @@ class KeyShardedPartialMergeWindowState(_HostPartialMixin, KeyShardedWindowState
     )
     export_start = SingleDeviceWindowState.export_start
     export_finish = SingleDeviceWindowState.export_finish
-
-    def read_slot_compact(self, slot: int):
-        # state is globally shaped; the spec carries the per-device shard,
-        # so the bucket cap must come from the global width
-        gids, rows = sa.read_slot_compact(
-            self.spec, self._state, slot, capacity=self.group_capacity
-        )
-        self._count_compact_d2h(gids, rows, self.group_capacity)
-        return gids, rows
 
 
 # ---------------------------------------------------------------------------
@@ -984,10 +896,7 @@ class PartialFinalWindowState(WindowStateBackend):
     def group_capacity(self) -> int:
         return self.spec.group_capacity
 
-    def update(
-        self, values, colvalid, win_rel, rem, gid, row_valid, base_mod,
-        min_win_rel=None, max_win_rel=None,
-    ):
+    def update(self, values, colvalid, win_rel, rem, gid, row_valid, base_mod):
         # rows must split evenly over the mesh: bucketed batches are powers
         # of two >= mesh size, so this holds by construction
         self._state = _partial_update(
@@ -1127,10 +1036,7 @@ class TwoLevelWindowState(WindowStateBackend):
     def group_capacity(self) -> int:
         return self.spec.group_capacity * self.n_keys
 
-    def update(
-        self, values, colvalid, win_rel, rem, gid, row_valid, base_mod,
-        min_win_rel=None, max_win_rel=None,
-    ):
+    def update(self, values, colvalid, win_rel, rem, gid, row_valid, base_mod):
         # rows split S ways (bucketed pow2 batches >= mesh rows by
         # construction, same invariant as PartialFinalWindowState)
         self._state = _two_level_update(
@@ -1185,12 +1091,10 @@ def make_sharded_state(
 ) -> WindowStateBackend:
     """Pick a layout: small state → Partial/Final (duplicate it, shard rows);
     large state → key-sharded (shard it, broadcast rows)."""
-    if device_strategy not in (
-        "scatter", "pallas_dense", "auto", "partial_merge"
-    ):
+    if device_strategy not in ("scatter", "auto", "partial_merge"):
         raise ValueError(
             f"unknown device strategy {device_strategy!r} (expected "
-            "'scatter', 'pallas_dense', 'partial_merge', or 'auto')"
+            "'scatter', 'partial_merge', or 'auto')"
         )
     # first point that touches the device, and the prewarm ladders below
     # are the bulk of what a stream compiles
@@ -1206,24 +1110,22 @@ def make_sharded_state(
         # S2); for CPU JAX it rests on host runs in which the native
         # single-pass reducer (native/partial_agg.cpp) beat shipping
         # rows through XLA's scatter adds.  Row shipping remains
-        # available explicitly ('scatter' / 'pallas_dense') and stays
-        # the 'auto' pick on backends neither covers (e.g. a GPU).
+        # available explicitly ('scatter') and stays the 'auto' pick on
+        # backends neither covers (e.g. a GPU).
         # ... except f64 accumulators on CPU: the partial_merge stripe
         # transports f64 as an f32 hi/lo split and refuses finite sums
         # beyond f32 range (ops/host_partial.py), while CPU XLA scatter
         # keeps f64 end-to-end — don't let 'auto' turn a working f64
         # workload into a runtime OverflowError.
-        if device_strategy == "auto" and (
-            spec.accum_dtype == jnp.float64
-            and jax.default_backend() == "cpu"
-        ):
-            return SingleDeviceWindowState(spec, "scatter")
+        backend = jax.default_backend()
+        f64_on_cpu = spec.accum_dtype == jnp.float64 and backend == "cpu"
         if device_strategy == "partial_merge" or (
             device_strategy == "auto"
-            and jax.default_backend() in ("tpu", "cpu")
+            and backend in ("tpu", "cpu")
+            and not f64_on_cpu
         ):
             return PartialMergeWindowState(spec)
-        return SingleDeviceWindowState(spec, device_strategy)
+        return SingleDeviceWindowState(spec)
     if SLICE_AXIS in mesh.axis_names:
         # 2-D (slices, keys) mesh: the two_level layout is the only one
         # shaped for it

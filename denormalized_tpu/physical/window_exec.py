@@ -409,7 +409,6 @@ class StreamingWindowExec(ExecOperator):
         *,
         accum_dtype=jnp.float32,
         compensated_sums: bool = False,
-        emission_compaction: bool = False,
         device_finalize: bool = True,
         min_group_capacity: int = 128,
         min_window_slots: int = 16,
@@ -494,7 +493,6 @@ class StreamingWindowExec(ExecOperator):
             comps = sa.with_compensation(comps)
         components = tuple(comps)
         self._compensated = compensated_sums
-        self._emission_compaction = emission_compaction
 
         self._grouped = len(self.group_exprs) > 0
         self._interner = GroupInterner(len(self.group_exprs)) if self._grouped else None
@@ -522,13 +520,10 @@ class StreamingWindowExec(ExecOperator):
         # active bitmask instead of raw component planes (see
         # segment_agg._finals_and_reset).  Only when every aggregate is
         # finalizable and the backend layout supports it (it returns None
-        # from read_reset_block_finals_start otherwise).  Compaction takes
-        # a different trigger branch entirely — preparing finals under it
-        # would compile programs that never run.
+        # from read_reset_block_finals_start otherwise).
         self._finals_specs = (
             tuple(self._agg_specs)
             if device_finalize
-            and not emission_compaction
             and sa.finals_possible(tuple(self._agg_specs))
             else None
         )
@@ -640,10 +635,6 @@ class StreamingWindowExec(ExecOperator):
             # deltas in _flush would miss
             m["partial_merges"] = self._backend.merges
             m["device_steps"] = self._backend.merges
-        elif hasattr(self._backend, "dense_updates"):
-            # single-device row shipping: the program each batch took
-            m["dense_updates"] = self._backend.dense_updates
-            m["scatter_updates"] = self._backend.scatter_updates
         m["bytes_h2d"] = self._backend.bytes_h2d
         m["bytes_d2h"] = self._backend.bytes_d2h
         # what the stripe's flushes cost: cells with rows, cells sent
@@ -668,9 +659,8 @@ class StreamingWindowExec(ExecOperator):
             self._interner.stats() if self._interner is not None
             else dict.fromkeys(INTERN_STATS, 0)
         )
-        # what 'auto' actually chose AND what actually dispatched (a
-        # report must RECORD the resolved strategy, not just the
-        # request) — each backend labels itself
+        # what 'auto' actually chose (a report must RECORD the resolved
+        # strategy, not just the request) — each backend labels itself
         m["strategy_resolved"] = self._backend.strategy_name
         return m
 
@@ -762,11 +752,10 @@ class StreamingWindowExec(ExecOperator):
     def _prepare_emission(self) -> None:
         """Pre-compile the emission programs this plan can reach: the
         finals ladder or the component gather's, never both (the trigger
-        takes the gather only where no finals were prepared), and neither
-        under compaction, which reads slot by slot."""
+        takes the gather only where no finals were prepared)."""
         if self._finals_specs is not None:
             self._backend.prepare_finals(self._finals_specs)
-        elif not self._emission_compaction:
+        else:
             self._backend.prepare_gather()
 
     def _grow(self, *, window_slots: int | None = None, group_capacity: int | None = None):
@@ -816,19 +805,12 @@ class StreamingWindowExec(ExecOperator):
 
     def _carry_counters(self, old_backend) -> None:
         """Link-traffic and merge counters live on the backend instance;
-        a grow/restore replacement must carry them or the bench's
+        a grow/restore replacement must carry them or ``metrics()``'s
         bytes_h2d/bytes_d2h reflect only the post-last-growth tail —
         exactly wrong for high-cardinality runs that grow repeatedly."""
         self._backend.bytes_h2d += old_backend.bytes_h2d
         self._backend.bytes_d2h += old_backend.bytes_d2h
         self._backend.carry_stripe_counters(old_backend)
-        for counter in ("merges", "dense_updates", "scatter_updates"):
-            if hasattr(self._backend, counter) and hasattr(old_backend, counter):
-                setattr(
-                    self._backend, counter,
-                    getattr(self._backend, counter)
-                    + getattr(old_backend, counter),
-                )
 
     def _ensure_capacity(self, max_win_rel: int):
         # gids are interner-dense and this runs after interning, before the
@@ -1091,15 +1073,6 @@ class StreamingWindowExec(ExecOperator):
                     pad(gid),
                     row_valid,
                     first % self._spec.window_slots,
-                    # span of the ON-TIME rows only: late rows (win_rel < 0)
-                    # are dropped by both kernels and must not widen the
-                    # dense-path span
-                    min_win_rel=int(
-                        win_rel64[win_rel64 >= 0].min()
-                        if (win_rel64 >= 0).any()
-                        else 0
-                    ),
-                    max_win_rel=int(win_rel64.max()),
                 )
             self._metrics["device_steps"] += 1
 
@@ -1236,16 +1209,15 @@ class StreamingWindowExec(ExecOperator):
                 yield b
 
     def _emit_window_batch(
-        self, j: int, active: np.ndarray, finals_of, gids=None
+        self, j: int, active: np.ndarray, finals_of
     ) -> RecordBatch | None:
         """Window ``j``'s emission batch — the rows of the positions
         ``active`` marks, ascending — or None when it marks none.  Position
-        ``p`` is group ``p``, or ``gids[p]`` where the planes are compacted;
-        ``finals_of(lo, hi, local)`` gives the output columns of the
-        positions ``lo + local``; the batch is filled ``EMIT_CHUNK_GROUPS``
-        positions at a time.  ONE batch a window: a consumer may take a
-        window's first row for the whole window delivered (the benchmark's
-        harness does)."""
+        ``p`` is group ``p``; ``finals_of(lo, hi, local)`` gives the output
+        columns of the positions ``lo + local``; the batch is filled
+        ``EMIT_CHUNK_GROUPS`` positions at a time.  ONE batch a window: a
+        consumer may take a window's first row for the whole window
+        delivered (the benchmark's harness does)."""
         m = int(np.count_nonzero(active))
         if m == 0:
             return None
@@ -1259,8 +1231,7 @@ class StreamingWindowExec(ExecOperator):
             if end == off:
                 continue
             if self._grouped:
-                g = local + lo if gids is None else gids[lo:hi][local]
-                keys = self._interner.keys_of(g.astype(np.int32))
+                keys = self._interner.keys_of((local + lo).astype(np.int32))
                 for c, kv in zip(cols, keys):
                     c[off:end] = kv
             for c, arr in zip(cols[n_keys:], finals_of(lo, hi, local)):
@@ -1342,13 +1313,6 @@ class StreamingWindowExec(ExecOperator):
         emit) the ``n_close`` windows the watermark has closed."""
         if self._backend.accumulates_host:
             self._flush()
-        if self._emission_compaction:
-            while self._first_open * self.slide_ms + self.length_ms <= self._watermark_ms:
-                b = self._emit_window(self._first_open)
-                self._first_open += 1
-                if b is not None:
-                    yield b
-            return
         while n_close > 0:
             # pow2 block sizes bound the compiled gather variants
             n = 1 << min(3, (n_close).bit_length() - 1)
@@ -1400,37 +1364,16 @@ class StreamingWindowExec(ExecOperator):
         self._backend.flush_pending()
 
     def _emit_window(self, j: int) -> RecordBatch | None:
-        with self._phases.phase("finalize", window=j, n=1):
-            return self._emit_window_inner(j)
-
-    def _emit_window_inner(self, j: int) -> RecordBatch | None:
+        """Read, reset and finalize ring slot ``j`` alone — the end-of-stream
+        flush of the windows the watermark never closed."""
         slot = j % self._spec.window_slots
-        compacted = None
-        with self._phases.phase("d2h_wait", window=j, n=1), span(
-            "window.emit", op=self.name, window=j * self.slide_ms
-        ):
-            if self._emission_compaction:
-                compacted = self._backend.read_slot_compact(slot)
-            if compacted is not None:
-                gids32, rows = compacted
-                self._backend.reset_slot(slot)
-            else:
+        with self._phases.phase("finalize", window=j, n=1):
+            with self._phases.phase("d2h_wait", window=j, n=1), span(
+                "window.emit", op=self.name, window=j * self.slide_ms
+            ):
                 rows = self._backend.read_slot(slot)
                 self._backend.reset_slot(slot)
-        if compacted is not None:
-            # rows hold ONLY the active groups, already in ascending gid
-            # order (read_slot_compact's contract).  Apply the same
-            # interner-bound guard the full path applies before keys_of.
-            ngroups = len(self._interner) if self._grouped else 1
-            in_bounds = gids32 < ngroups
-            if not in_bounds.all():
-                gids32 = gids32[in_bounds]
-                rows = {label: arr[in_bounds] for label, arr in rows.items()}
-            return self._emit_window_batch(
-                j, np.ones(len(gids32), dtype=bool),
-                self._finals_of(rows), gids=gids32,
-            )
-        return self._finalize_rows(j, rows)
+            return self._finalize_rows(j, rows)
 
     def _finalize_rows(self, j: int, rows: dict) -> RecordBatch | None:
         """Finalize one window's component planes into an emission batch

@@ -155,7 +155,6 @@ class Planner:
                     min_window_slots=self.config.min_window_slots,
                     min_batch_bucket=self.config.min_batch_bucket,
                     emit_on_close=self.config.emit_on_close,
-                    emission_compaction=self.config.emission_compaction,
                     device_finalize=self.config.device_finalize,
                     mesh=mesh,
                     shard_strategy=self.config.shard_strategy,

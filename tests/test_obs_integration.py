@@ -303,10 +303,8 @@ def test_metrics_disabled_engine_runs_clean(make_batch, registry):
 
 @pytest.mark.slow
 def test_metrics_overhead_within_noise(make_batch):
-    """Overhead guard (unit-scale twin of bench.py run_obs_overhead):
-    default-level metrics must not measurably slow the windowed
-    pipeline.  Threshold is deliberately loose — the authoritative gate
-    is the bench-scale run against the r5 baseline."""
+    """Overhead guard: default-level metrics must not measurably slow the
+    windowed pipeline.  Threshold is deliberately loose."""
     import time as _time
 
     batches = _batches(make_batch, n_batches=40, rows=2000)

@@ -283,71 +283,3 @@ def test_trailing_nul_normalization_consistent():
     fb._h = None  # force fallback
     ids_fb = fb.intern_array(keys)
     assert ids_native.tolist() == ids_fb.tolist() == [0, 0]
-
-
-def test_emission_compaction_parity(make_batch):
-    """emission_compaction=True must be output-identical to the full-read
-    path — incl. a SPARSE shape (large padded capacity, few active keys),
-    where the compacted transfer is the win."""
-    import numpy as np
-
-    from denormalized_tpu import Context, col
-    from denormalized_tpu.api import functions as F
-    from denormalized_tpu.api.context import EngineConfig
-    from denormalized_tpu.common.constants import WINDOW_START_COLUMN
-    from denormalized_tpu.sources.memory import MemorySource
-
-    rng = np.random.default_rng(17)
-    t0 = 1_700_000_000_000
-    batches = []
-    for b in range(10):
-        n = 512
-        ts = np.sort(t0 + b * 400 + rng.integers(0, 400, n))
-        keys = np.array(
-            [f"k{i}" for i in rng.integers(0, 9, n)], dtype=object
-        )
-        batches.append(make_batch(ts, keys, rng.normal(5, 2, n)))
-
-    def run(compaction):
-        ctx = Context(
-            EngineConfig(
-                emission_compaction=compaction,
-                # sparse: capacity padded far beyond the 9 live keys
-                min_group_capacity=4096,
-            )
-        )
-        res = (
-            ctx.from_source(
-                MemorySource.from_batches(
-                    batches, timestamp_column="occurred_at_ms"
-                )
-            )
-            .window(
-                ["sensor_name"],
-                [
-                    F.count(col("reading")).alias("c"),
-                    F.sum(col("reading")).alias("s"),
-                    F.min(col("reading")).alias("mn"),
-                    F.avg(col("reading")).alias("a"),
-                ],
-                1000,
-                500,
-            )
-            .collect()
-        )
-        return {
-            (
-                int(res.column(WINDOW_START_COLUMN)[i]),
-                res.column("sensor_name")[i],
-            ): (
-                int(res.column("c")[i]),
-                round(float(res.column("s")[i]), 3),
-                round(float(res.column("mn")[i]), 5),
-                round(float(res.column("a")[i]), 5),
-            )
-            for i in range(res.num_rows)
-        }
-
-    off = run(False)
-    on = run(True)
-    assert on == off and len(on) > 0

@@ -2,11 +2,11 @@
 
 libtpu can build a device topology without hardware
 (``jax.experimental.topologies``), and lowering against one of its devices
-runs the real XLA:TPU and Mosaic compilers.  Interpret mode on the CPU
-never sees a Mosaic refusal (scoped-VMEM overflow, an unsupported layout),
-so this is the only guard a CPU run gives the Pallas kernel before a chip
-call — and it costs no chip time.  Skipped where the topology cannot be
-built (no libtpu).
+runs the real XLA:TPU compiler.  A CPU run never sees what that compiler
+refuses (a program that does not fit the device's memory, an unsupported
+layout), so this is the only guard a CPU run gives the main path's
+programs before a chip call — and it costs no chip time.  Skipped where
+the topology cannot be built (no libtpu).
 """
 
 import dataclasses
@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from denormalized_tpu.ops import pallas_window as pw
 from denormalized_tpu.ops import segment_agg as sa
 from denormalized_tpu.ops.host_partial import HostPartialStripe
 
@@ -62,49 +61,6 @@ def _state(spec, sharding):
         )
         for c in spec.components
     }
-
-
-def _compile_dense(sharding, V, KREL, G):
-    pw._dense_partials.lower(
-        _sds(sharding, (B, V), jnp.float32),
-        _sds(sharding, (B, V), jnp.bool_),
-        _sds(sharding, (B, KREL), jnp.int32),
-        _sds(sharding, (B,), jnp.int32),
-        G=G, V=V, KREL=KREL, interpret=False,
-    ).compile()
-
-
-def test_dense_kernel_compiles_over_its_envelope(v5e):
-    """Every spec ``dense_supported`` admits reaches Mosaic as one of
-    (value cols) x (group tile) kernel bodies: the fan-out KREL is folded
-    outside the kernel and G only sets the grid.  A body's VMEM grows with
-    both, so each value-column count is compiled at the widest tile and
-    each narrower tile once; then the widest grid, where the kernel used
-    to overflow, at several fan-outs."""
-    tiles = sorted(
-        {pw.group_tile(g) for g in range(128, pw.MAX_DENSE_GROUPS + 1, 128)}
-    )
-    assert tiles == [128, 256, pw.GROUP_TILE]
-    for V in range(1, pw.MAX_DENSE_VALUE_COLS + 1):
-        _compile_dense(v5e, V, 1, pw.GROUP_TILE)
-    for gt in tiles[:-1]:
-        _compile_dense(v5e, 1, 1, gt)
-    for V, KREL in ((1, 1), (2, 1), (pw.MAX_DENSE_VALUE_COLS, pw.K_ACTIVE)):
-        _compile_dense(v5e, V, KREL, pw.MAX_DENSE_GROUPS)
-
-
-def test_dense_supported_stays_inside_the_compiled_envelope():
-    spec, _ = _simple_spec()
-
-    def admits(**kw):
-        return pw.dense_supported(dataclasses.replace(spec, **kw))
-
-    assert admits(group_capacity=pw.MAX_DENSE_GROUPS)
-    assert not admits(group_capacity=pw.MAX_DENSE_GROUPS + 128)
-    assert admits(num_value_cols=pw.MAX_DENSE_VALUE_COLS)
-    assert not admits(num_value_cols=pw.MAX_DENSE_VALUE_COLS + 1)
-    assert admits(slide_ms=1000 // pw.K_ACTIVE)
-    assert not admits(slide_ms=100)  # ten windows per row
 
 
 @pytest.mark.parametrize("slide_ms", [1000, 200])

@@ -28,10 +28,6 @@ then
     fail=1
 fi
 
-echo "== bench trend gate (BENCH_HISTORY.jsonl, latest vs previous)"
-python tools/bench_trend.py --gate --config simple --max-regress-pct 25 \
-    || fail=1
-
 echo "== fault-site docs drift"
 table="$(python -m tools.dnzlint --fault-site-table)"
 if ! python - "$table" <<'EOF'

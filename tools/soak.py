@@ -2392,7 +2392,7 @@ def _cluster_cell(args, partial: bool) -> dict:
           f"partitions, {batches} batches/partition "
           f"(~{per_worker_wall:.0f}s of stream per worker)",
           file=sys.stderr)
-    oracle = benchjob.oracle_rows(job_args, string_keys=True)
+    oracle = benchjob.oracle_rows(job_args)
     work = tempfile.mkdtemp(prefix="soak_cluster_")
     victim = n_workers - 1
     # one torn exchange frame mid-stream, detected by the receiver's
