@@ -8,7 +8,10 @@
 // width, block boundaries, the id store), JSON parser (escapes, nulls,
 // duplicates, malformed rows), the state observatory's sketch pass (empty
 // and refused calls, eight slots, ids at the top of their width, a table
-// as full as it gets, sampled counts, watches on threads of their own).
+// as full as it gets, sampled counts, watches on threads of their own),
+// the window operator's time arithmetic (floor division before the epoch
+// and at both ends of int64, no rows, a buffer longer than the batch, the
+// late / dropped / straddle counts) and the host reducer behind it.
 
 #include <atomic>
 #include <cassert>
@@ -25,6 +28,7 @@
 #include "json_parser.cpp"
 #include "kafka_client.cpp"
 #include "lsmkv.cpp"
+#include "partial_agg.cpp"
 #include "sketch_update.cpp"
 
 static void test_lsm(const char* dir) {
@@ -1217,6 +1221,128 @@ static void test_sketch_hammer() {
   printf("sketch hammer ok\n");
 }
 
+// floor(t / s) and t - floor(t / s) * s, the plain way, for s > 0
+static void floor_divmod(int64_t t, int64_t s, int64_t* q, int64_t* r) {
+  *q = t / s;
+  *r = t % s;
+  if (*r < 0) {
+    *r += s;
+    --*q;
+  }
+}
+
+static void test_window_project() {
+  const int64_t GUARD = 0x5a5a5a5a5a5a5a5a;
+  {
+    // no rows, and a slide the pass does not take: nothing is written
+    int64_t units[1] = {GUARD}, stats[3] = {GUARD, GUARD, GUARD};
+    int32_t rem[1] = {-7};
+    int64_t t = 5;
+    window_project_units(&t, 0, 200, units, rem, stats);
+    window_project_units(&t, 1, 0, units, rem, stats);
+    assert(units[0] == GUARD && rem[0] == -7 && stats[0] == GUARD);
+    int64_t rstats[3];
+    window_project_rebase(units, 0, 3, 1, 4, units, nullptr, rstats);
+    assert(rstats[0] == 0 && rstats[1] == 0 && rstats[2] == 0);
+  }
+  // event times before the epoch, across it, unsorted, and at both ends of
+  // int64 (a slide of 1 keeps the extremes as units); the buffers are
+  // longer than the batch and must stay untouched past it
+  const int64_t times[] = {-1,        0,         -200,      -201,
+                           199,       200,       -1000001,  7,
+                           INT64_MIN, INT64_MAX, INT64_MIN + 1, -1};
+  const int64_t n = (int64_t)(sizeof times / sizeof times[0]);
+  const int64_t slides[] = {1, 7, 200, 1000, 10000, INT64_MAX};
+  std::vector<int64_t> units((size_t)n + 4), win_rel((size_t)n + 4);
+  std::vector<int32_t> rem((size_t)n + 4);
+  std::vector<uint8_t> keep((size_t)n + 4);
+  for (int64_t slide : slides) {
+    for (int64_t m : {n, n - 4, (int64_t)1}) {  // the same buffers, reused
+      std::fill(units.begin(), units.end(), GUARD);
+      std::fill(rem.begin(), rem.end(), -7);
+      int64_t stats[4] = {0, 0, 0, GUARD};
+      window_project_units(times, m, slide, units.data(), rem.data(), stats);
+      assert(stats[3] == GUARD);  // three numbers, no more
+      int64_t u_min = INT64_MAX, u_max = INT64_MIN, t_min = INT64_MAX;
+      for (int64_t i = 0; i < m; i++) {
+        int64_t q, r;
+        floor_divmod(times[i], slide, &q, &r);
+        assert(units[(size_t)i] == q);
+        assert(rem[(size_t)i] == (int32_t)r);
+        u_min = q < u_min ? q : u_min;
+        u_max = q > u_max ? q : u_max;
+        t_min = times[i] < t_min ? times[i] : t_min;
+      }
+      assert(stats[0] == u_min && stats[1] == u_max && stats[2] == t_min);
+      for (size_t i = (size_t)m; i < units.size(); i++)
+        assert(units[i] == GUARD && rem[i] == -7);
+    }
+  }
+  {
+    // the rebase: units 10..15 against first = 12, two windows closable,
+    // each unit reaching four windows back
+    const int64_t u[] = {15, 10, 12, 13, 14, 11, 14};
+    int64_t stats[3];
+    std::fill(win_rel.begin(), win_rel.end(), GUARD);
+    std::fill(keep.begin(), keep.end(), (uint8_t)9);
+    window_project_rebase(u, 7, 12, 2, 4, win_rel.data(), keep.data(), stats);
+    const int64_t want[] = {3, -2, 0, 1, 2, -1, 2};
+    for (size_t i = 0; i < 7; i++) {
+      assert(win_rel[i] == want[i]);
+      assert(keep[i] == (want[i] >= 2));
+    }
+    assert(win_rel[7] == GUARD && keep[7] == 9);
+    // two behind first, four behind the watermark; the kept rows at 2 and
+    // 3 reach back to windows -2 and -1... 0 and 1 are closable: straddle
+    assert(stats[0] == 2 && stats[1] == 4 && stats[2] == 1);
+    // nothing closable: nothing straddles, whatever reaches behind first
+    window_project_rebase(u, 7, 12, 0, 4, win_rel.data(), nullptr, stats);
+    assert(stats[0] == 2 && stats[1] == 2 && stats[2] == 0);
+    // every kept row clear of the closable windows
+    window_project_rebase(u, 7, 4, 2, 4, win_rel.data(), nullptr, stats);
+    assert(stats[0] == 0 && stats[1] == 0 && stats[2] == 0);
+    // a window length of one unit never straddles
+    window_project_rebase(u, 7, 12, 2, 0, win_rel.data(), nullptr, stats);
+    assert(stats[1] == 4 && stats[2] == 0);
+  }
+  {
+    // the reducer takes the units as they are and rebases them by u_off;
+    // with two subs a row's remainder picks its sub
+    const int64_t u[] = {102, 100, 101, 100, 99, 104};
+    const int32_t r[] = {10, 150, 99, 100, 0, 0};
+    const int32_t g[] = {1, 0, 1, 0, 0, 0};
+    const double v[] = {1.0, 2.0, 3.0, 4.0, 5.0, 6.0};
+    const int32_t U = 3, SUB = 2, G = 2;
+    std::vector<double> rec((size_t)(U * SUB * G) * 5);
+    for (size_t c = 0; c < rec.size() / 5; c++) {
+      rec[c * 5] = rec[c * 5 + 1] = rec[c * 5 + 2] = 0.0;
+      rec[c * 5 + 3] = INFINITY;
+      rec[c * 5 + 4] = -INFINITY;
+    }
+    std::vector<int64_t> touched(16);
+    int64_t nt = 0;
+    // units 99 and 104 fall outside [100, 103): skipped
+    assert(partial_window_agg(u, 100, r, 100, g, v, nullptr, 6, 1, U, SUB, G,
+                              rec.data(), touched.data(), &nt) == 4);
+    assert(nt == 3);
+    auto cell = [&](int64_t unit, int64_t sub, int64_t gid) {
+      return rec.data() + (((unit * SUB) + sub) * G + gid) * 5;
+    };
+    assert(cell(0, 1, 0)[0] == 2.0 && cell(0, 1, 0)[2] == 6.0);  // rem 150, 100
+    assert(cell(0, 1, 0)[3] == 2.0 && cell(0, 1, 0)[4] == 4.0);
+    assert(cell(1, 0, 1)[0] == 1.0 && cell(1, 0, 1)[2] == 3.0);  // rem 99
+    assert(cell(2, 0, 1)[0] == 1.0 && cell(2, 0, 1)[2] == 1.0);
+    assert(cell(0, 0, 0)[0] == 0.0);
+    // one sub: the remainders are not read
+    int64_t nt1 = 0;
+    std::vector<double> rec1((size_t)(U * G) * 5, 0.0);
+    assert(partial_window_agg(u, 100, nullptr, 0, g, v, nullptr, 6, 1, U, 1,
+                              G, rec1.data(), touched.data(), &nt1) == 4);
+    assert(nt1 == 3 && rec1[0] == 2.0);
+  }
+  printf("window project ok\n");
+}
+
 int main(int argc, char** argv) {
   const char* dir = argc > 1 ? argv[1] : "/tmp/native_test_lsm";
   test_lsm(dir);
@@ -1232,6 +1358,7 @@ int main(int argc, char** argv) {
   test_kafka_hammer();
   test_interner_hammer();
   test_sketch_hammer();
+  test_window_project();
   printf("ALL NATIVE TESTS PASSED\n");
   return 0;
 }

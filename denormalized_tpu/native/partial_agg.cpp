@@ -26,10 +26,12 @@
 extern "C" {
 
 // One pass over n rows.  Inputs are dense C-order:
-//   win_rel: (n) int64  — slide-unit index rebased to the stripe window;
-//            rows outside [0, U) are skipped (late / overflow, the caller
-//            pre-rebased against u_lo)
-//   sub:     (n) uint8 or NULL — sub-bucket per row (0/1); NULL = all 0
+//   units:   (n) int64  — slide-unit index of every row; the row's unit in
+//            the stripe is units[i] - u_off, taken here so the caller
+//            rebases nothing.  Rows outside [0, U) are skipped (late /
+//            overflow)
+//   rem:     (n) int32 or NULL — ts - unit*slide; a row with
+//            rem >= edge is sub 1.  NULL (SUB == 1) = all sub 0
 //   gid:     (n) int32  — dense group ids in [0, G)
 //   values:  (n, V) f64 — value matrix (row-major)
 //   colvalid:(n, V) uint8 or NULL — per-cell validity; NULL = all valid
@@ -51,8 +53,10 @@ extern "C" {
 //            stripe then cost what it touched, not what it could hold.
 // Returns number of rows folded (excludes skipped).
 int64_t partial_window_agg(
-    const int64_t* win_rel,
-    const uint8_t* sub,
+    const int64_t* units,
+    int64_t u_off,
+    const int32_t* rem,
+    int64_t edge,
     const int32_t* gid,
     const double* values,
     const uint8_t* colvalid,
@@ -69,10 +73,11 @@ int64_t partial_window_agg(
   int64_t folded = 0;
   int64_t nt = *n_touched;
   auto cell_of = [&](int64_t i) -> int64_t {
-    const int64_t u = win_rel[i];
+    // unsigned: a unit far from the stripe wraps, it does not overflow
+    const int64_t u = (int64_t)((uint64_t)units[i] - (uint64_t)u_off);
     const int32_t g = gid[i];
     if (u < 0 || u >= U || g < 0 || g >= G) return -1;
-    const int32_t s = sub ? (int32_t)sub[i] : 0;
+    const int32_t s = rem ? (int32_t)(rem[i] >= edge) : 0;
     return ((u * SUB) + s) * G + g;
   };
   for (int64_t i = 0; i < n; ++i) {
@@ -156,6 +161,106 @@ int64_t partial_pack_cells(
     for (int32_t f = 0; f < R; ++f) r[f] = neutral[f];
   }
   return overflowed;
+}
+
+// The window operator's batch time arithmetic (ops/window_project.py), so
+// that a batch's timestamps are scanned once.
+//
+// window_project_units: for each of the n event times ts[i] (ms) and a
+// slide > 0, the slide unit floor(ts / slide) and the remainder
+// ts - unit * slide in [0, slide) — FLOOR division, NumPy's divmod, for
+// event times before the epoch too (C's `/` truncates) — written into
+// units (int64) and rem (int32, the low 32 bits), buffers the caller owns
+// with room for n.  stats[0..2] = the least unit, the greatest unit, the
+// least ts.  Nothing is written when n == 0.
+//
+// A stream runs forward, so nearly every row lies in the unit of the row
+// the last division was paid for: that is a subtraction and a compare
+// against that row (t0, its remainder r0), exact over the whole of int64,
+// and the division is paid only where the unit changes.  Shuffled input
+// pays it most rows and gets the same numbers.
+void window_project_units(
+    const int64_t* ts,
+    int64_t n,
+    int64_t slide,
+    int64_t* units,
+    int32_t* rem,
+    int64_t* stats) {
+  if (n <= 0 || slide <= 0) return;
+  int64_t u_min = INT64_MAX, u_max = INT64_MIN, t_min = INT64_MAX;
+  int64_t t0 = 0, q = 0;
+  uint64_t r0 = 0;
+  bool have = false;
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t t = ts[i];
+    uint64_t r;
+    bool same;
+    if (t >= t0) {
+      const uint64_t du = (uint64_t)t - (uint64_t)t0;
+      same = du < (uint64_t)slide - r0;
+      r = r0 + du;
+    } else {
+      const uint64_t du = (uint64_t)t0 - (uint64_t)t;
+      same = du <= r0;
+      r = r0 - du;
+    }
+    if (!(same && have)) {
+      q = t / slide;
+      int64_t m = t % slide;
+      if (m < 0) {  // slide > 0: q > INT64_MIN here
+        m += slide;
+        --q;
+      }
+      t0 = t;
+      r0 = r = (uint64_t)m;
+      have = true;
+      if (q < u_min) u_min = q;
+      if (q > u_max) u_max = q;
+    }
+    units[i] = q;
+    rem[i] = (int32_t)r;
+    if (t < t_min) t_min = t;
+  }
+  stats[0] = u_min;
+  stats[1] = u_max;
+  stats[2] = t_min;
+}
+
+// window_project_rebase: once the operator knows `first` (its lowest open
+// window) and `closable` (how many windows from there the watermark has
+// closed): per row w = win_rel[i] = units[i] - first, and in the same pass
+//   stats[0]  late      rows with w < 0          (behind first)
+//   stats[1]  behind    rows with w < closable   (behind the watermark:
+//                       what a host-reducing backend drops)
+//   stats[2]  straddle  0 / 1: a KEPT row (w >= closable) with
+//                       w - span < closable — it reaches back into a
+//                       closable window (where there is one)
+// keep[i] = w >= closable where keep is given; the caller gives it only
+// where a row will be dropped (the batch's least unit says so).
+void window_project_rebase(
+    const int64_t* units,
+    int64_t n,
+    int64_t first,
+    int64_t closable,
+    int64_t span,
+    int64_t* win_rel,
+    uint8_t* keep,
+    int64_t* stats) {
+  int64_t late = 0, behind = 0, straddle = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    // wraps as NumPy's int64 does, here and at `- span`
+    const int64_t w = (int64_t)((uint64_t)units[i] - (uint64_t)first);
+    win_rel[i] = w;
+    late += w < 0;
+    behind += w < closable;
+    straddle |= (w >= closable) &
+                ((int64_t)((uint64_t)w - (uint64_t)span) < closable);
+  }
+  if (keep)
+    for (int64_t i = 0; i < n; ++i) keep[i] = win_rel[i] >= closable;
+  stats[0] = late;
+  stats[1] = behind;
+  stats[2] = straddle && closable > 0;
 }
 
 }  // extern "C"

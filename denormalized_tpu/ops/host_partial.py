@@ -43,8 +43,10 @@ def _native():
             lib = load("partial_agg")
             lib.partial_window_agg.restype = ctypes.c_int64
             lib.partial_window_agg.argtypes = [
-                ctypes.c_void_p,  # win_rel int64
-                ctypes.c_void_p,  # sub uint8 | NULL
+                ctypes.c_void_p,  # units int64
+                ctypes.c_int64,   # u_off: a row's stripe unit = unit - u_off
+                ctypes.c_void_p,  # rem int32 | NULL (SUB == 1)
+                ctypes.c_int64,   # edge: rem >= edge is sub 1
                 ctypes.c_void_p,  # gid int32
                 ctypes.c_void_p,  # values f64
                 ctypes.c_void_p,  # colvalid uint8 | NULL
@@ -70,6 +72,28 @@ def _native():
                 ctypes.c_void_p,  # packed int32
                 ctypes.c_int64,   # stride
                 ctypes.c_void_p,  # neutral f64
+            ]
+            # the window operator's batch time arithmetic
+            # (ops/window_project.py)
+            lib.window_project_units.restype = None
+            lib.window_project_units.argtypes = [
+                ctypes.c_void_p,  # ts int64
+                ctypes.c_int64,   # n
+                ctypes.c_int64,   # slide_ms > 0
+                ctypes.c_void_p,  # units int64 out
+                ctypes.c_void_p,  # rem int32 out
+                ctypes.c_void_p,  # stats int64[3] out: u_min, u_max, ts_min
+            ]
+            lib.window_project_rebase.restype = None
+            lib.window_project_rebase.argtypes = [
+                ctypes.c_void_p,  # units int64
+                ctypes.c_int64,   # n
+                ctypes.c_int64,   # first
+                ctypes.c_int64,   # closable
+                ctypes.c_int64,   # span
+                ctypes.c_void_p,  # win_rel int64 out
+                ctypes.c_void_p,  # keep uint8 out | NULL
+                ctypes.c_void_p,  # stats int64[3] out: late, behind, straddle
             ]
             _LIB = lib
         except Exception as e:  # dnzlint: allow(broad-except) numpy partial-agg is the designed fallback on no-compiler boxes; logged so the downgrade is visible, gated by test_native_build_gate where g++ exists
@@ -145,6 +169,9 @@ class HostPartialStripe:
         self.G = group_capacity
         self.V = max(spec.num_value_cols, 1)
         self.SUB = 1 if spec.length_ms % spec.slide_ms == 0 else 2
+        # rows with rem >= L - (k-1)*S miss the oldest overlapping window:
+        # they are sub 1 (see partial_agg.cpp header)
+        self._edge = spec.length_ms - (spec.length_units - 1) * spec.slide_ms
         self.unit_cells = self.SUB * self.G
         self.U = max(
             1, min(self.U_MAX, self.MAX_STRIPE_CELLS // self.unit_cells)
@@ -200,7 +227,14 @@ class HostPartialStripe:
         values64: np.ndarray,   # (n, V) f64
         colvalid: np.ndarray | None,  # (n, V) bool or None (all valid)
         keep: np.ndarray | None,      # (n) bool rows to fold (None = all)
+        u_min: int | None = None,     # least and greatest of ``units``,
+        u_max: int | None = None,     # where the caller knows them
     ) -> None:
+        """Fold a batch's rows into the stripe.  A caller that knows the
+        extremes of ``units`` (the window operator does, from its one pass
+        over the timestamps) hands them in and no array is scanned for
+        them; they are of the whole batch, so a ``keep`` that drops rows
+        has them found again."""
         n = len(units)
         if n == 0:
             return
@@ -211,23 +245,17 @@ class HostPartialStripe:
             values64 = values64[keep]
             if colvalid is not None:
                 colvalid = colvalid[keep]
+            u_min = u_max = None
             n = len(units)
             if n == 0:
                 return
         if colvalid is not None and not self.nulls_seen and not colvalid.all():
             self.nulls_seen = True
+        if u_min is None:
+            u_min, u_max = int(units.min()), int(units.max())
         if self.u_base is None:
-            self.u_base = int(units.min())
-        # units is int64 (accumulate() normalizes), so the subtraction
-        # already yields a fresh contiguous int64 array — no astype copy
-        rel = units - self.u_base
-        self.u_hi = max(self.u_hi, int(rel.max()))
-        sub = None
-        if self.SUB == 2:
-            # rows with rem >= L - (k-1)*S miss the oldest overlapping
-            # window (see partial_agg.cpp header)
-            edge = self.spec.length_ms - (self.spec.length_units - 1) * self.spec.slide_ms
-            sub = (np.asarray(rem) >= edge).astype(np.uint8)
+            self.u_base = u_min
+        self.u_hi = max(self.u_hi, u_max - self.u_base)
         need = int(self._n_touched[0]) + n
         if need > len(self._touched):
             grown = np.empty(max(need, 2 * len(self._touched)), np.int64)
@@ -235,7 +263,12 @@ class HostPartialStripe:
             self._touched = grown
         lib = _native()
         if lib is not None:
-            rel = np.ascontiguousarray(rel, np.int64)
+            # the pass rebases each unit to the stripe and tells sub 0 from
+            # sub 1 itself: no array of n is made here
+            units_c = np.ascontiguousarray(units, np.int64)
+            rem_c = (
+                np.ascontiguousarray(rem, np.int32) if self.SUB == 2 else None
+            )
             gid_c = np.ascontiguousarray(gid, np.int32)
             vals_c = np.ascontiguousarray(values64, np.float64)
             cv = (
@@ -244,12 +277,19 @@ class HostPartialStripe:
                 else np.ascontiguousarray(colvalid, np.uint8)
             )
             lib.partial_window_agg(
-                _ptr(rel), _ptr(sub), _ptr(gid_c), _ptr(vals_c), _ptr(cv),
-                n, self.V, self.U, self.SUB, self.G, _ptr(self.rec),
-                _ptr(self._touched), _ptr(self._n_touched),
+                _ptr(units_c), self.u_base, _ptr(rem_c), self._edge, _ptr(gid_c),
+                _ptr(vals_c), _ptr(cv), n, self.V, self.U, self.SUB, self.G,
+                _ptr(self.rec), _ptr(self._touched), _ptr(self._n_touched),
             )
         else:
-            self._add_numpy(rel, sub, gid, values64, colvalid)
+            sub = (
+                (np.asarray(rem) >= self._edge).astype(np.uint8)
+                if self.SUB == 2 else None
+            )
+            self._add_numpy(
+                np.asarray(units, np.int64) - self.u_base, sub, gid,
+                values64, colvalid,
+            )
         self.rows += n
 
     def _add_numpy(self, rel, sub, gid, values64, colvalid):

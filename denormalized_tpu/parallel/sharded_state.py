@@ -391,13 +391,17 @@ class _HostPartialMixin:
         )
 
     def accumulate(
-        self, units_rel, rem, gid, values64, colvalid, keep, base_mod
+        self, units_rel, rem, gid, values64, colvalid, keep, base_mod,
+        u_min=None, u_max=None,
     ) -> None:
         """Fold one batch into the host stripe, flushing/chunking so no
         row is ever dropped: a batch spanning more slide units than a
         stripe can hold (catch-up reads, giant arrival batches) is folded
         in unit-range chunks with a merge between them — the partial-path
-        equivalent of the scatter path's W growth."""
+        equivalent of the scatter path's W growth.  ``u_min`` / ``u_max``:
+        the extremes of ``units_rel``, where the caller knows them (the
+        operator does, from its pass over the timestamps); the steady path
+        then scans no array."""
         units_rel = np.asarray(units_rel, np.int64)
         stripe = self._stripe
         span_u = stripe.U  # units a stripe holds
@@ -408,8 +412,8 @@ class _HostPartialMixin:
             # would need a flush (span overflow, row cap, units behind
             # u_base) falls through to the chunk loop below, which keeps
             # the one and only copy of the flush/admission logic.
-            u_min = int(units_rel.min())
-            u_max = int(units_rel.max())
+            if u_min is None:
+                u_min, u_max = int(units_rel.min()), int(units_rel.max())
             base = stripe.u_base if not stripe.is_empty() else u_min
             if (
                 u_min >= base
@@ -422,7 +426,10 @@ class _HostPartialMixin:
             ):
                 if stripe.is_empty():
                     self._pending_base_mod = int(base_mod)
-                stripe.add_batch(units_rel, rem, gid, values64, colvalid, None)
+                stripe.add_batch(
+                    units_rel, rem, gid, values64, colvalid, None,
+                    u_min, u_max,
+                )
                 return
         remaining = (
             np.ones(len(units_rel), bool) if keep is None else keep.copy()

@@ -48,6 +48,7 @@ from denormalized_tpu.logical.plan import WindowType
 from denormalized_tpu.ops import segment_agg as sa
 from denormalized_tpu.ops.host_partial import HostPartialStripe
 from denormalized_tpu.ops.interner import INTERN_STATS, GroupInterner
+from denormalized_tpu.ops.window_project import WindowProjector
 from denormalized_tpu.physical.base import (
     EOS,
     WM_ANNOUNCE,
@@ -565,6 +566,16 @@ class StreamingWindowExec(ExecOperator):
         # stripe mutation serialized; _join_acc() fences before any other
         # backend access (flush/emission/export/growth)
         self._host_pipeline = host_pipeline
+        # the batch's time arithmetic; its outputs go into buffers it keeps
+        # only where a batch is folded before the next is projected (see
+        # WindowProjector): not under host_pipeline, whose worker may still
+        # read batch k while k+1 is projected, and not for a row-shipping
+        # backend, whose device program reads its inputs asynchronously —
+        # those get fresh arrays a batch
+        self._proj = WindowProjector(
+            self.slide_ms, self._spec.length_units,
+            reuse_buffers=self._backend.accumulates_host and not host_pipeline,
+        )
         self._acc_exec = None
         self._acc_future = None
         self._acc_error: BaseException | None = None
@@ -653,6 +664,10 @@ class StreamingWindowExec(ExecOperator):
         # them the native pass folded (all, where the library loaded)
         m["sketch_update_batches"] = self._sw.update_batches
         m["sketch_native_batches"] = self._sw.sketch_native_batches
+        # batches whose timestamps ``window.project``'s native pass took
+        # (= batches_in where the library loaded and the timestamps are a
+        # contiguous int64 array)
+        m["project_native_batches"] = self._proj.native_batches
         # what the intern phase's native table did: intern_rows,
         # intern_extra_probes, intern_overflow_rows (0 when ungrouped)
         m.update(
@@ -843,12 +858,17 @@ class StreamingWindowExec(ExecOperator):
         self._obs_rows_in.add(n)
         ph = self._phases
         with ph.phase("project", batch=bno, rows=n):
-            S = self.slide_ms
-            ts = np.asarray(batch.column(CANONICAL_TIMESTAMP_COLUMN), dtype=np.int64)
-            units, rem64 = np.divmod(ts, S)  # one pass for quotient+remainder
-            rem = rem64.astype(np.int32)
+            # the batch's time arithmetic (ops/window_project.py): ONE pass
+            # over the timestamps gives every row's slide unit and remainder
+            # and the batch's extremes, so everything below that used to
+            # scan an array for its min or max reads a scalar
+            proj = self._proj
+            host = self._backend.accumulates_host
+            units, rem, u_min, u_max, ts_min = proj.units(
+                batch.column(CANONICAL_TIMESTAMP_COLUMN)
+            )
 
-            anchor = int(units.min()) - self._spec.length_units + 1
+            anchor = u_min - self._spec.length_units + 1
             if self._first_open is None:
                 # windows overlapping the first data: back to units.min() - k + 1
                 self._first_open = anchor
@@ -895,14 +915,18 @@ class StreamingWindowExec(ExecOperator):
                 # window this batch's rows can land in comes back into the
                 # ring (first_open lowers with it), so nothing reads as late
                 # that the all-resident run would have accepted
-                self._tier.touch_and_reload(
-                    int(units.min()) - self._spec.length_units + 1,
-                    int(units.max()),
-                )
+                self._tier.touch_and_reload(anchor, u_max)
             first = self._first_open
-            win_rel64 = units - first
-            self._max_win_seen = max(self._max_win_seen, int(units.max()))
-            late = int((win_rel64 < 0).sum())
+            # a host-reducing backend drops against the WATERMARK (windows
+            # already closable), not first_open — see below; neither moves
+            # before the drop is decided, so both counts, and the mask where
+            # a row is dropped, come out of the one pass that rebases the
+            # units (a row-shipping backend drops by win_rel: no mask)
+            closable_pre = self._closable() if host else 0
+            win_rel64, late, n_behind, straddle, keep = proj.rebase(
+                units, u_min, first, closable_pre, mask=host
+            )
+            self._max_win_seen = max(self._max_win_seen, u_max)
             if late:
                 self._metrics["late_rows"] += late
                 self._obs_late.add(late)
@@ -917,7 +941,7 @@ class StreamingWindowExec(ExecOperator):
                     gid = np.zeros(n, dtype=np.int32)
             with ph.phase("statewatch", batch=bno):
                 self._sw.update(gid)
-            self._ensure_capacity(int(win_rel64.max()))
+            self._ensure_capacity(u_max - first)
 
             # value matrix + per-column validity: f64 only when the backend
             # accumulates on host (partial_merge keeps f64 precision); the
@@ -925,12 +949,14 @@ class StreamingWindowExec(ExecOperator):
             V = self._spec.num_value_cols
             from denormalized_tpu.logical.expr import column_validity
 
-            host_dtype = (
-                np.float64 if self._backend.accumulates_host else np.float32
-            )
+            host_dtype = np.float64 if host else np.float32
             single_untransformed = (
                 V == 1 and self._value_transforms[0] is None
             )
+            # per-column validity, built only once a column has a mask
+            # (None = every value valid)
+            colvalid = None
+            any_invalid = False
             if single_untransformed:
                 # single untransformed value column (the common case): the
                 # evaluated column IS the value matrix — skip the zeros
@@ -941,20 +967,18 @@ class StreamingWindowExec(ExecOperator):
                 values64 = np.asarray(e.eval(batch), dtype=host_dtype).reshape(
                     n, 1
                 )
-                colvalid = np.ones((n, 1), dtype=bool)
                 m = column_validity(e, batch)
-                any_invalid = False
                 if m is not None:
-                    colvalid[:, 0] = m
-                    any_invalid = not m.all()
+                    colvalid = np.asarray(m, dtype=bool).reshape(n, 1)
+                    any_invalid = not colvalid.all()
             else:
                 values64 = np.zeros((n, max(V, 1)), dtype=host_dtype)
-                colvalid = np.ones((n, max(V, 1)), dtype=bool)
-                any_invalid = False
                 for j, e in enumerate(self._value_exprs):
                     raw = np.asarray(e.eval(batch), dtype=np.float64)
                     m = column_validity(e, batch)
                     if m is not None:
+                        if colvalid is None:
+                            colvalid = np.ones((n, max(V, 1)), dtype=bool)
                         colvalid[:, j] = m
                         any_invalid = any_invalid or not colvalid[:, j].all()
                     tr = self._value_transforms[j]
@@ -988,7 +1012,7 @@ class StreamingWindowExec(ExecOperator):
             if any_invalid:
                 self._any_nulls_seen = True
 
-            if self._backend.accumulates_host:
+            if host:
                 # partial_merge: reduce the batch on host; the device sees a
                 # merged stripe later (flush on trigger/growth/snapshot).
                 # Late-drop against the WATERMARK (windows already closable),
@@ -996,36 +1020,28 @@ class StreamingWindowExec(ExecOperator):
                 # semantics wall-clock-dependent — this is exactly where the
                 # scatter path's first_open would sit, since it emits every
                 # closable window immediately.
-                closable_pre = self._closable()
-                if late or closable_pre:
-                    keep = win_rel64 >= closable_pre
-                    if closable_pre and self._spec.length_units > 1:
-                        # A kept row's unit partial feeds EVERY window
-                        # containing that unit — including closable windows
-                        # whose emission is merely deferred.  The stripe is
-                        # per-unit, so that stale contribution cannot be
-                        # subtracted per-window later; the only sound order is
-                        # freeze-then-accumulate: emit every closable window
-                        # now, then rebase against the advanced first_open.
-                        # Only rows strictly BEHIND the watermark can straddle
-                        # (a row at ts ≥ wm has no closable window), so a
-                        # sorted feed never takes this path.
-                        lows = win_rel64 - (self._spec.length_units - 1)
-                        if bool((keep & (lows < closable_pre)).any()):
-                            yield from self._trigger(force=True)
-                            first = self._first_open
-                            win_rel64 = units - first
-                            closable_pre = self._closable()  # 0 post-emission
-                            keep = win_rel64 >= closable_pre
-                    n_drop = int((~keep).sum())
-                    if n_drop:
-                        self._metrics["late_rows"] += n_drop - late
-                        self._obs_late.add(n_drop - late)
-                    else:
-                        keep = None
-                else:
-                    keep = None
-        if self._backend.accumulates_host:
+                if straddle:
+                    # A kept row's unit partial feeds EVERY window
+                    # containing that unit — including closable windows
+                    # whose emission is merely deferred.  The stripe is
+                    # per-unit, so that stale contribution cannot be
+                    # subtracted per-window later; the only sound order is
+                    # freeze-then-accumulate: emit every closable window
+                    # now, then rebase against the advanced first_open.
+                    # Only rows strictly BEHIND the watermark can straddle
+                    # (a row at ts ≥ wm has no closable window), so a
+                    # sorted feed never takes this path.
+                    yield from self._trigger(force=True)
+                    first = self._first_open
+                    win_rel64, _, n_behind, _, keep = proj.rebase(
+                        units, u_min, first, self._closable()  # 0 post-emission
+                    )
+                if n_behind:
+                    # rows behind the watermark's closable count that were
+                    # not already counted against first_open
+                    self._metrics["late_rows"] += n_behind - late
+                    self._obs_late.add(n_behind - late)
+        if host:
             if (
                 self._acc_future is None or self._acc_future.done()
             ) and self._backend.pending_rows == 0:
@@ -1038,6 +1054,9 @@ class StreamingWindowExec(ExecOperator):
                 colvalid if any_invalid else None,
                 keep,
                 first % self._spec.window_slots,
+                # the batch's extremes, known since the timestamp pass
+                u_min - first,
+                u_max - first,
             )
             if self._host_pipeline:
                 self._submit_acc(bno, acc_args)
@@ -1046,6 +1065,8 @@ class StreamingWindowExec(ExecOperator):
                     self._backend.accumulate(*acc_args)
         else:
             values = values64  # already f32 (see allocation above)
+            if colvalid is None:
+                colvalid = np.ones(values.shape, dtype=bool)
             win_rel = np.clip(
                 win_rel64, -1, self._spec.window_slots
             ).astype(np.int32)
@@ -1080,17 +1101,14 @@ class StreamingWindowExec(ExecOperator):
         # unless the source supplies per-partition watermarks, which
         # arrive as kind="partition" hints right after their batch
         if not self._src_watermarks:
-            bmin = int(ts.min())
-            if self._watermark_ms is None or bmin > self._watermark_ms:
-                self._watermark_ms = bmin
+            if self._watermark_ms is None or ts_min > self._watermark_ms:
+                self._watermark_ms = ts_min
         yield from self._trigger()
         if self._tier is not None:
             # after the trigger: closable windows have emitted, so the
             # [first_open, this batch's lowest window) prefix is the
             # watermark-deferred cold span
-            self._tier.maybe_spill(
-                int(units.min()) - self._spec.length_units + 1
-            )
+            self._tier.maybe_spill(anchor)
 
     # -- host pipeline fence --------------------------------------------
     def _join_acc(self) -> None:

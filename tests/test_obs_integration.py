@@ -80,6 +80,8 @@ WINDOW_KEYS = {
     "phase_ms_d2h_wait", "phase_ms_finalize", "phase_ms_other",
     # what the statewatch phase ran: batches sketched, natively of those
     "sketch_update_batches", "sketch_native_batches",
+    # batches whose timestamps window.project's native pass took
+    "project_native_batches",
     # what the host stripe's flushes touched and sent
     "stripe_cells_active", "stripe_cells_shipped", "stripe_bytes_touched",
     # the native interner's tallies (docs/observability.md, Spans)
