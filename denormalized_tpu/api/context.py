@@ -102,7 +102,7 @@ class EngineConfig:
     # — the dp x tp analog; see parallel/sharded_state.TwoLevelWindowState)
     mesh_slices: int | None = None
     # 'auto' | 'key_sharded' | 'partial_final' | 'two_level'
-    # (see parallel/sharded_state.py)
+    # (see parallel/sharded_state.py); a strategy named here ships rows
     shard_strategy: str = "auto"
     # single-device kernel strategy:
     #   'scatter'       — ship rows, device scatters them into the window
@@ -113,10 +113,13 @@ class EngineConfig:
     #                     the device merges them into the ring.  Traffic
     #                     scales with cardinality, not rows — the right
     #                     choice behind a narrow host↔device link
-    #   'auto'          — partial_merge on single-device TPU (a rule
-    #                     chosen on an earlier installation, not measured
-    #                     on this one; ROADMAP S2) and CPU (it beats XLA
-    #                     scatter adds there), except
+    #   'auto'          — partial_merge on a TPU (one device: a rule
+    #                     chosen on an earlier installation, ROADMAP S2;
+    #                     a 1-D mesh with shard_strategy 'auto': measured
+    #                     on four v5e chips, PERF.md PR 31 — each device
+    #                     folds its own key block's share of the stripe)
+    #                     and on the CPU (it beats XLA scatter adds
+    #                     there), except
     #                     f64 accumulators on CPU, which keep scatter:
     #                     the partial stripe's f32 hi/lo transport cannot
     #                     carry finite f64 sums beyond f32 range.  On

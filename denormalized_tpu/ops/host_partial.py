@@ -137,6 +137,14 @@ class HostPartialStripe:
     hold: the reducer notes every cell it writes for the first time
     (``_touched``); packing gathers those records and the reset rewrites
     them, and nothing else of the stripe is read or written.
+
+    ``key_blocks``: into how many equal, contiguous blocks of group ids a
+    packed unit is split — one a device of a key-sharded mesh, each device
+    holding ``G / key_blocks`` groups of the ring.  The touched cells are
+    ascending, so a block's share of a unit is one contiguous run of them
+    (one run a sub-bucket), found by binary search; it is packed with ids
+    local to the block, still ascending and distinct, and a device receives
+    and folds its own share only.  One block is the whole unit.
     """
 
     # most slide units a stripe spans; a wider batch forces a flush
@@ -164,19 +172,32 @@ class HostPartialStripe:
         "(~3.4e38); use device_strategy='scatter' for this workload"
     )
 
-    def __init__(self, spec: sa.WindowKernelSpec, group_capacity: int):
+    def __init__(
+        self, spec: sa.WindowKernelSpec, group_capacity: int,
+        key_blocks: int = 1,
+    ):
+        if group_capacity % key_blocks:
+            raise ValueError(
+                f"group capacity {group_capacity} is not divisible into "
+                f"{key_blocks} key blocks"
+            )
         self.spec = spec
         self.G = group_capacity
+        self.blocks = key_blocks
         self.V = max(spec.num_value_cols, 1)
         self.SUB = 1 if spec.length_ms % spec.slide_ms == 0 else 2
         # rows with rem >= L - (k-1)*S miss the oldest overlapping window:
         # they are sub 1 (see partial_agg.cpp header)
         self._edge = spec.length_ms - (spec.length_units - 1) * spec.slide_ms
         self.unit_cells = self.SUB * self.G
+        # a key block's groups, and a unit's cells in it: what a device
+        # is sent at most
+        self.G_block = self.G // key_blocks
+        self.block_cells = self.SUB * self.G_block
         self.U = max(
             1, min(self.U_MAX, self.MAX_STRIPE_CELLS // self.unit_cells)
         )
-        self._buckets = self.buckets_for(self.unit_cells)
+        self._buckets = self.buckets_for(self.block_cells)
         self.u_base: int | None = None
         self.u_hi = 0  # highest stripe-relative unit written (span - 1)
         self.rows = 0
@@ -187,10 +208,14 @@ class HostPartialStripe:
         self.nulls_seen = False
         # work counters (docs/observability.md): cells with rows / cells
         # sent (padding included) / host bytes scanned and rewritten by
-        # pack and reset, all summed over the stripes taken so far
+        # pack and reset / bytes of the packed matrices handed out, all
+        # summed over the stripes taken so far; and the cells with rows
+        # again, by the key block they fell in
         self.cells_active = 0
         self.cells_shipped = 0
         self.bytes_touched = 0
+        self.bytes_packed = 0
+        self.cells_by_block = np.zeros(key_blocks, np.int64)
         # a cell without rows holds the fold-neutral record
         self._neutral = np.array(
             [0.0] + [0.0, 0.0, np.inf, -np.inf] * self.V
@@ -206,13 +231,17 @@ class HostPartialStripe:
         self._dirty: list = []  # (index or slice, cells) still to reset
 
     #: the work counters above, as ``metrics()`` surfaces them (``stripe_<name>``)
-    COUNTERS = ("cells_active", "cells_shipped", "bytes_touched")
+    COUNTERS = (
+        "cells_active", "cells_shipped", "bytes_touched", "bytes_packed",
+    )
 
     def carry_counters(self, old: "HostPartialStripe") -> None:
         """Take over the counts of the stripe this one replaces (capacity
         growth, restore), so they stay sums over the operator's life."""
         for name in self.COUNTERS:
             setattr(self, name, getattr(self, name) + getattr(old, name))
+        if old.blocks == self.blocks:
+            self.cells_by_block += old.cells_by_block
 
     def host_bytes(self) -> int:
         """Bytes of host memory the stripe's records hold."""
@@ -354,24 +383,25 @@ class HostPartialStripe:
     def transfer_buckets(self) -> list[int]:
         """Every padded size a compact pack of this stripe can have: the
         powers of two from ``MIN_BUCKET`` up to the last one below a slide
-        unit's cells (a pack is of ONE unit, and one that would need a
-        bucket as wide as the unit goes dense — fewer bytes, see
-        ``take_packed``).  A set fixed by the spec, so every merge program
-        is compiled at construction: the sizes a run meets vary with its
-        pacing, and an unseen one mid-stream is a compile.  Padding is
-        under two cells a cell above ``MIN_BUCKET``."""
+        unit's cells in one key block (a pack is of ONE unit, every block
+        of it padded alike, and one that would need a bucket as wide as
+        the block goes dense — fewer bytes, see ``take_packed``).  A set
+        fixed by the spec, so every merge program is compiled at
+        construction: the sizes a run meets vary with its pacing, and an
+        unseen one mid-stream is a compile.  Padding is under two cells a
+        cell above ``MIN_BUCKET``."""
         return list(self._buckets)
 
     def layout_for(self, A: int, n_planes: int) -> tuple[int, bool]:
-        """``(a_pad, dense)`` of a unit with ``A`` active cells: the
-        compact bucket that covers them, or the dense layout (``a_pad`` =
-        the unit's cells) where that moves fewer bytes — the index row
-        counted — or no bucket covers them."""
+        """``(a_pad, dense)`` of a unit whose fullest key block has ``A``
+        active cells: the compact bucket that covers them, or the dense
+        layout (``a_pad`` = the block's cells) where that moves fewer
+        bytes — the index row counted — or no bucket covers them."""
         a_pad = next((b for b in self._buckets if b >= A), None)
         if a_pad is None or (
-            n_planes * self.unit_cells < (n_planes + 1) * a_pad
+            n_planes * self.block_cells < (n_planes + 1) * a_pad
         ):
-            return self.unit_cells, True
+            return self.block_cells, True
         return a_pad, False
 
     def _planes_walk(self, lean: bool):
@@ -425,7 +455,13 @@ class HostPartialStripe:
           fold-neutral values (count 0, sum 0, min +inf, max −inf).  Wins
           once most of a unit is active (e.g. 100K live keys in a ring
           131,072 wide: 5 planes × 131,072 against 6 × 131,072), and the
-          device folds it without a scatter."""
+          device folds it without a scatter.
+
+        With ``key_blocks > 1`` every matrix has a leading axis of that
+        length: block ``b`` is the matrix of the unit's cells whose group
+        lies in key block ``b``, with ``G / key_blocks`` in ``G``'s place
+        (ids local to the block, the header in every block), all blocks
+        padded to the ``a_pad`` that covers the fullest."""
         if self.rows == 0:
             return []
         with clock.phase("flush_pack"):
@@ -456,27 +492,42 @@ class HostPartialStripe:
         # link — the device merge aliases them to the row-count plane
         lean = not self.nulls_seen and sa.lean_possible(self.spec)
         n_planes = self.n_planes(lean)
-        cells = self.unit_cells
-        bounds = np.searchsorted(active, np.arange(used + 1) * cells)
+        B = self.blocks
+        # where each (unit, sub, key block) starts among the active cells:
+        # they are ascending, so a block's share of a sub-bucket is one run
+        edges = (
+            (np.arange(used)[:, None, None] * self.SUB
+             + np.arange(self.SUB)[None, :, None]) * self.G
+            + np.arange(B + 1)[None, None, :] * self.G_block
+        )
+        cuts = np.searchsorted(active, edges)  # (used, SUB, B + 1)
         out = []
         for u in range(used):
-            lo, hi = int(bounds[u]), int(bounds[u + 1])
+            lo, hi = int(cuts[u, 0, 0]), int(cuts[u, -1, -1])
             A = hi - lo
             if A == 0:
                 continue
-            a_pad, dense = self.layout_for(A, n_planes)
+            by_block = np.diff(cuts[u], axis=1).sum(axis=0)
+            a_pad, dense = self.layout_for(int(by_block.max()), n_planes)
             if dense:
                 packed = self._pack_dense(u, lean, n_planes)
             else:
                 packed = self._pack_compact(
-                    u, active[lo:hi], a_pad, lean, n_planes
+                    u, active, cuts[u], a_pad, lean, n_planes
                 )
-            packed[0, a_pad] = self.u_base + u
-            packed[0, a_pad + 1] = base_mod
+            packed[:, 0, a_pad] = self.u_base + u
+            packed[:, 0, a_pad + 1] = base_mod
             self.cells_active += A
-            self.cells_shipped += a_pad
-            out.append((packed, a_pad, lean, dense))
+            self.cells_by_block += by_block
+            self.cells_shipped += B * a_pad
+            self.bytes_packed += packed.nbytes
+            out.append((self._handed_out(packed), a_pad, lean, dense))
         return out
+
+    def _handed_out(self, packed: np.ndarray) -> np.ndarray:
+        """A matrix a key block as ``take_packed`` hands it out: the block
+        axis stays only where there is more than one."""
+        return packed if self.blocks > 1 else packed[0]
 
     def _f32_bits(self, c: sa.AggComponent, src: np.ndarray) -> list[np.ndarray]:
         """A component's cells as the int32-bitcast f32 rows it ships as:
@@ -485,70 +536,106 @@ class HostPartialStripe:
             return list(self._split_sum(src))
         return [src.astype(np.float32).view(np.int32)]
 
-    def _pack_compact(self, u, cells_u, a_pad, lean, n_planes) -> np.ndarray:
-        A = len(cells_u)
-        packed = np.empty((n_planes + 1, a_pad + 2), np.int32)
-        packed[:, A:] = 0
-        packed[0, A:a_pad] = -1
+    def _pack_compact(self, u, active, cuts, a_pad, lean, n_planes) -> np.ndarray:
+        """Compact pack of unit ``u``, a matrix a key block: ``cuts[s, b]``
+        is where sub-bucket ``s`` of block ``b`` starts in ``active``."""
+        B, G_block = self.blocks, self.G_block
+        packed = np.empty((B, n_planes + 1, a_pad + 2), np.int32)
         walk = list(self._planes_walk(lean))
         lib = _native()
         if lib is not None:
-            # one pass over the records: pack them and put them back to
-            # neutral (nothing is left for _reset to do for these cells)
             fields = np.array([self._field(c) for c, _ in walk], np.int32)
             split = np.array([c.kind == "sum" for c, _ in walk], np.uint8)
-            overflowed = lib.partial_pack_cells(
-                _ptr(cells_u), A, u * self.unit_cells, _ptr(self.rec),
-                self.rec.shape[1], _ptr(fields), _ptr(split), len(walk),
-                _ptr(packed), packed.shape[1], _ptr(self._neutral),
-            )
-            if overflowed and self.spec.accum_dtype == sa.jnp.float64:
-                raise OverflowError(self.F64_OVERFLOW)
-            self.bytes_touched += 2 * A * self.rec.shape[1] * 8
-            return packed
-        packed[0, :A] = cells_u - u * self.unit_cells
-        recs = self.rec[cells_u]  # one gather: the active records
-        for c, pi in walk:
-            for k, row in enumerate(self._f32_bits(c, recs[:, self._field(c)])):
-                packed[1 + pi + k, :A] = row
-        self.bytes_touched += recs.nbytes
-        self._dirty.append((cells_u, A))
+        overflowed = 0
+        for b in range(B):
+            at = 0  # cells of this block packed so far
+            for s in range(self.SUB):
+                run = active[int(cuts[s, b]) : int(cuts[s, b + 1])]
+                n = len(run)
+                if n == 0:
+                    continue
+                # a cell's id in the block: s * G_block + (g - b * G_block)
+                base = (
+                    u * self.unit_cells + s * (self.G - G_block) + b * G_block
+                )
+                if lib is not None:
+                    # one pass over the records: pack them and put them
+                    # back to neutral (nothing is left for _reset to do
+                    # for these cells)
+                    into = packed[b, :, at:]
+                    overflowed += lib.partial_pack_cells(
+                        _ptr(run), n, base, _ptr(self.rec),
+                        self.rec.shape[1], _ptr(fields), _ptr(split),
+                        len(walk), _ptr(into), packed.shape[2],
+                        _ptr(self._neutral),
+                    )
+                    self.bytes_touched += 2 * n * self.rec.shape[1] * 8
+                else:
+                    packed[b, 0, at : at + n] = run - base
+                    recs = self.rec[run]  # one gather: the active records
+                    for c, pi in walk:
+                        for k, row in enumerate(
+                            self._f32_bits(c, recs[:, self._field(c)])
+                        ):
+                            packed[b, 1 + pi + k, at : at + n] = row
+                    self.bytes_touched += recs.nbytes
+                    self._dirty.append((run, n))
+                at += n
+            packed[b, :, at:] = 0
+            packed[b, 0, at:a_pad] = -1
+        if overflowed and self.spec.accum_dtype == sa.jnp.float64:
+            raise OverflowError(self.F64_OVERFLOW)
         return packed
+
+    def _by_block(self, row: np.ndarray) -> np.ndarray:
+        """A dense plane of a unit, ``(SUB*G,)``, as ``(blocks, SUB*G /
+        blocks)``: row ``b`` holds key block ``b``'s groups of every
+        sub-bucket (a view where there is one block)."""
+        return row.reshape(self.SUB, self.blocks, self.G_block).transpose(
+            1, 0, 2
+        ).reshape(self.blocks, self.block_cells)
 
     def _pack_dense(self, u, lean, n_planes) -> np.ndarray:
         """Dense (index-free) pack of unit ``u``: plane p at row p, cell i
-        = index i of the unit.  No host gather — a strided read of each
-        field; cells without rows already hold the fold-neutral values."""
-        cells = self.unit_cells
+        = index i of the unit (of its key block).  No host gather — a
+        strided read of each field; cells without rows already hold the
+        fold-neutral values."""
+        cells, bc = self.unit_cells, self.block_cells
         unit = self.rec[u * cells : (u + 1) * cells]
-        packed = np.empty((n_planes, cells + 2), np.int32)
-        packed[:, cells:] = 0
+        packed = np.empty((self.blocks, n_planes, bc + 2), np.int32)
+        packed[:, :, bc:] = 0
         for c, pi in self._planes_walk(lean):
             for k, row in enumerate(self._f32_bits(c, unit[:, self._field(c)])):
-                packed[pi + k, :cells] = row
+                packed[:, pi + k, :bc] = self._by_block(row)
         self.bytes_touched += unit.nbytes
         self._dirty.append((slice(u * cells, (u + 1) * cells), cells))
         return packed
+
+    def _noop(self, packed: np.ndarray, a_pad: int) -> np.ndarray:
+        """``packed`` (blocks, rows, a_pad + 2) addressed to no window, in
+        the shape ``take_packed`` hands out."""
+        packed[:, 0, a_pad] = self.NOOP_UNIT
+        return self._handed_out(packed)
 
     def dense_noop(self, lean: bool) -> np.ndarray:
         """An all-neutral DENSE packed matrix addressed to no window (merge
         prewarm, padding of a stack of dense units): count/sum planes zero,
         min/max planes +inf/−inf bit patterns — the planes of a freshly
         reset unit, by the same walk."""
-        cells = self.unit_cells
-        packed = np.zeros((self.n_planes(lean), cells + 2), np.int32)
+        bc = self.block_cells
+        packed = np.zeros((self.blocks, self.n_planes(lean), bc + 2), np.int32)
         for c, pi in self._planes_walk(lean):
             if c.kind in NEUTRAL_BITS:
-                packed[pi, :cells] = NEUTRAL_BITS[c.kind]
-        packed[0, cells] = self.NOOP_UNIT
-        return packed
+                packed[:, pi, :bc] = NEUTRAL_BITS[c.kind]
+        return self._noop(packed, bc)
 
     def compact_noop(self, a_pad: int, lean: bool) -> np.ndarray:
         """An all-padding COMPACT packed matrix (for prewarm)."""
-        packed = np.zeros((self.n_planes(lean) + 1, a_pad + 2), np.int32)
-        packed[0, :a_pad] = -1
-        packed[0, a_pad] = self.NOOP_UNIT
-        return packed
+        packed = np.zeros(
+            (self.blocks, self.n_planes(lean) + 1, a_pad + 2), np.int32
+        )
+        packed[:, 0, :a_pad] = -1
+        return self._noop(packed, a_pad)
 
     def _split_sum(self, src: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         """(hi, lo) f32 split of a host f64 sum plane, int32-bitcast —

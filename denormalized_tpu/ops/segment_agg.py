@@ -346,10 +346,7 @@ def merge_partials(
     ``a_pad == SUB*G``, cell i IS index i of the unit, the index plane is
     omitted (plane p sits at row p, header ints still in row 0's tail
     slots) and cells without rows carry fold-neutral values."""
-    return merge_partials_body(
-        spec, SUB, a_pad, state, packed, spec.group_capacity, None, lean,
-        dense,
-    )
+    return merge_partials_body(spec, SUB, a_pad, state, packed, lean, dense)
 
 
 def lean_skippable(c: AggComponent) -> bool:
@@ -373,15 +370,15 @@ def merge_partials_body(
     a_pad: int,
     state: dict[str, jax.Array],
     packed: jax.Array,
-    G_total: int,
-    g_shift,
     lean: bool = False,
     dense: bool = False,
 ) -> dict[str, jax.Array]:
-    """Shared fold of one slide unit: ``state`` holds the contiguous group
-    slice ``[g_shift, g_shift + cap)`` of a ``G_total``-wide group space
-    (single device: the whole space, ``g_shift`` None; key-sharded mesh:
-    one shard per device, shift = axis_index * G_local).
+    """Shared fold of one slide unit into the ``spec.group_capacity``
+    groups ``state`` holds: the whole group space on a single device, one
+    key block on a key-sharded mesh — there ``spec`` is the device-local
+    one and ``packed`` the block's own share of the unit, its cell ids
+    local to the block (host_partial.take_packed splits a unit by key
+    block), so a device of a mesh runs the program a single device runs.
 
     Each window the unit feeds is ONE ring row: the row is sliced out,
     folded and written back, so a merge reads and writes ``k`` rows of
@@ -395,11 +392,10 @@ def merge_partials_body(
     null-free stripe's per-column counts equal its row counts
     cell-for-cell (host_partial.take_packed).
 
-    Compact: the cells' (s, g) come from the index row; on one device
-    with ``SUB == 1`` they are the row's scatter indices as they stand —
-    ascending and distinct, which the scatter is told.  Dense: plane ``p``
-    is ``SUB`` rows of ``G_total`` cells and folds elementwise, no
-    scatter."""
+    Compact: the cells' (s, g) come from the index row; with ``SUB == 1``
+    they are the row's scatter indices as they stand — ascending and
+    distinct, which the scatter is told.  Dense: plane ``p`` is ``SUB``
+    rows of ``G`` cells and folds elementwise, no scatter."""
     if packed.ndim == 3:
         # a stripe's dense units in one call (the backend stacks them, padded
         # with no-op units to the stripe's span: one transfer and one
@@ -408,7 +404,7 @@ def merge_partials_body(
         def one(j, st):
             unit = jax.lax.dynamic_index_in_dim(packed, j, 0, keepdims=False)
             return merge_partials_body(
-                spec, SUB, a_pad, st, unit, G_total, g_shift, lean, dense
+                spec, SUB, a_pad, st, unit, lean, dense
             )
 
         if packed.shape[0] == 1:
@@ -418,7 +414,7 @@ def merge_partials_body(
     k = spec.length_units
     u_rel = packed[0, a_pad]
     base_mod = packed[0, a_pad + 1]
-    cap = next(iter(state.values())).shape[1]
+    G = spec.group_capacity
     plane0 = 0 if dense else 1
 
     def f32_plane(pi):
@@ -426,32 +422,22 @@ def merge_partials_body(
             packed[plane0 + pi, :a_pad], jnp.float32
         )
 
-    if dense:
-        def sub_rows(pv):
-            """The plane's SUB rows of this device's ``cap`` groups."""
-            rows = pv.reshape(SUB, G_total)
-            if g_shift is None:
-                return rows
-            return jax.lax.dynamic_slice(rows, (0, g_shift), (SUB, cap))
-    else:
+    if not dense:
         idx = packed[0, :a_pad]
-        s = idx // G_total
-        g = idx % G_total - (0 if g_shift is None else g_shift)
-        valid = (idx >= 0) & (g >= 0) & (g < cap)
-        # what the host guarantees, where this device sees it unchanged
-        flags = dict(
-            indices_are_sorted=g_shift is None and SUB == 1,
-            unique_indices=SUB == 1,
-        )
+        s = idx // G
+        g = idx % G
+        valid = idx >= 0
+        # what the host guarantees
+        flags = dict(indices_are_sorted=SUB == 1, unique_indices=SUB == 1)
         # dropped entries scatter out of range, each to a place of its own
-        out_of_range = cap + jnp.arange(a_pad, dtype=jnp.int32)
+        out_of_range = G + jnp.arange(a_pad, dtype=jnp.int32)
 
     def fold(kind, row, pv, ok_sub):
-        """``row`` (cap,) with one plane of the unit folded in; ``ok_sub``
+        """``row`` (G,) with one plane of the unit folded in; ``ok_sub``
         says whether sub-bucket 1 belongs to this window."""
         pv = pv.astype(row.dtype)
         if dense:
-            rows = sub_rows(pv)
+            rows = pv.reshape(SUB, G)
             for sub in range(SUB if ok_sub else 1):
                 r = rows[sub]
                 row = (
@@ -478,12 +464,12 @@ def merge_partials_body(
 
         def apply(label, kind, planes):
             buf = state[label]
-            row = jax.lax.dynamic_slice(buf, (slot, 0), (1, cap)).reshape(cap)
+            row = jax.lax.dynamic_slice(buf, (slot, 0), (1, G)).reshape(G)
             new = row
             for pv in planes:
                 new = fold(kind, new, pv, ok_sub)
             state[label] = jax.lax.dynamic_update_slice(
-                buf, jnp.where(in_ring, new, row).reshape(1, cap), (slot, 0)
+                buf, jnp.where(in_ring, new, row).reshape(1, G), (slot, 0)
             )
 
         pi = 0
@@ -511,8 +497,20 @@ def merge_partials_body(
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 5), donate_argnums=3)
-@jax.named_scope("dnz.gather_and_reset")
 def _gather_and_reset(
+    spec: WindowKernelSpec,
+    n: int,
+    g_bucket: int,
+    state: dict[str, jax.Array],
+    first_slot,
+    lean: bool = False,
+):
+    """Single-device entry of :func:`gather_and_reset_body`."""
+    return gather_and_reset_body(spec, n, g_bucket, state, first_slot, lean)
+
+
+@jax.named_scope("dnz.gather_and_reset")
+def gather_and_reset_body(
     spec: WindowKernelSpec,
     n: int,
     g_bucket: int,
@@ -523,12 +521,12 @@ def _gather_and_reset(
     """Read ``n`` consecutive ring slots AND reset them in one program —
     one device round-trip per emission cycle instead of two per window.
 
-    ``g_bucket`` is the transferred group width — the GLOBAL capacity for
-    sharded layouts (whose static spec carries only the per-device
-    shard), the spec capacity on a single device.  ``lean`` omits
-    per-column count planes from the transfer (they equal the row-count
-    plane when the stream has never carried a null; the host aliases
-    them back)."""
+    ``g_bucket`` is the transferred group width, a prefix of the groups
+    ``state`` holds: of the whole group space on a single device, of the
+    device's own key block under ``shard_map`` on a key-sharded mesh
+    (``spec`` is the device-local one there).  ``lean`` omits per-column
+    count planes from the transfer (they equal the row-count plane when
+    the stream has never carried a null; the host aliases them back)."""
     state, comp = _read_and_reset_slots(spec, n, g_bucket, state, first_slot)
     out = {
         c.label: comp[c.label]
@@ -536,8 +534,6 @@ def _gather_and_reset(
         if not (lean and lean_skippable(c))
     }
     return state, out
-
-
 
 
 def _read_and_reset_slots(
@@ -597,11 +593,16 @@ def pack_active(active: jax.Array) -> jax.Array:
     return jnp.sum(a << shifts, axis=1, dtype=jnp.int32).astype(jnp.uint8)
 
 
-def unpack_active(packed: np.ndarray) -> np.ndarray:
+def unpack_active(packed: np.ndarray, blocks: int = 1) -> np.ndarray:
     """Host inverse of :func:`pack_active`: (n, g // 8) uint8 → (n, g)
-    bool."""
+    bool.  ``blocks``: how many equal runs of the group axis were packed
+    each on its own and laid side by side (one a device of a key-sharded
+    mesh, whose devices each pack their own key block)."""
     n, w = packed.shape
-    bits = np.unpackbits(packed[:, :, None], axis=2, bitorder="little")
+    bits = np.unpackbits(
+        packed.reshape(n * blocks, w // blocks)[:, :, None],
+        axis=2, bitorder="little",
+    )
     return bits.transpose(0, 2, 1).reshape(n, 8 * w).astype(bool)
 
 
@@ -612,8 +613,22 @@ def finals_possible(agg_specs: tuple) -> bool:
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), donate_argnums=4)
-@jax.named_scope("dnz.finals_and_reset")
 def _finals_and_reset(
+    spec: WindowKernelSpec,
+    agg_specs: tuple,
+    n: int,
+    g_bucket: int,
+    state: dict[str, jax.Array],
+    first_slot,
+):
+    """Single-device entry of :func:`finals_and_reset_body`."""
+    return finals_and_reset_body(
+        spec, agg_specs, n, g_bucket, state, first_slot
+    )
+
+
+@jax.named_scope("dnz.finals_and_reset")
+def finals_and_reset_body(
     spec: WindowKernelSpec,
     agg_specs: tuple,
     n: int,

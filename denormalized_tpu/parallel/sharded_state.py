@@ -23,10 +23,20 @@ sharding layouts of the SAME device kernel (`update_state_impl`), wrapped in
   aggregation at extreme ingest rates: input transfer is 1/n per device and
   the merge collective runs once per window, not per batch.
 
-- :class:`SingleDeviceWindowState` — the degenerate 1-device backend used by
-  default (and on the single live chip).
+- :class:`SingleDeviceWindowState` — the degenerate 1-device backend, and
+  :class:`PartialMergeWindowState`, the same ring fed with host-reduced
+  partials instead of rows (``device_strategy='auto'`` on every TPU and
+  CPU backend).
 
-All three present the same interface to the window operator, which stays
+- :class:`KeyShardedPartialMergeWindowState` — ``partial_merge`` over the
+  key-sharded ring, ``auto``'s pick on a 1-D mesh: the host splits each
+  packed stripe unit by key block (binary search over its ascending cell
+  ids), a device receives and folds its own block with the program a single
+  device runs, and emission runs the single device's programs on every
+  block.  Every program that touches the ring is a ``shard_map`` over
+  ``P(None, "keys")``: nothing is replicated, gathered or left to GSPMD.
+
+All present the same interface to the window operator, which stays
 oblivious to the layout.
 """
 
@@ -68,16 +78,39 @@ class WindowStateBackend:
     # flush opens ``window.flush`` on it wherever the flush is set off
     # (trigger, growth, snapshot, or span overflow inside ``accumulate``)
     phases = NULL_CLOCK
+    # contiguous blocks of group ids the ring is split into, one a device:
+    # above 1 on the key-sharded layouts
+    key_blocks: int = 1
 
-    def stripe_counters(self) -> dict[str, int]:
+    def stripe_counters(self) -> dict:
         """What the host stripe's flushes cost, as ``metrics()`` names it:
         ``stripe_cells_active``, ``stripe_cells_shipped``,
-        ``stripe_bytes_touched`` — all 0 for a row-shipping backend."""
+        ``stripe_bytes_touched``, ``stripe_bytes_packed`` — all 0 for a
+        row-shipping backend — and ``merge_cells_by_shard``, the active
+        cells again by the key block (device) they fell in: a list of
+        ``key_blocks`` sums that add up to ``stripe_cells_active``, and
+        the same numbers one by one as ``merge_cells_shard_<i>``."""
         stripe = getattr(self, "_stripe", None)
-        return {
+        out = {
             f"stripe_{name}": getattr(stripe, name, 0)
             for name in HostPartialStripe.COUNTERS
         }
+        by_shard = (
+            [0] * self.key_blocks if stripe is None
+            else stripe.cells_by_block.tolist()
+        )
+        out["merge_cells_by_shard"] = by_shard
+        for i, cells in enumerate(by_shard):
+            out[f"merge_cells_shard_{i}"] = cells
+        return out
+
+    def _count_rows_h2d(self, *arrays) -> None:
+        """Row shipping: the batch's arrays cross from the host once — on
+        a mesh to one device, from which the update program fans them out
+        over the interconnect (``bytes_h2d`` counts what the host sends)."""
+        self.bytes_h2d += sum(
+            int(np.asarray(a).nbytes) for a in arrays if a is not None
+        )
 
     def carry_stripe_counters(self, old: "WindowStateBackend") -> None:
         """Take over the stripe counts and the merge count of the backend
@@ -198,12 +231,11 @@ class SingleDeviceWindowState(WindowStateBackend):
             variants = {False, sa.lean_possible(spec)}
             for n in (1, 2, 4, 8):
                 if n <= spec.window_slots:
-                    for g_bucket in {min(1024, self.group_capacity),
-                                     self.group_capacity}:
+                    for g_bucket in {min(1024, spec.group_capacity),
+                                     spec.group_capacity}:
                         for lean in variants:
-                            self._state, _ = sa._gather_and_reset(
-                                spec, n, g_bucket, self._state,
-                                jnp.asarray(0, jnp.int32), lean,
+                            self._gather(
+                                n, g_bucket, np.int32(0), lean
                             )
 
     @property
@@ -211,11 +243,7 @@ class SingleDeviceWindowState(WindowStateBackend):
         return self.spec.group_capacity
 
     def update(self, values, colvalid, win_rel, rem, gid, row_valid, base_mod):
-        self.bytes_h2d += sum(
-            int(np.asarray(a).nbytes)
-            for a in (values, colvalid, win_rel, rem, gid, row_valid)
-            if a is not None
-        )
+        self._count_rows_h2d(values, colvalid, win_rel, rem, gid, row_valid)
         self._state = sa.update_state(
             self.spec,
             self._state,
@@ -262,13 +290,30 @@ class SingleDeviceWindowState(WindowStateBackend):
         262K-capacity ring, and ~all of it when capacity is
         over-provisioned)."""
         assert n <= self.spec.window_slots  # slots must be distinct
-        g_bucket = self._live_bucket(live_groups)
-        self._state, out = sa._gather_and_reset(
-            self.spec, n, g_bucket, self._state,
-            jnp.asarray(first_slot, jnp.int32), lean,
+        out = self._gather(
+            n, self._live_bucket(live_groups), np.int32(first_slot), lean,
         )
         for arr in out.values():
             arr.copy_to_host_async()
+        return out
+
+    # -- the two emission programs, run on the ring this backend holds: a
+    # key-sharded mesh overrides them with the same bodies under shard_map.
+    # ``g_bucket`` is a prefix of ``self.spec.group_capacity`` groups: of
+    # the ring here, of every device's own key block there.  ``first_slot``
+    # comes as a host scalar, which goes to every device of a mesh with the
+    # call (one put on a device would be resharded to the others first)
+    def _gather(self, n: int, g_bucket: int, first_slot, lean: bool) -> dict:
+        self._state, out = sa._gather_and_reset(
+            self.spec, n, g_bucket, self._state, first_slot, lean
+        )
+        return out
+
+    def _finals(self, n: int, g_bucket: int, first_slot) -> dict:
+        self._state, out = sa._finals_and_reset(
+            self.spec, self._finals_specs, n, g_bucket, self._state,
+            first_slot,
+        )
         return out
 
     def read_reset_block_finish(self, handle) -> dict[str, np.ndarray]:
@@ -280,9 +325,8 @@ class SingleDeviceWindowState(WindowStateBackend):
         self._finals_specs = tuple(agg_specs)
         if _prewarm():
             # pre-compile the finals ladder like the component-gather one
-            # in __init__: an unseen (n, bucket) pair compiling mid-stream
-            # stalls the stream for seconds at a wide ring.  group_capacity
-            # is the property — the GLOBAL width on sharded layouts.
+            # in prepare_gather: an unseen (n, bucket) pair compiling
+            # mid-stream stalls the stream for seconds at a wide ring.
             # Every n the operator's block sizes reach (_close_windows:
             # powers of two up to 8) is reachable on any plan: one step of
             # the watermark can pass several window ends (a feed gap, an
@@ -292,19 +336,21 @@ class SingleDeviceWindowState(WindowStateBackend):
             # for (1.6 GB at 10M groups, freed at once).
             for n in (1, 2, 4, 8):
                 if n <= self.spec.window_slots:
-                    for g_bucket in {min(1024, self.group_capacity),
-                                     self.group_capacity}:
-                        self._state, _ = sa._finals_and_reset(
-                            self.spec, self._finals_specs, n, g_bucket,
-                            self._state, jnp.asarray(0, jnp.int32),
-                        )
+                    for g_bucket in {min(1024, self.spec.group_capacity),
+                                     self.spec.group_capacity}:
+                        self._finals(n, g_bucket, np.int32(0))
 
     def _live_bucket(self, live_groups) -> int:
         """Transferred group width: pow2 of the interner's live size
-        (floor 1024), capped at capacity — the single bucketing policy
-        for every emission ladder (component gather AND finals), so both
-        prewarm sets stay aligned with runtime requests."""
-        g_bucket = self.group_capacity
+        (floor 1024), capped at the capacity of the ring a device holds —
+        the single bucketing policy for every emission ladder (component
+        gather AND finals), so both prewarm sets stay aligned with runtime
+        requests.  On a key-sharded mesh every device hands back this
+        prefix of its own key block: ids are dealt in order, so either the
+        live ids all lie in block 0's prefix, or the prefix is the whole
+        block — position ``p`` of the assembled row is group ``p`` for
+        every live ``p`` either way."""
+        g_bucket = self.spec.group_capacity
         if live_groups is not None:
             g_bucket = min(
                 g_bucket,
@@ -319,10 +365,8 @@ class SingleDeviceWindowState(WindowStateBackend):
         if specs is None:
             return None
         assert n <= self.spec.window_slots
-        g_bucket = self._live_bucket(live_groups)
-        self._state, out = sa._finals_and_reset(
-            self.spec, specs, n, g_bucket, self._state,
-            jnp.asarray(first_slot, jnp.int32),
+        out = self._finals(
+            n, self._live_bucket(live_groups), np.int32(first_slot),
         )
         for arr in out.values():
             arr.copy_to_host_async()
@@ -354,7 +398,9 @@ class _HostPartialMixin:
     accumulates_host = True
 
     def _init_host_partial(self, stripe_group_capacity: int) -> None:
-        self._stripe = HostPartialStripe(self.spec, stripe_group_capacity)
+        self._stripe = HostPartialStripe(
+            self.spec, stripe_group_capacity, self.key_blocks
+        )
         self._pending_base_mod = 0
         self.merges = 0
         if _prewarm():
@@ -376,8 +422,8 @@ class _HostPartialMixin:
                 # mask); layout owned by the stripe.  A flush's dense
                 # units go stacked, U to a call
                 self._merge(
-                    np.stack([stripe.dense_noop(lean)] * stripe.U),
-                    stripe.unit_cells, lean, dense=True,
+                    np.stack([stripe.dense_noop(lean)] * stripe.U, axis=-3),
+                    stripe.block_cells, lean, dense=True,
                 )
 
     @property
@@ -470,7 +516,9 @@ class _HostPartialMixin:
             # one transfer and one merge program per compact unit, and one
             # for all the dense units together (stacked, the stack padded
             # with no-op units to the stripe's span): a stripe of many small
-            # units costs one dispatch, not one each
+            # units costs one dispatch, not one each.  ``bytes_h2d`` counts
+            # every byte the host sends, once: on a mesh a packed matrix is
+            # a block a device and each block goes to its own device only
             stripe = self._stripe
             dense_units = []
             for packed, a_pad, lean, dense in stripe.take_packed(
@@ -483,14 +531,18 @@ class _HostPartialMixin:
                 self._merge(packed, a_pad, lean, dense)
             if dense_units:
                 pad = stripe.U - len(dense_units)
-                dense_units += [stripe.dense_noop(lean)] * pad
+                noop = stripe.dense_noop(lean)
+                dense_units += [noop] * pad
                 stripe.cells_shipped += pad * stripe.unit_cells
+                stripe.bytes_packed += pad * noop.nbytes
+                # units stacked in front of the (planes, cells) axes,
+                # behind the key-block axis where there is one
                 stacked = (
-                    dense_units[0][None] if stripe.U == 1
-                    else np.stack(dense_units)
+                    np.expand_dims(dense_units[0], -3) if stripe.U == 1
+                    else np.stack(dense_units, axis=-3)
                 )
                 self.bytes_h2d += int(stacked.nbytes)
-                self._merge(stacked, stripe.unit_cells, lean, True)
+                self._merge(stacked, stripe.block_cells, lean, True)
             self.merges += 1
 
 
@@ -538,6 +590,24 @@ def _mask_to_key_shard(spec: sa.WindowKernelSpec, gid, row_valid):
     return jnp.clip(local_gid, 0, G_local - 1), mine
 
 
+def _ring_specs(spec: sa.WindowKernelSpec) -> dict:
+    """The ring's partitioning on a 1-D mesh, a spec a component: window
+    slots whole, groups split into key blocks.  Every program below that
+    touches the ring takes and returns it under these specs inside
+    ``shard_map``, so no compiler decision can gather or replicate it."""
+    return {c.label: P(None, KEY_AXIS) for c in spec.components}
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _key_sharded_init(spec: sa.WindowKernelSpec, mesh: Mesh):
+    """A fresh ring, each device's block made on that device (``spec`` is
+    the device-local one)."""
+    return shard_map(
+        lambda: sa.init_state(spec), mesh=mesh, in_specs=(),
+        out_specs=_ring_specs(spec),
+    )()
+
+
 @functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=2)
 def _key_sharded_update(
     spec: sa.WindowKernelSpec,
@@ -560,17 +630,8 @@ def _key_sharded_update(
     return shard_map(
         body,
         mesh=mesh,
-        in_specs=(
-            {c.label: P(None, KEY_AXIS) for c in spec.components},
-            P(),
-            P(),
-            P(),
-            P(),
-            P(),
-            P(),
-            P(),
-        ),
-        out_specs={c.label: P(None, KEY_AXIS) for c in spec.components},
+        in_specs=(_ring_specs(spec), P(), P(), P(), P(), P(), P(), P()),
+        out_specs=_ring_specs(spec),
     )(state, values, colvalid, win_rel, rem, gid, row_valid, base_mod)
 
 
@@ -600,23 +661,18 @@ class KeyShardedWindowState(WindowStateBackend):
             accum_dtype=spec.accum_dtype,
             compensated=spec.compensated,
         )
+        self.key_blocks = n
         self._sharding = NamedSharding(mesh, P(None, KEY_AXIS))
-        self._state = {
-            c.label: jax.device_put(
-                jnp.full(
-                    (spec.window_slots, spec.group_capacity),
-                    self.spec.init_value(c),
-                ),
-                self._sharding,
-            )
-            for c in spec.components
-        }
+        # born sharded: every device fills its own block (a full ring made
+        # on one device first would be the whole ring's bytes there)
+        self._state = _key_sharded_init(self.spec, mesh)
 
     @property
     def group_capacity(self) -> int:
         return self.spec.group_capacity * self.n
 
     def update(self, values, colvalid, win_rel, rem, gid, row_valid, base_mod):
+        self._count_rows_h2d(values, colvalid, win_rel, rem, gid, row_valid)
         self._state = _key_sharded_update(
             self.spec,
             self.mesh,
@@ -639,7 +695,7 @@ class KeyShardedWindowState(WindowStateBackend):
 
     def reset_slot(self, slot: int) -> None:
         self._state = _key_sharded_reset_slot(
-            self.spec, self._state, jnp.asarray(slot, dtype=jnp.int32)
+            self.spec, self.mesh, self._state, np.int32(slot)
         )
 
     def export(self) -> dict[str, np.ndarray]:
@@ -658,9 +714,8 @@ class KeyShardedWindowState(WindowStateBackend):
                 w = min(src.shape[0], W)
                 g = min(src.shape[1], G_total)
                 buf[:w, :g] = src[:w, :g]
-            self._state[c.label] = jax.device_put(
-                jnp.asarray(buf), self._sharding
-            )
+            # from the host straight to each device's own block
+            self._state[c.label] = jax.device_put(buf, self._sharding)
 
 
 @functools.partial(
@@ -676,38 +731,74 @@ def _key_sharded_merge_partials(
     state,
     packed,
 ):
-    """Sharded fold of one host-partial stripe: the packed matrix is
-    replicated over ICI and every device folds only the cells whose group
-    id lands in its block — the hash-exchange analog for partials (no
-    collective needed; the "exchange" rides the input broadcast)."""
-    G_local = spec.group_capacity
-    n = mesh.devices.size
+    """Sharded fold of one host-partial stripe: ``packed`` holds one matrix
+    a key block (``HostPartialStripe(..., key_blocks=n)`` split the unit on
+    the host, ids local to the block) and device ``b`` receives and folds
+    block ``b`` alone — the program a single device runs, over its own
+    groups.  The hash exchange of the reference, done by binary search
+    before the transfer: no collective, nothing replicated."""
 
     def body(state_l, packed_l):
-        shift = jax.lax.axis_index(KEY_AXIS) * G_local
         return sa.merge_partials_body(
-            spec, SUB, a_pad, state_l, packed_l, G_local * n, shift, lean,
-            dense,
+            spec, SUB, a_pad, state_l, packed_l[0], lean, dense
         )
 
     return shard_map(
         body,
         mesh=mesh,
-        in_specs=({c.label: P(None, KEY_AXIS) for c in spec.components}, P()),
-        out_specs={c.label: P(None, KEY_AXIS) for c in spec.components},
+        in_specs=(_ring_specs(spec), P(KEY_AXIS)),
+        out_specs=_ring_specs(spec),
     )(state, packed)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4), donate_argnums=5)
+def _key_sharded_gather_and_reset(
+    spec: sa.WindowKernelSpec, mesh: Mesh, n: int, g_bucket: int, lean: bool,
+    state, first_slot,
+):
+    """:func:`segment_agg.gather_and_reset_body` on every device's own key
+    block: the ``g_bucket`` prefix of each block, side by side."""
+    return shard_map(
+        lambda state_l, slot: sa.gather_and_reset_body(
+            spec, n, g_bucket, state_l, slot, lean
+        ),
+        mesh=mesh,
+        in_specs=(_ring_specs(spec), P()),
+        out_specs=(_ring_specs(spec), P(None, KEY_AXIS)),
+    )(state, first_slot)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4), donate_argnums=5)
+def _key_sharded_finals_and_reset(
+    spec: sa.WindowKernelSpec, mesh: Mesh, agg_specs: tuple, n: int,
+    g_bucket: int, state, first_slot,
+):
+    """:func:`segment_agg.finals_and_reset_body` on every device's own key
+    block: finals and active bits of the ``g_bucket`` prefix of each block,
+    side by side (``segment_agg.unpack_active(bits, blocks=n_devices)``)."""
+    return shard_map(
+        lambda state_l, slot: sa.finals_and_reset_body(
+            spec, agg_specs, n, g_bucket, state_l, slot
+        ),
+        mesh=mesh,
+        in_specs=(_ring_specs(spec), P()),
+        out_specs=(_ring_specs(spec), P(None, KEY_AXIS)),
+    )(state, first_slot)
+
+
 class KeyShardedPartialMergeWindowState(_HostPartialMixin, KeyShardedWindowState):
-    """partial_merge over a device mesh: host stripes cover the GLOBAL
-    group space; each device merges its own group block from the
-    replicated packed stripe.  Emission gathers/reset via a fused global
-    program (GSPMD partitions it over the same sharding)."""
+    """partial_merge over a device mesh: the host stripe covers the GLOBAL
+    group space and packs each unit split by key block, a device merging
+    its own block's share.  Emission runs the single device's gather and
+    finals programs on every device's block under ``shard_map``: the ring,
+    the finals and the active bits stay split over the key axis from the
+    first program to the copy back to the host."""
 
     strategy_name = "partial_merge/key_sharded"
 
     def __init__(self, spec: sa.WindowKernelSpec, mesh: Mesh):
         super().__init__(spec, mesh)
+        self._packed_sharding = NamedSharding(mesh, P(KEY_AXIS))
         # stripe spans the GLOBAL group space
         self._init_host_partial(self.group_capacity)
 
@@ -717,13 +808,24 @@ class KeyShardedPartialMergeWindowState(_HostPartialMixin, KeyShardedWindowState
     ) -> None:
         self._state = _key_sharded_merge_partials(
             self.spec, self.mesh, self._stripe.SUB, a_pad, lean, dense,
-            self._state, jnp.asarray(packed),
+            self._state, jax.device_put(packed, self._packed_sharding),
         )
 
-    # fused async gather+reset + on-device finalization: identical
-    # machinery to the single-device backend
-    # (self.group_capacity is the global width here; GSPMD partitions the
-    # programs over the key sharding)
+    def _gather(self, n: int, g_bucket: int, first_slot, lean: bool) -> dict:
+        self._state, out = _key_sharded_gather_and_reset(
+            self.spec, self.mesh, n, g_bucket, lean, self._state, first_slot
+        )
+        return out
+
+    def _finals(self, n: int, g_bucket: int, first_slot) -> dict:
+        self._state, out = _key_sharded_finals_and_reset(
+            self.spec, self.mesh, self._finals_specs, n, g_bucket,
+            self._state, first_slot,
+        )
+        return out
+
+    # the async block emission and its prewarm are the single device's,
+    # over the two programs above
     read_reset_block = SingleDeviceWindowState.read_reset_block
     read_reset_block_start = SingleDeviceWindowState.read_reset_block_start
     read_reset_block_finish = SingleDeviceWindowState.read_reset_block_finish
@@ -868,13 +970,23 @@ def _partial_reset_slot(spec: sa.WindowKernelSpec, state, slot):
     return state
 
 
-@functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
-def _key_sharded_reset_slot(spec: sa.WindowKernelSpec, state, slot):
-    for c in spec.components:
-        buf = state[c.label]
-        row = jnp.full((buf.shape[1],), spec.init_value(c))
-        state[c.label] = buf.at[slot].set(row.astype(buf.dtype))
-    return state
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=2)
+def _key_sharded_reset_slot(
+    spec: sa.WindowKernelSpec, mesh: Mesh, state, slot
+):
+    """Every device re-initializes the slot in its own block (``spec`` is
+    the device-local one)."""
+    def body(state_l, slot):
+        for c in spec.components:
+            buf = state_l[c.label]
+            row = jnp.full((buf.shape[1],), spec.init_value(c))
+            state_l[c.label] = buf.at[slot].set(row.astype(buf.dtype))
+        return state_l
+
+    return shard_map(
+        body, mesh=mesh, in_specs=(_ring_specs(spec), P()),
+        out_specs=_ring_specs(spec),
+    )(state, slot)
 
 
 class PartialFinalWindowState(WindowStateBackend):
@@ -906,6 +1018,7 @@ class PartialFinalWindowState(WindowStateBackend):
     def update(self, values, colvalid, win_rel, rem, gid, row_valid, base_mod):
         # rows must split evenly over the mesh: bucketed batches are powers
         # of two >= mesh size, so this holds by construction
+        self._count_rows_h2d(values, colvalid, win_rel, rem, gid, row_valid)
         self._state = _partial_update(
             self.spec,
             self.mesh,
@@ -1046,6 +1159,7 @@ class TwoLevelWindowState(WindowStateBackend):
     def update(self, values, colvalid, win_rel, rem, gid, row_valid, base_mod):
         # rows split S ways (bucketed pow2 batches >= mesh rows by
         # construction, same invariant as PartialFinalWindowState)
+        self._count_rows_h2d(values, colvalid, win_rel, rem, gid, row_valid)
         self._state = _two_level_update(
             self.spec,
             self.mesh,
@@ -1096,8 +1210,27 @@ def make_sharded_state(
     strategy: str = "auto",
     device_strategy: str = "scatter",
 ) -> WindowStateBackend:
-    """Pick a layout: small state → Partial/Final (duplicate it, shard rows);
-    large state → key-sharded (shard it, broadcast rows)."""
+    """Pick a backend from the mesh, the shard ``strategy`` and the
+    ``device_strategy``.
+
+    ``device_strategy='auto'`` is one rule on one device and on a 1-D mesh:
+    host edge-reduction (``partial_merge``) on every TPU and CPU backend,
+    f64 accumulators on the CPU excepted — partials are orders of magnitude
+    smaller than rows, so the host↔device traffic follows cardinality and
+    not the row rate.  On one device that is
+    :class:`PartialMergeWindowState`; on a 1-D mesh, with the shard
+    strategy left at ``auto`` too, :class:`KeyShardedPartialMergeWindowState`
+    (every device folds its own key block's share of the stripe).  Measured
+    on four v5e chips at 40M groups against the row-shipping ``key_sharded``
+    layout that ``auto`` used to pick there (PERF.md section 6, PR 31).  A
+    mesh that spans processes keeps row shipping: a process's stripe cannot
+    reach another process's devices.
+
+    Row shipping stays available by name: ``device_strategy='scatter'``, or
+    a shard strategy named on a mesh — ``key_sharded`` (shard the state,
+    broadcast the rows; ``auto``'s pick above 4096 groups where the rule
+    above does not apply), ``partial_final`` (duplicate the small state,
+    shard the rows), ``two_level`` on a 2-D mesh."""
     if device_strategy not in ("scatter", "auto", "partial_merge"):
         raise ValueError(
             f"unknown device strategy {device_strategy!r} (expected "
@@ -1108,29 +1241,26 @@ def make_sharded_state(
     from denormalized_tpu.api.context import enable_compilation_cache
 
     enable_compilation_cache()
+    # 'auto' chooses host edge-reduction on EVERY TPU and CPU backend.  For
+    # one TPU the rule was chosen on an earlier installation (ROADMAP S2);
+    # on four it was measured (PR 31); for CPU JAX it rests on host runs in
+    # which the native single-pass reducer (native/partial_agg.cpp) beat
+    # shipping rows through XLA's scatter adds.  Row shipping stays the
+    # 'auto' pick on backends neither covers (e.g. a GPU).
+    # ... except f64 accumulators on CPU: the partial_merge stripe
+    # transports f64 as an f32 hi/lo split and refuses finite sums beyond
+    # f32 range (ops/host_partial.py), while CPU XLA scatter keeps f64
+    # end-to-end — don't let 'auto' turn a working f64 workload into a
+    # runtime OverflowError.
+    backend = jax.default_backend()
+    f64_on_cpu = spec.accum_dtype == jnp.float64 and backend == "cpu"
+    auto_partial = (
+        device_strategy == "auto"
+        and backend in ("tpu", "cpu")
+        and not f64_on_cpu
+    )
     if mesh is None or mesh.devices.size == 1:
-        # 'auto' chooses host edge-reduction on EVERY single-device
-        # TPU and CPU backend: partials are orders of magnitude smaller
-        # than rows, so the host↔device traffic scales with cardinality
-        # instead of the row rate.  For the TPU this rule was chosen on
-        # an earlier installation, not measured on this one (ROADMAP
-        # S2); for CPU JAX it rests on host runs in which the native
-        # single-pass reducer (native/partial_agg.cpp) beat shipping
-        # rows through XLA's scatter adds.  Row shipping remains
-        # available explicitly ('scatter') and stays the 'auto' pick on
-        # backends neither covers (e.g. a GPU).
-        # ... except f64 accumulators on CPU: the partial_merge stripe
-        # transports f64 as an f32 hi/lo split and refuses finite sums
-        # beyond f32 range (ops/host_partial.py), while CPU XLA scatter
-        # keeps f64 end-to-end — don't let 'auto' turn a working f64
-        # workload into a runtime OverflowError.
-        backend = jax.default_backend()
-        f64_on_cpu = spec.accum_dtype == jnp.float64 and backend == "cpu"
-        if device_strategy == "partial_merge" or (
-            device_strategy == "auto"
-            and backend in ("tpu", "cpu")
-            and not f64_on_cpu
-        ):
+        if device_strategy == "partial_merge" or auto_partial:
             return PartialMergeWindowState(spec)
         return SingleDeviceWindowState(spec)
     if SLICE_AXIS in mesh.axis_names:
@@ -1152,7 +1282,9 @@ def make_sharded_state(
         raise ValueError(
             "two_level needs a 2-D mesh — set EngineConfig.mesh_slices"
         )
-    if device_strategy == "partial_merge":
+    if device_strategy == "partial_merge" or (
+        auto_partial and strategy == "auto" and not mesh.is_multi_process
+    ):
         # host partials imply the Partial/Final split already happened on
         # the host, so the mesh's job is holding the (large) group space:
         # the key-sharded layout is the only one that makes sense here

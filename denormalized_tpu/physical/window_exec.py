@@ -649,8 +649,9 @@ class StreamingWindowExec(ExecOperator):
         m["bytes_h2d"] = self._backend.bytes_h2d
         m["bytes_d2h"] = self._backend.bytes_d2h
         # what the stripe's flushes cost: cells with rows, cells sent
-        # (padding included), host bytes scanned and rewritten (0 for a
-        # row-shipping backend)
+        # (padding included), host bytes scanned and rewritten, bytes of
+        # the packed matrices (0 for a row-shipping backend), and the
+        # cells with rows by the key block (device) they fell in
         m.update(self._backend.stripe_counters())
         ms = self._phases.ms
         for key in WINDOW_PHASES:
@@ -675,8 +676,12 @@ class StreamingWindowExec(ExecOperator):
             else dict.fromkeys(INTERN_STATS, 0)
         )
         # what 'auto' actually chose (a report must RECORD the resolved
-        # strategy, not just the request) — each backend labels itself
+        # strategy, not just the request) — each backend labels itself —
+        # and over how many devices the ring is laid out
         m["strategy_resolved"] = self._backend.strategy_name
+        m["mesh_devices"] = (
+            1 if self._mesh is None else int(self._mesh.devices.size)
+        )
         return m
 
     def _label(self):
@@ -1199,7 +1204,9 @@ class StreamingWindowExec(ExecOperator):
         if is_finals:
             # finals block: one plane per output aggregate + packed
             # active bitmask; no host-side finalize needed
-            bits = sa.unpack_active(block[sa.ACTIVE_BITS])
+            bits = sa.unpack_active(
+                block[sa.ACTIVE_BITS], self._backend.key_blocks
+            )
             planes = [
                 block[f"__final_{k}__"] for k in range(len(self.aggr_exprs))
             ]

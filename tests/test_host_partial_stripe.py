@@ -152,7 +152,7 @@ def test_every_layout_a_unit_can_take_was_announced(G, sub):
     spec = _spec(G, 1000, 1000 if sub == 1 else 400)
     stripe = HostPartialStripe.__new__(HostPartialStripe)  # no allocation
     stripe.spec, stripe.G, stripe.SUB = spec, G, sub
-    stripe.unit_cells = sub * G
+    stripe.unit_cells = stripe.block_cells = sub * G
     stripe._buckets = HostPartialStripe.buckets_for(sub * G)
     buckets = stripe.transfer_buckets()
     assert buckets == sorted(set(buckets))

@@ -84,6 +84,10 @@ WINDOW_KEYS = {
     "project_native_batches",
     # what the host stripe's flushes touched and sent
     "stripe_cells_active", "stripe_cells_shipped", "stripe_bytes_touched",
+    "stripe_bytes_packed",
+    # the active cells again by key block (one block without a mesh), as a
+    # list and one by one; and the devices the ring is laid out over
+    "merge_cells_by_shard", "merge_cells_shard_0", "mesh_devices",
     # the native interner's tallies (docs/observability.md, Spans)
     "intern_rows", "intern_extra_probes", "intern_overflow_rows",
 }

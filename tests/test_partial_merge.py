@@ -663,6 +663,57 @@ def test_auto_strategy_on_cpu_partial_merge_except_f64(monkeypatch):
     )
 
 
+@pytest.mark.parametrize("backend", ["tpu", "cpu", "gpu"])
+def test_auto_strategy_on_a_1d_mesh_follows_the_one_device_rule(
+    monkeypatch, backend
+):
+    """On a 1-D mesh 'auto' / 'auto' is the one-device rule: host
+    edge-reduction on every TPU and CPU backend (each device folding its
+    own key block's share of the stripe), f64 on the CPU excepted — decided
+    on four v5e chips at 40M groups (PERF.md section 6, PR 31), where the
+    row-shipping key_sharded layout that 'auto' used to pick was held
+    against it.  Row shipping stays reachable by name."""
+    import jax
+    import jax.numpy as jnp
+
+    import denormalized_tpu.parallel.sharded_state as ss
+    from denormalized_tpu.ops import segment_agg as sa
+    from denormalized_tpu.parallel.mesh import make_mesh
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four virtual devices")
+    monkeypatch.setattr(ss.jax, "default_backend", lambda: backend)
+    # routing is what this test pins: keep the TPU's prewarm ladders out
+    monkeypatch.setattr(ss, "_prewarm", lambda: False)
+    mesh = make_mesh(4)
+
+    def make(strategy, device_strategy, dtype=jnp.float32, G=8192):
+        spec = sa.WindowKernelSpec(
+            components=tuple(sa.components_for([("sum", 0)])),
+            num_value_cols=1, window_slots=4, group_capacity=G,
+            length_ms=1000, slide_ms=1000, accum_dtype=dtype,
+        )
+        return ss.make_sharded_state(spec, mesh, strategy, device_strategy)
+
+    auto = make("auto", "auto")
+    if backend == "gpu":  # neither measured nor covered: rows are shipped
+        assert type(auto) is ss.KeyShardedWindowState
+    else:
+        assert type(auto) is ss.KeyShardedPartialMergeWindowState
+        assert auto.strategy_name == "partial_merge/key_sharded"
+        assert auto.key_blocks == 4
+    if backend == "cpu":
+        assert type(make("auto", "auto", jnp.float64)) is (
+            ss.KeyShardedWindowState)
+    # by name: a shard strategy, or row shipping, is taken as asked
+    assert type(make("key_sharded", "auto")) is ss.KeyShardedWindowState
+    assert type(make("partial_final", "auto")) is ss.PartialFinalWindowState
+    assert type(make("auto", "scatter")) is ss.KeyShardedWindowState
+    assert type(make("auto", "scatter", G=4096)) is ss.PartialFinalWindowState
+    assert type(make("auto", "partial_merge")) is (
+        ss.KeyShardedPartialMergeWindowState)
+
+
 @pytest.mark.parametrize(
     "backend,expected_lag_s",
     [("cpu", 0.0), ("tpu", 0.2), ("gpu", 0.2)],

@@ -23,16 +23,31 @@ B = 131_072  # the `simple` deployment's arrival batch
 
 
 @pytest.fixture(scope="module")
-def v5e():
+def topo():
     try:
         from jax.experimental import topologies
 
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # noqa: BLE001 — any failure means "no libtpu here"
         pytest.skip(f"cannot build a v5e topology: {type(e).__name__}: {e}")
+
+
+@pytest.fixture(scope="module")
+def v5e(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def v5e_mesh(topo):
+    """The four chips of one v5e host as the 1-D key mesh."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from denormalized_tpu.parallel.mesh import KEY_AXIS
+
+    return Mesh(np.array(topo.devices), (KEY_AXIS,))
 
 
 def _sds(sharding, shape, dtype):
@@ -149,3 +164,61 @@ def test_emission_programs_compile(v5e):
     sa._finals_and_reset.lower(
         spec, aggs, 8, G, _state(spec, v5e), slot
     ).compile()
+
+
+def test_keyed_40m_programs_keep_the_ring_split_over_four_chips(v5e_mesh):
+    """benchmark/configs/keyed_40m.json on the four chips of one host: the
+    merge and the finals emission of the key-sharded partial_merge backend
+    at the real size — a 12.8 GB ring, 3.2 GB a chip.  Every chip folds its
+    own key block in place, no program holds a collective, the finals and
+    the active bits come back split over the key axis, and the fullest
+    program (the prewarm's n = 8 block) leaves a chip two thirds empty.
+    (Left to GSPMD, the same emission gathered 7.7 GB of scratch a chip at a
+    bucket that does not align with the key blocks.)"""
+    import dataclasses
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from denormalized_tpu.parallel import sharded_state as ss
+    from denormalized_tpu.parallel.mesh import KEY_AXIS
+
+    spec10, aggs = _keyed_10m_spec()  # the device-local spec: 10M groups
+    n, G = 4, 40_000_000
+    assert spec10.group_capacity * n == G
+    split = NamedSharding(v5e_mesh, P(None, KEY_AXIS))
+    state = {
+        c.label: _sds(split, (16, G), spec10.init_value(c).dtype)
+        for c in spec10.components
+    }
+    slot = _sds(NamedSharding(v5e_mesh, P()), (), jnp.int32)
+    ring = 16 * spec10.group_capacity * 4 * len(spec10.components)  # a chip
+    stripe = HostPartialStripe.__new__(HostPartialStripe)  # no 1.6 GB here
+    stripe.block_cells = spec10.group_capacity
+    stripe._buckets = HostPartialStripe.buckets_for(stripe.block_cells)
+    assert stripe.transfer_buckets()[-1] == 1 << 23
+
+    def check(compiled, out_bytes):
+        m = compiled.memory_analysis()
+        assert m.alias_size_in_bytes == ring  # the block folded in place
+        assert m.output_size_in_bytes - ring <= out_bytes + 4096  # padding
+        assert ring + out_bytes + m.temp_size_in_bytes < 6e9
+        assert not re.search(
+            r"all-gather|all-reduce|all-to-all|collective-permute",
+            compiled.as_text(),
+        )
+        for sh in jax.tree.leaves(compiled.output_shardings):
+            assert sh.is_equivalent_to(split, 2)
+
+    a_pad = 1 << 21  # a 4M-row stripe's quarter, padded
+    packed = _sds(
+        NamedSharding(v5e_mesh, P(KEY_AXIS)), (n, 6, a_pad + 2), jnp.int32
+    )
+    check(ss._key_sharded_merge_partials.lower(
+        spec10, v5e_mesh, 1, a_pad, True, False, state, packed
+    ).compile(), 0)
+    for blocks in (1, 8):
+        check(ss._key_sharded_finals_and_reset.lower(
+            spec10, v5e_mesh, aggs, blocks, spec10.group_capacity, state, slot
+        ).compile(), blocks * spec10.group_capacity * (5 * 4 + 1))
+
