@@ -156,6 +156,9 @@ class WindowStateBackend:
     # returns a handle; finish materializes it on host.  The default is
     # synchronous (start does the work); device backends override start to
     # return in-flight device arrays so the transfer overlaps ingest.
+    # ``finish`` touches the handle alone — the operator runs it on a worker
+    # thread beside ingest — and ``count_block_d2h`` books the block it
+    # returned, on the thread that drives the backend.
     def read_reset_block_start(
         self, first_slot: int, n: int, live_groups=None, lean=False
     ):
@@ -163,6 +166,10 @@ class WindowStateBackend:
 
     def read_reset_block_finish(self, handle) -> dict[str, "np.ndarray"]:
         return handle
+
+    def count_block_d2h(self, block: dict) -> None:
+        """Add a block ``read_reset_block_finish`` returned to
+        ``bytes_d2h``.  Nothing here: ``read_slot`` counted it."""
 
     # -- emission prewarm: the operator calls the one its plan can reach --
     def prepare_gather(self) -> None:
@@ -266,11 +273,6 @@ class SingleDeviceWindowState(WindowStateBackend):
             self.spec, self._state, jnp.asarray(slot, dtype=jnp.int32)
         )
 
-    def read_reset_block(self, first_slot: int, n: int) -> dict[str, np.ndarray]:
-        return self.read_reset_block_finish(
-            self.read_reset_block_start(first_slot, n)
-        )
-
     def read_reset_block_start(
         self, first_slot: int, n: int, live_groups=None, lean=False
     ):
@@ -317,9 +319,10 @@ class SingleDeviceWindowState(WindowStateBackend):
         return out
 
     def read_reset_block_finish(self, handle) -> dict[str, np.ndarray]:
-        out = jax.device_get(handle)
-        self.bytes_d2h += sum(int(a.nbytes) for a in out.values())
-        return out
+        return jax.device_get(handle)
+
+    def count_block_d2h(self, block: dict) -> None:
+        self.bytes_d2h += sum(int(a.nbytes) for a in block.values())
 
     def prepare_finals(self, agg_specs: tuple) -> None:
         self._finals_specs = tuple(agg_specs)
@@ -826,9 +829,9 @@ class KeyShardedPartialMergeWindowState(_HostPartialMixin, KeyShardedWindowState
 
     # the async block emission and its prewarm are the single device's,
     # over the two programs above
-    read_reset_block = SingleDeviceWindowState.read_reset_block
     read_reset_block_start = SingleDeviceWindowState.read_reset_block_start
     read_reset_block_finish = SingleDeviceWindowState.read_reset_block_finish
+    count_block_d2h = SingleDeviceWindowState.count_block_d2h
     _live_bucket = SingleDeviceWindowState._live_bucket
     prepare_gather = SingleDeviceWindowState.prepare_gather
     prepare_finals = SingleDeviceWindowState.prepare_finals

@@ -107,6 +107,10 @@ WINDOW_PHASES = (
     "trigger", "flush_send", "flush_pack", "gather",
     "d2h_wait", "finalize", "other",
 )
+#: the one key of that clock which is not the pull thread's: the ``-d2h``
+#: worker's ``window.d2h_fetch``, surfaced as ``d2h_fetch_ms`` — time beside
+#: the phases above, not part of their sum
+D2H_FETCH = "d2h_fetch"
 
 
 def _next_pow2(n: int) -> int:
@@ -515,7 +519,7 @@ class StreamingWindowExec(ExecOperator):
         )
         # bound like the registry instruments below: the shared falsy null
         # under metrics_enabled=False
-        self._phases = phase_clock("window", WINDOW_PHASES)
+        self._phases = phase_clock("window", WINDOW_PHASES + (D2H_FETCH,))
         self._backend = self._new_backend()
         # on-device finalization: emission ships final output planes + an
         # active bitmask instead of raw component planes (see
@@ -594,9 +598,12 @@ class StreamingWindowExec(ExecOperator):
         self._emit_lag_s = emit_lag_ms / 1000.0
         self._merge_rows = partial_merge_rows
         self._stripe_wall: float | None = None
-        # dispatched-but-unmaterialized emission blocks:
-        # (j0, n, handle, is_finals)
+        # dispatched-but-untaken emission blocks, ascending:
+        # (j0, n, handle, is_finals, fetch) — ``fetch`` is the future of the
+        # ``-d2h`` worker bringing the block to the host, or None where the
+        # trigger that dispatched the block drains it itself
         self._pending_emit: list[tuple] = []
+        self._emit_exec = None
         # async checkpoint in flight: (epoch, meta, backend, handle), plus
         # the barrier marker held until the snapshot is durable
         self._pending_snapshot: tuple | None = None
@@ -609,6 +616,11 @@ class StreamingWindowExec(ExecOperator):
             "device_steps": 0,
             "partial_merges": 0,
             "grow_events": 0,
+            # deferred emission blocks the pull thread took when their fetch
+            # had landed, and those something made it wait for
+            # (_drain_pending)
+            "emit_blocks_overlapped": 0,
+            "emit_blocks_waited": 0,
             # whole duration of the spans around hints, markers and
             # end-of-stream: the hint path's counterpart of dnz_op_batch_ms
             "hint_path_ms": 0.0,
@@ -661,6 +673,10 @@ class StreamingWindowExec(ExecOperator):
         m["phase_ms_flush"] = (
             m["phase_ms_flush_send"] + m["phase_ms_flush_pack"]
         )
+        # what the emission blocks' bytes cost on the ``-d2h`` worker, beside
+        # the pull thread's phases (phase_ms_d2h_wait is the part of it the
+        # pull thread had to stand still for)
+        m["d2h_fetch_ms"] = ms.get(D2H_FETCH, 0.0)
         # what ``window.statewatch`` ran: batches sketched, and how many of
         # them the native pass folded (all, where the library loaded)
         m["sketch_update_batches"] = self._sw.update_batches
@@ -1142,16 +1158,21 @@ class StreamingWindowExec(ExecOperator):
             # half-updated stripe
             raise err
 
+    def _one_worker(self, role: str):
+        """A pool of one thread, ``<operator>-<role>``: its tasks run in
+        the order they were handed over."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        return ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix=f"{self.name}-{role}"
+        )
+
     def _submit_acc(self, bno: int, args: tuple) -> None:
         if self._acc_error is not None:
             err, self._acc_error = self._acc_error, None
             raise err
         if self._acc_exec is None:
-            from concurrent.futures import ThreadPoolExecutor
-
-            self._acc_exec = ThreadPoolExecutor(
-                max_workers=1, thread_name_prefix=f"{self.name}-acc"
-            )
+            self._acc_exec = self._one_worker("acc")
 
         backend = self._backend
         ph = self._phases
@@ -1181,20 +1202,60 @@ class StreamingWindowExec(ExecOperator):
         )
         return max(0, int(wm_win) - self._first_open)
 
-    def _drain_pending(self) -> Iterator[RecordBatch]:
-        """Materialize previously dispatched emission blocks (their
-        device→host transfers have been running in the background)."""
-        if not self._pending_emit:
-            return
-        pending, self._pending_emit = self._pending_emit, []
+    def _drain_pending(self, wait: bool = True) -> Iterator[RecordBatch]:
+        """Take dispatched emission blocks, oldest first, and emit their
+        windows.  A deferred block is brought to the host by the ``-d2h``
+        worker beside ingest (``_fetch_block``); ``wait=False`` — the
+        trigger after every batch — takes the blocks that have landed and
+        leaves the rest in flight, so the pull thread stands still for a
+        block only where something needs it out first: the next close, a
+        marker, an idle hint, the end of the stream, the cold tier's due
+        windows.  A block without a worker (dispatched and drained in one
+        trigger) is fetched here.  A worker's failure is raised here, on
+        the pull thread, when its block is taken."""
         ph = self._phases
-        for j0, n, handle, is_finals in pending:
-            # the only place the pull thread waits for the device
-            with ph.phase("d2h_wait", window=j0, n=n):
-                block = self._backend.read_reset_block_finish(handle)
+        pending = self._pending_emit
+        while pending:
+            j0, n, handle, is_finals, fetch = pending[0]
+            landed = fetch is not None and fetch.done()
+            if fetch is not None and not (wait or landed):
+                return
+            del pending[0]
+            if landed:
+                self._metrics["emit_blocks_overlapped"] += 1
+                block = fetch.result()
+            else:
+                # the only place the pull thread waits for the device
+                with ph.phase("d2h_wait", window=j0, n=n):
+                    if fetch is None:
+                        block = self._backend.read_reset_block_finish(handle)
+                    else:
+                        self._metrics["emit_blocks_waited"] += 1
+                        block = fetch.result()
             with ph.phase("finalize", window=j0, n=n):
                 out = list(self._finalize_block(j0, n, block, is_finals))
+            # booked on this thread (read_slot adds to the same counter),
+            # right after the block's windows were (windows_emitted), not a
+            # finalize before them: whoever reads both reads them in step
+            self._backend.count_block_d2h(block)
             yield from out
+
+    def _fetch_block(self, j0: int, n: int, handle):
+        """Hand a dispatched block to the one ``-d2h`` worker, which waits
+        for its copy and assembles it on the host — ``jax.device_get`` waits
+        inside the runtime, without the interpreter lock — and touches
+        nothing but the handle.  Returns the future ``_drain_pending``
+        takes the block from."""
+        if self._emit_exec is None:
+            self._emit_exec = self._one_worker("d2h")
+        finish = self._backend.read_reset_block_finish
+        ph = self._phases
+
+        def fetch():
+            with ph.phase(D2H_FETCH, window=j0, n=n):
+                return finish(handle)
+
+        return self._emit_exec.submit(fetch)
 
     def _finalize_block(
         self, j0: int, n: int, block: dict, is_finals: bool
@@ -1280,8 +1341,11 @@ class StreamingWindowExec(ExecOperator):
         feed — whose stripe is necessarily older than the lag when its
         window closes — emits immediately.  ``force`` bypasses the
         deferral: ingest uses it to freeze closable windows before a
-        batch whose rows would otherwise leak late units into them."""
-        yield from self._drain_pending()
+        batch whose rows would otherwise leak late units into them.
+
+        Opens by taking the emission blocks whose fetch has landed — in
+        order, without waiting for the rest (``_drain_pending``)."""
+        yield from self._drain_pending(wait=False)
         if (
             self._tier is not None
             and self._tier.any_spilled
@@ -1296,7 +1360,11 @@ class StreamingWindowExec(ExecOperator):
                     self._watermark_ms, self.length_ms, self.slide_ms
                 )
             )
-            for j in self._tier.due_windows(wmf):
+            due = self._tier.due_windows(wmf)
+            if due:
+                # blocks in flight hold older windows still
+                yield from self._drain_pending()
+            for j in due:
                 b = self._finalize_rows(j, self._tier.emit_rows(j))
                 if b is not None:
                     yield b
@@ -1338,22 +1406,32 @@ class StreamingWindowExec(ExecOperator):
         emit) the ``n_close`` windows the watermark has closed."""
         if self._backend.accumulates_host:
             self._flush()
+        # every older block leaves first: at most one close's blocks are in
+        # flight (the device holds no more of them than one close makes),
+        # and windows leave in ascending order
+        yield from self._drain_pending()
+        # row-shipping backends emit in the same trigger; so does a zero
+        # emit lag (the CPU default): its streams may pause, and a block
+        # left in flight would hold a paused stream's output until the next
+        # batch arrives.  Everywhere else the block's bytes cross beside
+        # ingest and the trigger after the batch they land in takes them
+        deferred = self._backend.accumulates_host and self._emit_lag_s > 0
         while n_close > 0:
             # pow2 block sizes bound the compiled gather variants
             n = 1 << min(3, (n_close).bit_length() - 1)
             n = min(n, self._spec.window_slots)
             live = len(self._interner) if self._grouped else 1
-            with self._phases.phase("gather", window=self._first_open, n=n):
+            j0 = self._first_open
+            with self._phases.phase("gather", window=j0, n=n):
                 handle = None
                 if self._finals_specs is not None:
                     handle = self._backend.read_reset_block_finals_start(
-                        self._first_open % self._spec.window_slots, n,
-                        live_groups=live,
+                        j0 % self._spec.window_slots, n, live_groups=live,
                     )
                 is_finals = handle is not None
                 if not is_finals:
                     handle = self._backend.read_reset_block_start(
-                        self._first_open % self._spec.window_slots, n,
+                        j0 % self._spec.window_slots, n,
                         live_groups=live,
                         # only when the lean layout actually differs — else
                         # the lean=True program would be a duplicate
@@ -1363,18 +1441,13 @@ class StreamingWindowExec(ExecOperator):
                             and sa.lean_possible(self._spec)
                         ),
                     )
-            self._pending_emit.append((self._first_open, n, handle, is_finals))
+            self._pending_emit.append((
+                j0, n, handle, is_finals,
+                self._fetch_block(j0, n, handle) if deferred else None,
+            ))
             self._first_open += n
             n_close -= n
-        if not self._backend.accumulates_host or self._emit_lag_s == 0:
-            # row-shipping backends emit synchronously (prompt, in the
-            # same trigger); the async pipeline — drain on the NEXT
-            # trigger so the device→host transfer overlaps ingest — is
-            # reserved for the deferred partial_merge path where remote
-            # round-trips dominate.  With a zero emit lag (the CPU
-            # default) there is nothing to overlap, and deferring the
-            # drain would hold a paused live stream's output until the
-            # next rowful batch arrives.
+        if not deferred:
             yield from self._drain_pending()
 
     def _stripe_fits_more(self) -> bool:
@@ -1577,7 +1650,15 @@ class StreamingWindowExec(ExecOperator):
         try:
             yield from self._run_inner()
         finally:
-            self._shutdown_acc()
+            try:
+                self._shutdown_acc()
+            finally:
+                # the -d2h worker: a stream that ends takes its blocks
+                # first, so a fetch is still running only in one that is
+                # abandoned — its block is dropped with it
+                ex, self._emit_exec = self._emit_exec, None
+                if ex is not None:
+                    ex.shutdown(wait=True, cancel_futures=True)
 
     def _shutdown_acc(self) -> None:
         """Stop the host-pipeline worker (if any).  Joins the in-flight
@@ -1668,9 +1749,9 @@ class StreamingWindowExec(ExecOperator):
             # will follow, but an idle period delivers exactly ONE hint — a
             # deferred emission would never run and the final windows would
             # sit closed-but-unemitted, defeating the feature.  Likewise
-            # drain the async emission pipeline NOW: blocks dispatched by
-            # this trigger normally materialize on the next item, and there
-            # is no next item.
+            # wait for the emission blocks NOW: a block this trigger
+            # dispatched is normally taken by a later trigger, once it has
+            # landed, and there is no later item.
             yield from self._trigger(force=True)
             yield from self._drain_pending()
         yield WatermarkHint(

@@ -78,6 +78,9 @@ WINDOW_KEYS = {
     "phase_ms_trigger", "phase_ms_flush_send", "phase_ms_flush_pack",
     "phase_ms_flush", "phase_ms_gather",
     "phase_ms_d2h_wait", "phase_ms_finalize", "phase_ms_other",
+    # deferred emission blocks taken once landed / waited for, and what
+    # their bytes cost on the -d2h worker, beside the phases above
+    "emit_blocks_overlapped", "emit_blocks_waited", "d2h_fetch_ms",
     # what the statewatch phase ran: batches sketched, natively of those
     "sketch_update_batches", "sketch_native_batches",
     # batches whose timestamps window.project's native pass took
