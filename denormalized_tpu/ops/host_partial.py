@@ -216,6 +216,12 @@ class HostPartialStripe:
         self.bytes_touched = 0
         self.bytes_packed = 0
         self.cells_by_block = np.zeros(key_blocks, np.int64)
+        # what the device merges of those stripes fold: ring rows (one a
+        # window a packed unit feeds: ``length_units`` of them away from
+        # the ring's edges) and packed entries x the windows they are
+        # folded into — the scatter's work
+        self.window_folds = 0
+        self.fold_entries = 0
         # a cell without rows holds the fold-neutral record
         self._neutral = np.array(
             [0.0] + [0.0, 0.0, np.inf, -np.inf] * self.V
@@ -234,11 +240,13 @@ class HostPartialStripe:
     COUNTERS = (
         "cells_active", "cells_shipped", "bytes_touched", "bytes_packed",
     )
+    #: and the two of the device's fold (``merge_<name>``)
+    MERGE_COUNTERS = ("window_folds", "fold_entries")
 
     def carry_counters(self, old: "HostPartialStripe") -> None:
         """Take over the counts of the stripe this one replaces (capacity
         growth, restore), so they stay sums over the operator's life."""
-        for name in self.COUNTERS:
+        for name in self.COUNTERS + self.MERGE_COUNTERS:
             setattr(self, name, getattr(self, name) + getattr(old, name))
         if old.blocks == self.blocks:
             self.cells_by_block += old.cells_by_block
@@ -517,12 +525,27 @@ class HostPartialStripe:
                 )
             packed[:, 0, a_pad] = self.u_base + u
             packed[:, 0, a_pad + 1] = base_mod
+            self._count_folds(self.u_base + u, A, int(cuts[u, 0, -1]) - lo)
             self.cells_active += A
             self.cells_by_block += by_block
             self.cells_shipped += B * a_pad
             self.bytes_packed += packed.nbytes
             out.append((self._handed_out(packed), a_pad, lean, dense))
         return out
+
+    def _count_folds(self, u_rel: int, A: int, A_sub0: int) -> None:
+        """Book what ``merge_partials_body`` does with a packed unit of
+        ``A`` active cells, ``A_sub0`` of them in sub-bucket 0: the unit
+        feeds windows ``u_rel - k + 1 .. u_rel``, those inside the ring
+        take a fold each, and the oldest takes sub-bucket 0 alone where a
+        unit has two."""
+        k, W = self.spec.length_units, self.spec.window_slots
+        for i in range(k):
+            if 0 <= u_rel - i < W:
+                self.window_folds += 1
+                self.fold_entries += (
+                    A_sub0 if self.SUB == 2 and i == k - 1 else A
+                )
 
     def _handed_out(self, packed: np.ndarray) -> np.ndarray:
         """A matrix a key block as ``take_packed`` hands it out: the block

@@ -81,20 +81,37 @@ class WindowStateBackend:
     # contiguous blocks of group ids the ring is split into, one a device:
     # above 1 on the key-sharded layouts
     key_blocks: int = 1
+    # why a stripe flush happened (``flush_pending``'s ``reason``), one
+    # counter a reason, summing to the flushes that found rows (``merges``):
+    # ``span`` a unit outside the stripe's span met inside ``accumulate``,
+    # ``rows`` the operator's ``partial_merge_rows`` or the stripe's row cap,
+    # ``close`` a window close nothing deferred, ``lag`` a close taken when
+    # the deferral clock ran out, ``forced`` everything else (a hint, a
+    # marker, the end of the stream, growth, a lowered ring base, the cold
+    # tier)
+    FLUSH_REASONS = ("span", "rows", "close", "lag", "forced")
 
     def stripe_counters(self) -> dict:
         """What the host stripe's flushes cost, as ``metrics()`` names it:
         ``stripe_cells_active``, ``stripe_cells_shipped``,
-        ``stripe_bytes_touched``, ``stripe_bytes_packed`` — all 0 for a
-        row-shipping backend — and ``merge_cells_by_shard``, the active
-        cells again by the key block (device) they fell in: a list of
-        ``key_blocks`` sums that add up to ``stripe_cells_active``, and
-        the same numbers one by one as ``merge_cells_shard_<i>``."""
+        ``stripe_bytes_touched``, ``stripe_bytes_packed``; what the merges
+        fold on the device, ``merge_window_folds`` and
+        ``merge_fold_entries``; why each flush happened,
+        ``flush_reason_<reason>`` — all 0 for a row-shipping backend — and
+        ``merge_cells_by_shard``, the active cells again by the key block
+        (device) they fell in: a list of ``key_blocks`` sums that add up to
+        ``stripe_cells_active``, and the same numbers one by one as
+        ``merge_cells_shard_<i>``."""
         stripe = getattr(self, "_stripe", None)
         out = {
             f"stripe_{name}": getattr(stripe, name, 0)
             for name in HostPartialStripe.COUNTERS
         }
+        for name in HostPartialStripe.MERGE_COUNTERS:
+            out[f"merge_{name}"] = getattr(stripe, name, 0)
+        reasons = getattr(self, "flush_reasons", {})
+        for reason in self.FLUSH_REASONS:
+            out[f"flush_reason_{reason}"] = reasons.get(reason, 0)
         by_shard = (
             [0] * self.key_blocks if stripe is None
             else stripe.cells_by_block.tolist()
@@ -113,11 +130,12 @@ class WindowStateBackend:
         )
 
     def carry_stripe_counters(self, old: "WindowStateBackend") -> None:
-        """Take over the stripe counts and the merge count of the backend
+        """Take over the stripe counts and the flush counts of the backend
         this one replaces."""
         if self.accumulates_host and old.accumulates_host:
             self._stripe.carry_counters(old._stripe)
-            self.merges += old.merges
+            for reason, n in old.flush_reasons.items():
+                self.flush_reasons[reason] += n
 
     @property
     def strategy_name(self) -> str:
@@ -134,10 +152,12 @@ class WindowStateBackend:
     def update(self, values, colvalid, win_rel, rem, gid, row_valid, base_mod):
         raise NotImplementedError
 
-    def flush_pending(self) -> None:
+    def flush_pending(self, reason: str = "forced") -> None:
         """Merge any host-accumulated partials into device state.  No-op
         for row-shipping backends.  MUST be called before emission,
-        export, or capacity growth on host-accumulating backends."""
+        export, or capacity growth on host-accumulating backends.
+        ``reason``: one of ``FLUSH_REASONS``, counted where the flush found
+        rows to merge."""
 
     def read_reset_block(self, first_slot: int, n: int) -> dict[str, "np.ndarray"]:
         """Read and reset n consecutive ring slots; default = per-slot
@@ -405,7 +425,7 @@ class _HostPartialMixin:
             self.spec, stripe_group_capacity, self.key_blocks
         )
         self._pending_base_mod = 0
-        self.merges = 0
+        self.flush_reasons = dict.fromkeys(self.FLUSH_REASONS, 0)
         if _prewarm():
             # pre-compile every merge program with a no-op stripe: which
             # bucket a flush lands in depends on runtime pacing, and an
@@ -432,6 +452,12 @@ class _HostPartialMixin:
     @property
     def pending_rows(self) -> int:
         return self._stripe.rows
+
+    @property
+    def merges(self) -> int:
+        """Stripe flushes that found rows and merged them: the sum of the
+        counts by reason."""
+        return sum(self.flush_reasons.values())
 
     def update(self, *a, **k):
         raise RuntimeError(
@@ -489,7 +515,9 @@ class _HostPartialMixin:
                 u0 < stripe.u_base
                 or stripe.rows >= stripe.MAX_STRIPE_ROWS
             ):
-                self.flush_pending()
+                self.flush_pending(
+                    "span" if u0 < stripe.u_base else "rows"
+                )
             base = stripe.u_base if not stripe.is_empty() else u0
             chunk = (
                 remaining
@@ -501,7 +529,9 @@ class _HostPartialMixin:
                 not stripe.is_empty()
                 and stripe.rows + n_chunk > stripe.MAX_STRIPE_ROWS
             ):
-                self.flush_pending()
+                # no row of the batch lies in the stripe's span, or the
+                # rows that do would pass the row cap
+                self.flush_pending("span" if n_chunk == 0 else "rows")
                 continue
             if stripe.is_empty():
                 self._pending_base_mod = int(base_mod)
@@ -510,7 +540,7 @@ class _HostPartialMixin:
             )
             remaining &= ~chunk
 
-    def flush_pending(self) -> None:
+    def flush_pending(self, reason: str = "forced") -> None:
         if self._stripe.is_empty():
             return
         with self.phases.phase(
@@ -546,7 +576,7 @@ class _HostPartialMixin:
                 )
                 self.bytes_h2d += int(stacked.nbytes)
                 self._merge(stacked, stripe.block_cells, lean, True)
-            self.merges += 1
+            self.flush_reasons[reason] += 1
 
 
 class PartialMergeWindowState(_HostPartialMixin, SingleDeviceWindowState):

@@ -613,6 +613,8 @@ class StreamingWindowExec(ExecOperator):
             "batches_in": 0,
             "late_rows": 0,
             "windows_emitted": 0,
+            # rows of the emitted batches (before any downstream filter)
+            "emit_rows": 0,
             "device_steps": 0,
             "partial_merges": 0,
             "grow_events": 0,
@@ -662,8 +664,9 @@ class StreamingWindowExec(ExecOperator):
         m["bytes_d2h"] = self._backend.bytes_d2h
         # what the stripe's flushes cost: cells with rows, cells sent
         # (padding included), host bytes scanned and rewritten, bytes of
-        # the packed matrices (0 for a row-shipping backend), and the
-        # cells with rows by the key block (device) they fell in
+        # the packed matrices, the ring rows and entries their merges fold,
+        # why each flush happened (all 0 for a row-shipping backend), and
+        # the cells with rows by the key block (device) they fell in
         m.update(self._backend.stripe_counters())
         ms = self._phases.ms
         for key in WINDOW_PHASES:
@@ -1307,6 +1310,7 @@ class StreamingWindowExec(ExecOperator):
         m = int(np.count_nonzero(active))
         if m == 0:
             return None
+        self._metrics["emit_rows"] += m
         cols = self._emit_cols.take(m)
         n_keys = len(self.group_exprs)
         off = 0
@@ -1381,17 +1385,19 @@ class StreamingWindowExec(ExecOperator):
                 self._backend.accumulates_host
                 and self._backend.pending_rows >= self._merge_rows
             ):
-                self._flush()
+                self._flush("rows")
             return
-        if self._backend.accumulates_host:
-            age = time.perf_counter() - (self._stripe_wall or 0.0)
-            if (
-                not force
-                and age < self._emit_lag_s
-                and self._backend.pending_rows < self._merge_rows
-                and self._stripe_fits_more()
-            ):
-                return
+        # why the close's flush happens, for the backend's count
+        reason = "forced" if force else "close"
+        if self._backend.accumulates_host and not force:
+            if self._backend.pending_rows >= self._merge_rows:
+                reason = "rows"
+            elif self._emit_lag_s > 0:
+                age = time.perf_counter() - (self._stripe_wall or 0.0)
+                if age >= self._emit_lag_s:
+                    reason = "lag"
+                elif self._stripe_fits_more():
+                    return
         # the trigger acts.  Only now does it open its span: it runs after
         # every batch and every hint and mostly returns above, and the
         # deferral test is not worth two clock reads each time (it stays
@@ -1399,13 +1405,16 @@ class StreamingWindowExec(ExecOperator):
         with self._phases.phase(
             "trigger", batch=self._metrics["batches_in"], n=n_close
         ):
-            yield from self._close_windows(n_close)
+            yield from self._close_windows(n_close, reason)
 
-    def _close_windows(self, n_close: int) -> Iterator[RecordBatch]:
-        """Flush the stripe, then gather (and, where nothing is deferred,
-        emit) the ``n_close`` windows the watermark has closed."""
+    def _close_windows(
+        self, n_close: int, reason: str = "close"
+    ) -> Iterator[RecordBatch]:
+        """Flush the stripe (``reason``: what set the close off), then
+        gather (and, where nothing is deferred, emit) the ``n_close``
+        windows the watermark has closed."""
         if self._backend.accumulates_host:
-            self._flush()
+            self._flush(reason)
         # every older block leaves first: at most one close's blocks are in
         # flight (the device holds no more of them than one close makes),
         # and windows leave in ascending order
@@ -1456,10 +1465,10 @@ class StreamingWindowExec(ExecOperator):
         span_now = self._max_win_seen - self._first_open + 1
         return span_now + 1 < HostPartialStripe.U_MAX
 
-    def _flush(self) -> None:
+    def _flush(self, reason: str = "forced") -> None:
         # counters reconcile from backend.merges in metrics()
         self._join_acc()
-        self._backend.flush_pending()
+        self._backend.flush_pending(reason)
 
     def _emit_window(self, j: int) -> RecordBatch | None:
         """Read, reset and finalize ring slot ``j`` alone — the end-of-stream

@@ -68,7 +68,7 @@ SOURCE_KEYS = {
     "kafka_decode_ms", "queue_wait_ms",
 }
 WINDOW_KEYS = {
-    "rows_in", "batches_in", "late_rows", "windows_emitted",
+    "rows_in", "batches_in", "late_rows", "windows_emitted", "emit_rows",
     "device_steps", "partial_merges", "grow_events", "hint_path_ms",
     "bytes_h2d", "bytes_d2h", "strategy_resolved", "first_batch_at",
     # exclusive host milliseconds per phase of the operator, and the
@@ -88,6 +88,12 @@ WINDOW_KEYS = {
     # what the host stripe's flushes touched and sent
     "stripe_cells_active", "stripe_cells_shipped", "stripe_bytes_touched",
     "stripe_bytes_packed",
+    # what the merges of those stripes fold on the device: ring rows, and
+    # packed entries x windows fed; and why each flush happened, one
+    # counter a reason (they add up to device_steps on a stripe backend)
+    "merge_window_folds", "merge_fold_entries",
+    "flush_reason_span", "flush_reason_rows", "flush_reason_close",
+    "flush_reason_lag", "flush_reason_forced",
     # the active cells again by key block (one block without a mesh), as a
     # list and one by one; and the devices the ring is laid out over
     "merge_cells_by_shard", "merge_cells_shard_0", "mesh_devices",
