@@ -654,9 +654,18 @@ class ProjectExec(ExecOperator):
 
 
 class FilterExec(ExecOperator):
-    def __init__(self, input_op: ExecOperator, predicate: Expr):
+    """``absorbed``: the operator beneath applies the predicate where it
+    emits (``StreamingWindowExec.set_emission_predicate``) and this node
+    passes its batches on — it stays in the tree so that the plan's node
+    ids, the addresses of checkpointed state, are what they were."""
+
+    def __init__(
+        self, input_op: ExecOperator, predicate: Expr, *,
+        absorbed: bool = False,
+    ):
         self.input_op = input_op
         self.predicate = predicate
+        self.absorbed = absorbed
         self.schema = input_op.schema
         self.bind_obs("filter")
 
@@ -665,19 +674,23 @@ class FilterExec(ExecOperator):
         return [self.input_op]
 
     def _label(self):
-        return f"FilterExec({self.predicate!r})"
+        where = ", applied by the input at emission" if self.absorbed else ""
+        return f"FilterExec({self.predicate!r}{where})"
 
     def run(self) -> Iterator[StreamItem]:
         for item in self._doctor_input():
             if isinstance(item, RecordBatch):
                 t0 = time.perf_counter()
                 self._obs_rows_in.add(item.num_rows)
-                keep = np.asarray(self.predicate.eval(item), dtype=bool)
-                out = (
-                    item if keep.all()
-                    else item.filter(keep) if keep.any()
-                    else None
-                )
+                if self.absorbed:
+                    out = item
+                else:
+                    keep = np.asarray(self.predicate.eval(item), dtype=bool)
+                    out = (
+                        item if keep.all()
+                        else item.filter(keep) if keep.any()
+                        else None
+                    )
                 self._note_batch(t0, item.num_rows)
                 if out is not None:
                     yield out

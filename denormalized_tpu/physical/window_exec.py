@@ -97,6 +97,56 @@ class _EmissionColumns:
         self._sets = (self._sets + [bufs])[-self.KEEP:]
         return [b[:m] for b in bufs]
 
+
+class _EmissionPredicate:
+    """A post-aggregation filter the planner handed down (``Planner``'s rule
+    for ``lp.Filter``), applied where a window is emitted: to the final
+    values of a chunk's active positions, before a row's key string and
+    columns are built, so a row that fails is never materialized.
+
+    The predicate is the user's ``Expr``, evaluated by the ``Expr.eval`` a
+    ``FilterExec`` would call, over a batch of the columns it names and no
+    others — each cast to its output field's dtype first (a float32 final
+    to the float64 of its column), the window bounds as the int64 constants
+    the emitted batch carries: the same values, so the same rows."""
+
+    def __init__(self, predicate: Expr, schema: Schema, n_keys: int) -> None:
+        self.predicate = predicate
+        names = sorted(predicate.columns_referenced())
+        self._schema = schema.select(names)
+        n_finals = len(schema) - n_keys - 3
+        # per named column: the index of its aggregate among the finals and
+        # the column's dtype, or which of the window's three bounds it is
+        self._sources: list[tuple[int, np.dtype | None]] = []
+        for name in names:
+            at = schema.index_of(name) - n_keys
+            if at < 0:
+                raise PlanError(
+                    f"emission predicate names the group key {name!r}"
+                )
+            self._sources.append(
+                (at, schema.field(name).dtype.to_numpy()) if at < n_finals
+                else (at - n_finals, None)
+            )
+
+    def keep(
+        self, n: int, finals: list[np.ndarray], bounds: tuple[int, int, int]
+    ) -> np.ndarray:
+        """Boolean mask over the ``n`` positions of ``finals`` (one array
+        an aggregate): those the predicate would let out.  ``bounds`` is
+        the window's (start, end, canonical timestamp).  The positions may
+        include some that hold no row — whatever a fold-neutral cell
+        finalizes to (a 0 count, a NaN average) — whose answer the caller
+        discards; their arithmetic must not warn."""
+        cols = [
+            np.full(n, bounds[at], np.int64) if dt is None
+            else finals[at].astype(dt, copy=False)
+            for at, dt in self._sources
+        ]
+        with np.errstate(all="ignore"):
+            keep = self.predicate.eval(RecordBatch(self._schema, cols))
+        return np.asarray(keep, dtype=bool)
+
 #: keys of the window operator's phase clock, surfaced by ``metrics()`` as
 #: ``phase_ms_<key>``: exclusive host milliseconds, so they add up to the
 #: wall the operator's outer spans covered (docs/observability.md, Spans).
@@ -547,6 +597,9 @@ class StreamingWindowExec(ExecOperator):
             [f.dtype.to_numpy() if f.dtype.is_numeric else np.dtype(object)
              for f in fields]
         )
+        # the filter directly above, where the planner handed it down
+        # (set_emission_predicate)
+        self._emit_pred: _EmissionPredicate | None = None
 
         # streaming state
         self._ckpt: tuple | None = None
@@ -613,8 +666,11 @@ class StreamingWindowExec(ExecOperator):
             "batches_in": 0,
             "late_rows": 0,
             "windows_emitted": 0,
-            # rows of the emitted batches (before any downstream filter)
+            # rows the closed windows held (before the emission predicate
+            # or any downstream operator), and those of them the emission
+            # predicate kept out: delivered = emit_rows - emit_rows_filtered
             "emit_rows": 0,
+            "emit_rows_filtered": 0,
             "device_steps": 0,
             "partial_merges": 0,
             "grow_events": 0,
@@ -707,9 +763,22 @@ class StreamingWindowExec(ExecOperator):
         w = f"{self.window_type.value} {self.length_ms}ms"
         if self.slide_ms != self.length_ms:
             w += f"/{self.slide_ms}ms"
+        flt = (
+            f", emit_filter={self._emit_pred.predicate!r}"
+            if self._emit_pred is not None else ""
+        )
         return (
             f"StreamingWindowExec({w}, groups=[{', '.join(g.name for g in self.group_exprs)}], "
-            f"aggs=[{', '.join(a.name for a in self.aggr_exprs)}])"
+            f"aggs=[{', '.join(a.name for a in self.aggr_exprs)}]{flt})"
+        )
+
+    def set_emission_predicate(self, predicate: Expr) -> None:
+        """Apply ``predicate`` — a row-wise expression over aggregate
+        outputs and window bounds, no group key (the planner checks) —
+        where windows are emitted, on every emission path: the rows that
+        fail are counted (``emit_rows_filtered``) and never built."""
+        self._emit_pred = _EmissionPredicate(
+            predicate, self.schema, len(self.group_exprs)
         )
 
     # -- cold tier (state/tiering.py) -----------------------------------
@@ -1303,35 +1372,59 @@ class StreamingWindowExec(ExecOperator):
         """Window ``j``'s emission batch — the rows of the positions
         ``active`` marks, ascending — or None when it marks none.  Position
         ``p`` is group ``p``; ``finals_of(lo, hi, local)`` gives the output
-        columns of the positions ``lo + local``; the batch is filled
+        columns of the positions ``lo + local`` (``local`` an index array,
+        or ``slice(None)`` for all of ``lo:hi``); the batch is filled
         ``EMIT_CHUNK_GROUPS`` positions at a time.  ONE batch a window: a
         consumer may take a window's first row for the whole window
-        delivered (the benchmark's harness does)."""
+        delivered (the benchmark's harness does).
+
+        Under an emission predicate a chunk's positions are narrowed to the
+        rows that pass before anything of a row is built: the predicate is
+        evaluated over the chunk's final values at EVERY position — views
+        of the finals planes, contiguous passes — and the result masked by
+        ``active``, which costs less than gathering the active positions'
+        finals first and compressing them after.  A window whose every row
+        fails gives no batch and is a window emitted all the same."""
         m = int(np.count_nonzero(active))
         if m == 0:
             return None
         self._metrics["emit_rows"] += m
         cols = self._emit_cols.take(m)
         n_keys = len(self.group_exprs)
+        pred = self._emit_pred
+        start = j * self.slide_ms
+        bounds = (start, start + self.length_ms, start)
         off = 0
         for lo in range(0, len(active), EMIT_CHUNK_GROUPS):
             hi = min(lo + EMIT_CHUNK_GROUPS, len(active))
-            local = np.flatnonzero(active[lo:hi])
-            end = off + len(local)
-            if end == off:
+            mask = active[lo:hi]
+            if pred is not None:
+                whole = finals_of(lo, hi, slice(None))
+                mask = mask & pred.keep(hi - lo, whole, bounds)
+            local = np.flatnonzero(mask)
+            if len(local) == 0:
                 continue
+            finals = (
+                finals_of(lo, hi, local) if pred is None
+                else [a[local] for a in whole]
+            )
+            end = off + len(local)
             if self._grouped:
                 keys = self._interner.keys_of((local + lo).astype(np.int32))
                 for c, kv in zip(cols, keys):
                     c[off:end] = kv
-            for c, arr in zip(cols[n_keys:], finals_of(lo, hi, local)):
+            for c, arr in zip(cols[n_keys:], finals):
                 c[off:end] = arr
             off = end
-        # window bounds + canonical timestamp
-        cols[-3][:] = j * self.slide_ms
-        cols[-2][:] = j * self.slide_ms + self.length_ms
-        cols[-1][:] = j * self.slide_ms
         self._window_emitted(j)
+        if off < m:
+            self._metrics["emit_rows_filtered"] += m - off
+            if off == 0:
+                return None
+            cols = [c[:off] for c in cols]
+        # window bounds + canonical timestamp
+        for c, v in zip(cols[-3:], bounds):
+            c[:] = v
         return RecordBatch(self.schema, cols)
 
     def _trigger(self, force: bool = False) -> Iterator[RecordBatch]:

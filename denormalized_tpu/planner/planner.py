@@ -16,6 +16,18 @@ from __future__ import annotations
 
 from denormalized_tpu.common.errors import PlanError
 from denormalized_tpu.logical import plan as lp
+from denormalized_tpu.logical.expr import (
+    AliasExpr,
+    BinaryExpr,
+    CaseExpr,
+    CastExpr,
+    Column,
+    Expr,
+    Literal,
+    NotExpr,
+    ScalarFunctionExpr,
+)
+from denormalized_tpu.logical.optimizer import _expr_nodes
 from denormalized_tpu.physical.base import ExecOperator
 from denormalized_tpu.physical.simple_execs import (
     FilterExec,
@@ -24,6 +36,36 @@ from denormalized_tpu.physical.simple_execs import (
     SourceExec,
 )
 from denormalized_tpu.physical.window_exec import StreamingWindowExec
+
+#: expression nodes whose value at a row is a function of that row's columns
+#: alone (a scalar function: one that takes arguments).  Not among them: UDFs
+#: (may keep state or see the batch), window functions (the batch is their
+#: frame), ``is_null`` (reads a validity mask, the optimizer's caution in
+#: ``FilterPushdown``), field access, and whatever is added later
+_ROW_WISE = (
+    Column, Literal, BinaryExpr, NotExpr, AliasExpr, CastExpr, CaseExpr,
+    ScalarFunctionExpr,
+)
+
+
+def _applies_at_emission(predicate: Expr, window: StreamingWindowExec) -> bool:
+    """Can ``window`` apply the filter above it where it emits, before a
+    row's key string and columns are built?  Only a predicate that is
+    provably row-wise and names aggregate outputs and window bounds alone —
+    a group key would need the strings first.  All or nothing: a
+    conjunction is not split."""
+    names = predicate.columns_referenced()
+    keys = {f.name for f in window.schema.fields[:len(window.group_exprs)]}
+    return (
+        bool(names)
+        and not names & keys
+        and all(window.schema.has(n) for n in names)
+        and all(
+            isinstance(e, _ROW_WISE)
+            and (not isinstance(e, ScalarFunctionExpr) or e.args)
+            for e in _expr_nodes(predicate)
+        )
+    )
 
 
 class Planner:
@@ -99,7 +141,18 @@ class Planner:
             return ProjectExec(child, node.exprs, node.schema)
         if isinstance(node, lp.Filter):
             child = self.create_physical_plan(node.input)
-            return FilterExec(child, node.predicate)
+            # a filter straight over the ring's window operator goes down
+            # into it where it can (what the plan shows decides, no option);
+            # the node stays, as a pass-through: taking it out would
+            # renumber the window and the source beneath it, and a snapshot
+            # written under the old ids would not be found
+            # (state/checkpoint.py assign_node_ids)
+            absorbed = isinstance(
+                child, StreamingWindowExec
+            ) and _applies_at_emission(node.predicate, child)
+            if absorbed:
+                child.set_emission_predicate(node.predicate)
+            return FilterExec(child, node.predicate, absorbed=absorbed)
         if isinstance(node, lp.StreamingWindow):
             child = self.create_physical_plan(node.input)
             aggr_exprs = self._route_approx(node)

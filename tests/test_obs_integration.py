@@ -69,6 +69,8 @@ SOURCE_KEYS = {
 }
 WINDOW_KEYS = {
     "rows_in", "batches_in", "late_rows", "windows_emitted", "emit_rows",
+    # rows of emit_rows a filter handed down to the operator kept out
+    "emit_rows_filtered",
     "device_steps", "partial_merges", "grow_events", "hint_path_ms",
     "bytes_h2d", "bytes_d2h", "strategy_resolved", "first_batch_at",
     # exclusive host milliseconds per phase of the operator, and the
