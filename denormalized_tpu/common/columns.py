@@ -15,6 +15,9 @@ These classes carry the shredded form directly inside
   columns (``PrimitiveColumn`` leaves at the parser's natural width,
   ``StringColumn`` string leaves, nested ``NestedColumn``s) plus
   Arrow-style list offsets.
+- :class:`DictionaryColumn` — ``int32`` codes into an object ndarray of
+  values: a window's group keys as emitted, the codes being the group ids
+  and the values the interner's key mirror.
 
 Python rows materialize ONLY at user-facing boundaries (sinks, UDFs,
 ``to_pydict``, pyarrow interop) via the cached :meth:`Column.as_object`
@@ -275,6 +278,68 @@ class StringColumn(Column):
 
     def __repr__(self) -> str:
         return f"StringColumn({len(self)} rows, {self.data.nbytes}B)"
+
+
+class DictionaryColumn(Column):
+    """Dictionary-encoded column: row ``i`` is ``values[indices[i]]``.
+
+    ``indices`` is an int32 array (the window operator's: a view of a pooled
+    emission buffer, holding group ids); ``values`` is an object ndarray,
+    the dictionary (the window operator's: a prefix of its interner's key
+    mirror, whose entries are never written again once filled, so the
+    column reads the same values whenever and on whatever thread it is
+    read).  A take, slice or mask stays on the indices; a row's Python
+    value is reached in :meth:`as_object` and ``_get_one`` alone, and a
+    null is a ``None`` value, which :attr:`validity` reads from there."""
+
+    __slots__ = ("indices", "values", "_obj", "_valid")
+
+    def __init__(self, indices: np.ndarray, values: np.ndarray) -> None:
+        self.indices = indices
+        self.values = values
+        self._obj: np.ndarray | None = None
+        self._valid: np.ndarray | None | bool = False  # False: not read yet
+
+    @property
+    def validity(self) -> np.ndarray | None:
+        """False where the row's value is None; None where no row's is —
+        the other columns' convention.  Read from the materialized rows,
+        once: only a consumer that asks for nulls pays for it."""
+        if self._valid is False:
+            valid = np.not_equal(self.as_object(), None)
+            self._valid = None if valid.all() else valid
+        return self._valid
+
+    def __len__(self) -> int:
+        return len(self.indices)
+
+    @property
+    def nbytes(self) -> int:
+        return self.indices.nbytes
+
+    def _get_one(self, i: int):
+        return self.values[self.indices[i]]
+
+    def as_object(self) -> np.ndarray:
+        if self._obj is None:
+            self._obj = self.values[self.indices]
+        return self._obj
+
+    def take(self, indices: np.ndarray) -> "DictionaryColumn":
+        return DictionaryColumn(
+            self.indices[np.asarray(indices, dtype=np.int64)], self.values
+        )
+
+    def slice(self, start: int, length: int) -> "DictionaryColumn":
+        return DictionaryColumn(
+            self.indices[start: start + length], self.values
+        )
+
+    def __repr__(self) -> str:
+        return (
+            f"DictionaryColumn({len(self)} rows, "
+            f"{len(self.values)} values)"
+        )
 
 
 #: assembly type codes, matching pyassemble.cpp's node types
@@ -731,6 +796,10 @@ def column_spec_and_buffers(col) -> tuple[dict, list[np.ndarray]]:
     bufs: list[np.ndarray] = []
 
     def walk(node) -> dict:
+        if isinstance(node, DictionaryColumn):
+            # travels as the StringColumn of its rows' values: no format of
+            # its own on the wire or in a snapshot
+            node = StringColumn.from_objects(node.as_object())
         if isinstance(node, StringColumn):
             spec = {"k": "str", "v": node.validity is not None}
             bufs.append(node.offsets)

@@ -476,23 +476,36 @@ class ColumnInterner:
         self._lib.intern_stats(self._h, out)
         return (int(out[0]), int(out[1]), int(out[2]))
 
+    def key_mirror(self) -> np.ndarray | None:
+        """The filled prefix of the object-array mirror of the native
+        table's keys, synced with every key interned so far: id ``i``'s key
+        is ``mirror[i]``.  None where no key went through the native table.
+
+        The mirror grows by doubling and takes the new keys alone, so a
+        call costs the keys that appeared since the last one.  An entry
+        below the filled length is never written again: a doubling copies
+        into a new array and leaves the old one as it was, so a prefix
+        handed out reads the same keys for as long as anyone holds it."""
+        if not self._native_active:
+            return None
+        self._sync_native_values()
+        n = len(self._values)
+        arr, have = self._values_arr, self._values_arr_n
+        if arr is None or have > n:
+            arr, have = np.empty(n, dtype=object), 0
+        elif len(arr) < n:
+            arr = np.empty(max(n, 2 * len(arr)), dtype=object)
+            arr[:have] = self._values_arr[:have]
+        arr[have:n] = self._values[have:n]
+        self._values_arr, self._values_arr_n = arr, n
+        return arr[:n]
+
     def value_of(self, ids: np.ndarray) -> np.ndarray:
-        if self._native_active:
-            self._sync_native_values()
+        mirror = self.key_mirror()
+        if mirror is not None:
             # fancy-index the object-array mirror: C-speed gather even for
-            # 100k-group emissions.  The mirror grows by doubling and takes
-            # the new keys alone: an emission costs the keys that appeared
-            # since the last one, not every key ever seen
-            n = len(self._values)
-            arr, have = self._values_arr, self._values_arr_n
-            if arr is None or have > n:
-                arr, have = np.empty(n, dtype=object), 0
-            elif len(arr) < n:
-                arr = np.empty(max(n, 2 * len(arr)), dtype=object)
-                arr[:have] = self._values_arr[:have]
-            arr[have:n] = self._values[have:n]
-            self._values_arr, self._values_arr_n = arr, n
-            return arr[np.asarray(ids)]
+            # 100k-group emissions
+            return mirror[np.asarray(ids)]
         nb = self._num_by_id
         if nb is not None and len(nb) > len(self._values):
             # numeric fast path with an un-flushed suffix: gather from
@@ -663,6 +676,16 @@ class GroupInterner:
         Spans: a row counts once per string key column)."""
         per_col = [it.native_stats() for it in self._col_interners]
         return dict(zip(INTERN_STATS, map(sum, zip(*per_col))))
+
+    def key_dictionary(self) -> np.ndarray | None:
+        """The values a group id indexes, where the key is one column in
+        the native table: group ``g``'s key is ``dictionary[g]`` for every
+        group interned so far, and stays so (``ColumnInterner.key_mirror``).
+        None for several key columns, or keys the native table does not
+        hold (numbers, no compiler)."""
+        if self.num_columns != 1:
+            return None
+        return self._col_interners[0].key_mirror()
 
     def keys_of(self, gids: np.ndarray) -> list[np.ndarray]:
         """Reconstruct each key column's values for the given group ids."""

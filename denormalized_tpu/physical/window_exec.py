@@ -41,6 +41,7 @@ from denormalized_tpu.common.constants import (
     WINDOW_END_COLUMN,
     WINDOW_START_COLUMN,
 )
+from denormalized_tpu.common.columns import DictionaryColumn
 from denormalized_tpu.common.errors import PlanError
 from denormalized_tpu.common.record_batch import RecordBatch
 from denormalized_tpu.common.schema import DataType, Field, Schema
@@ -94,15 +95,17 @@ class _EmissionColumns:
         self._dtypes = dtypes
         self._sets: list[list[np.ndarray]] = []
 
-    def take(self, m: int) -> list[np.ndarray]:
-        """One column of ``m`` rows per dtype, contents undefined."""
+    def take(self, m: int, first: np.dtype | None = None) -> list[np.ndarray]:
+        """One column of ``m`` rows per dtype, contents undefined; the first
+        of dtype ``first`` where given (a key emitted as dictionary codes)."""
+        dtypes = self._dtypes if first is None else [first, *self._dtypes[1:]]
         for bufs in self._sets:
             # 2 = the set's reference + getrefcount's own argument
-            if len(bufs[0]) >= m and all(
+            if bufs[0].dtype == dtypes[0] and len(bufs[0]) >= m and all(
                 sys.getrefcount(bufs[i]) == 2 for i in range(len(bufs))
             ):
                 return [b[:m] for b in bufs]
-        bufs = [np.empty(m + m // 8, dt) for dt in self._dtypes]
+        bufs = [np.empty(m + m // 8, dt) for dt in dtypes]
         self._sets = (self._sets + [bufs])[-self.KEEP:]
         return [b[:m] for b in bufs]
 
@@ -110,7 +113,7 @@ class _EmissionColumns:
 class _EmissionPredicate:
     """A post-aggregation filter the planner handed down (``Planner``'s rule
     for ``lp.Filter``), applied where a window is emitted: to the final
-    values of a chunk's active positions, before a row's key string and
+    values of a chunk's active positions, before a row's key and
     columns are built, so a row that fails is never materialized.
 
     The predicate is the user's ``Expr``, evaluated by the ``Expr.eval`` a
@@ -696,6 +699,9 @@ class StreamingWindowExec(ExecOperator):
             # predicate kept out: delivered = emit_rows - emit_rows_filtered
             "emit_rows": 0,
             "emit_rows_filtered": 0,
+            # delivered rows whose key went out as dictionary codes (all of
+            # them where the key is one column in the native interner)
+            "emit_keys_dict_rows": 0,
             "device_steps": 0,
             "partial_merges": 0,
             "grow_events": 0,
@@ -1435,18 +1441,33 @@ class StreamingWindowExec(ExecOperator):
         finals first and compressing them after.  A window whose every row
         fails gives no batch and is a window emitted all the same.
 
+        Where the key is one column in the interner's native table, the
+        key column goes out as a ``DictionaryColumn``: the kept positions'
+        group ids over the interner's key mirror, no Python string touched
+        (``emit_keys_dict_rows`` counts its rows); a consumer that reads
+        the keys gathers them then.  Other keys are gathered here, one
+        object column a key.
+
         Runs inside ``window.finalize``, whose three children split it:
         ``window.finalize_predicate``, ``window.finalize_columns`` (the
         kept positions' finals and their copy) and ``window.finalize_keys``
-        (their key strings and their copy)."""
+        (the key mirror's sync with the keys interned since the last
+        emission, and the kept positions' group ids, or their key values,
+        and their copy)."""
         m = int(np.count_nonzero(active))
         if m == 0:
             return None
         self._metrics["emit_rows"] += m
-        cols = self._emit_cols.take(m)
+        ph = self._phases
+        dictionary = None
+        if self._grouped:
+            with ph.phase("finalize_keys", window=j):
+                dictionary = self._interner.key_dictionary()
+        cols = self._emit_cols.take(
+            m, None if dictionary is None else np.dtype(np.int32)
+        )
         n_keys = len(self.group_exprs)
         pred = self._emit_pred
-        ph = self._phases
         start = j * self.slide_ms
         bounds = (start, start + self.length_ms, start)
         off = 0
@@ -1470,11 +1491,15 @@ class StreamingWindowExec(ExecOperator):
                     c[off:end] = arr
             if self._grouped:
                 with ph.phase("finalize_keys", window=j):
-                    keys = self._interner.keys_of(
-                        (local + lo).astype(np.int32)
-                    )
-                    for c, kv in zip(cols, keys):
-                        c[off:end] = kv
+                    if dictionary is not None:
+                        np.add(local, lo, out=cols[0][off:end],
+                               casting="unsafe")
+                    else:
+                        keys = self._interner.keys_of(
+                            (local + lo).astype(np.int32)
+                        )
+                        for c, kv in zip(cols, keys):
+                            c[off:end] = kv
             off = end
         self._window_emitted(j)
         if off < m:
@@ -1482,6 +1507,9 @@ class StreamingWindowExec(ExecOperator):
             if off == 0:
                 return None
             cols = [c[:off] for c in cols]
+        if dictionary is not None:
+            cols[0] = DictionaryColumn(cols[0], dictionary)
+            self._metrics["emit_keys_dict_rows"] += off
         # window bounds + canonical timestamp
         for c, v in zip(cols[-3:], bounds):
             c[:] = v
