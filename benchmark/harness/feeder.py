@@ -23,11 +23,15 @@ lateness seen since the last mark), ``{"cmd": "stop"}`` (production ends; reply:
 
 Modes.  Chunks are encoded ahead by ``encoders`` child processes of the
 feeder (the same module, started with ``"encode": [i, n]``), up to
-``ahead_chunks`` beyond the last one appended.  ``drain``: a chunk is
-appended whenever the topic is less than ``lead_events`` ahead of the
-offsets the consumer has asked for, so offered load is above capacity and
-memory is bounded.  ``paced``: a chunk is appended at its due time, whatever
-the consumer does (open loop); how late each append ran is reported.
+``ahead_chunks`` beyond the last one appended.  What a chunk holds is the
+deployment's: a child loads the module at ``module`` by its path and calls
+its ``encode_chunk(feed, c)``; the feeder reads ``partitions``,
+``chunk_ms`` and ``events_per_chunk`` out of ``feed`` and nothing else.
+``drain``: a chunk is appended whenever the topic is less than
+``lead_events`` ahead of the offsets the consumer has asked for, so offered
+load is above capacity and memory is bounded.  ``paced``: a chunk is
+appended at its due time, whatever the consumer does (open loop); how late
+each append ran is reported.
 """
 
 from __future__ import annotations
@@ -41,7 +45,7 @@ import sys
 import threading
 import time
 
-from benchmark.harness import events, wire
+from benchmark.harness import manifest
 
 
 class Broker:
@@ -268,18 +272,6 @@ def _recv_all(conn: socket.socket, n: int) -> bytes | None:
     return bytes(buf)
 
 
-def encode_chunk(feed: events.Feed, encoder: wire.Encoder, c: int) -> list[bytes]:
-    """Chunk ``c`` as one blob a partition."""
-    ts, kid, micro = events.chunk_arrays(feed, c)
-    per = feed.events_per_chunk // feed.partitions
-    first_ts = events.T0 + c * feed.chunk_ms
-    return [
-        encoder.encode(c * per, first_ts, ts[p::feed.partitions],
-                       kid[p::feed.partitions], micro[p::feed.partitions])
-        for p in range(feed.partitions)
-    ]
-
-
 def lateness_ms(appended_at: float, origin: float, c: int, chunk_ms: int) -> float:
     """How long after its due time chunk ``c`` was appended."""
     return (appended_at - (origin + (c + 1) * chunk_ms / 1000.0)) * 1000.0
@@ -296,16 +288,17 @@ class Producer:
     says so."""
 
     def __init__(self, broker: Broker, params: dict):
-        feed = events.Feed(**params["feed"])
+        feed = params["feed"]
         mode = params["mode"]
         if mode not in ("drain", "paced"):
             raise ValueError(f"unknown traffic mode {mode!r}")
-        self.broker, self.feed, self.mode = broker, feed, mode
+        self.broker, self.mode = broker, mode
+        self.partitions, self.chunk_ms = feed["partitions"], feed["chunk_ms"]
         self.lead_events = int(params.get("lead_events", 0))
         self.ahead_chunks = max(1, int(params["ahead_chunks"]))
         self.encoders = max(1, int(params["encoders"]))
         self._params = params
-        self._per = feed.events_per_chunk // feed.partitions
+        self._per = feed["events_per_chunk"] // self.partitions
         self.chunks = 0  # appended so far
         self.late_ms: list[float] = []
         self._stop = threading.Event()
@@ -356,7 +349,7 @@ class Producer:
 
     def _read(self, child: subprocess.Popen, index: int) -> None:
         c = index
-        head = struct.Struct(">q%dI" % self.feed.partitions)
+        head = struct.Struct(">q%dI" % self.partitions)
         while not self._stop.is_set():
             with self._ready_cond:
                 if c - self.chunks >= self.ahead_chunks:
@@ -376,7 +369,7 @@ class Producer:
 
     def _append(self) -> None:
         broker = self.broker
-        dt = self.feed.chunk_ms / 1000.0
+        dt = self.chunk_ms / 1000.0
         while not self._stop.is_set():
             c = self.chunks
             if self.mode == "paced":
@@ -397,7 +390,7 @@ class Producer:
             broker.append(blobs, self._per)
             if self.mode == "paced":
                 self.late_ms.append(lateness_ms(
-                    time.monotonic(), self.origin, c, self.feed.chunk_ms
+                    time.monotonic(), self.origin, c, self.chunk_ms
                 ))
             with self._ready_cond:
                 self.chunks = c + 1
@@ -408,15 +401,16 @@ def encode_forever(params: dict) -> int:
     """An encoder child: chunks ``i, i+n, ...`` framed onto standard output
     (chunk index and one length a partition, then the blobs) until the pipe
     closes or the parent kills it."""
-    feed = events.Feed(**params["feed"])
+    feed = params["feed"]
+    encode_chunk = manifest.load_module(
+        params["module"], "bench_deployment_").encode_chunk
     i, n = params["encode"]
-    encoder = wire.Encoder(feed.key_prefix, feed.key_width, feed.records_per_batch)
-    head = struct.Struct(">q%dI" % feed.partitions)
+    head = struct.Struct(">q%dI" % feed["partitions"])
     out = sys.stdout.buffer
     c = i
     try:
         while True:
-            blobs = encode_chunk(feed, encoder, c)
+            blobs = encode_chunk(feed, c)
             out.write(head.pack(c, *(len(b) for b in blobs)))
             for b in blobs:
                 out.write(b)

@@ -2,11 +2,14 @@
 
 A cell ``<name>`` is ``workloads/<name>.json`` (its traffic: mode, sizes,
 rate, what is checked), its configuration ``configs/<config>.json`` (the
-deployment: query, keys, partitions, guarantees) and, for every per-layer
-metric that lists the cell, a reader ``metrics/<metric>.py`` with a
-``read(obs)`` function.  A later PR adds a cell, a configuration or a
-per-layer metric by adding files and manifest entries and edits nothing
-here; the tests prove it with files that exist only under their fixtures.
+deployment: query, keys, partitions, guarantees), the deployment module
+the configuration names under ``module`` (its records, its stream and its
+reference: ``deployments/emit_measurements.py`` says what one holds) and,
+for every per-layer metric that lists the cell, a reader
+``metrics/<metric>.py`` with a ``read(obs)`` function.  A later PR adds a
+cell, a configuration, a deployment or a per-layer metric by adding files
+and manifest entries and edits nothing here; the tests prove it with files
+that exist only under their fixtures.
 """
 
 from __future__ import annotations
@@ -15,6 +18,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import types
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -29,6 +33,8 @@ class Cell:
     end_to_end: dict[str, str]  # metric name -> unit
     per_layer: dict[str, str]
     readers: dict  # per-layer metric name -> read(obs)
+    #: the configuration's deployment module (records, stream, reference)
+    deployment: types.ModuleType
     #: the per-layer metrics whose reader needs the profiler's trace and
     #: nothing else: a traced line always carries them (the others where
     #: their reader found a number, else the line names them as not read)
@@ -54,15 +60,25 @@ def _load_json(path: str) -> dict:
         return json.load(f)
 
 
-def load_reader(path: str):
-    """The ``read`` function of a reader module, loaded by path (metric
-    names hold dots, so they are no module names)."""
+def load_module(path: str, prefix: str) -> types.ModuleType:
+    """A module of the benchmark's, loaded by path (metric names hold dots,
+    so they are no module names; a tree of fixtures is no package)."""
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + os.path.basename(path)[:-3].replace(".", "_"), path
+        prefix + os.path.basename(path)[:-3].replace(".", "_"), path
     )
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def load_reader(path: str):
+    """The ``read`` function of a reader module, loaded by path."""
+    return load_module(path, "bench_metric_").read
+
+
+def _own_or_real(own: str, real: str) -> str:
+    """A tree of fixtures may bring files of its own beside the real ones."""
+    return own if os.path.exists(own) else real
 
 
 def load_cell(name: str, manifest_path: str | None = None,
@@ -92,18 +108,23 @@ def load_cell(name: str, manifest_path: str | None = None,
     per_layer = {
         m["name"]: m["unit"] for m in manifest["per_layer"] if _lists(m, name)
     }
-    readers = {}
-    for m in per_layer:
-        # a tree of fixtures may bring readers of its own beside the real ones
-        own = os.path.join(bench_dir, "metrics", m + ".py")
-        readers[m] = load_reader(
-            own if os.path.exists(own)
-            else os.path.join(BENCH_DIR, "metrics", m + ".py")
-        )
+    readers = {
+        m: load_reader(_own_or_real(
+            os.path.join(bench_dir, "metrics", m + ".py"),
+            os.path.join(BENCH_DIR, "metrics", m + ".py"),
+        ))
+        for m in per_layer
+    }
+    # the module's path is relative to the root of the manifest's tree
+    deployment = load_module(_own_or_real(
+        os.path.join(os.path.dirname(os.path.abspath(manifest_path)),
+                     config["module"]),
+        os.path.join(ROOT, config["module"]),
+    ), "bench_deployment_")
     from_trace = frozenset(
         m["name"] for m in manifest["per_layer"]
         if m["name"] in per_layer and m["source"] == "device_trace"
         and readers[m["name"]](TRACE_ALONE) is not None
     )
     return Cell(name, int(entry["chips"]), config, traffic, end_to_end,
-                per_layer, readers, from_trace)
+                per_layer, readers, deployment, from_trace)

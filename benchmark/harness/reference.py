@@ -8,7 +8,9 @@ of the engine and takes nothing the engine made.
 
 ``compare`` holds the rows the engine delivered for those windows against
 it and returns numbers, each with a limit of its own (``LIMITS``); PERF.md
-gives the readings every limit was set from.
+gives the readings every limit was set from.  ``verdict`` holds every
+deployment's numbers to ``LIMITS``, and a number ``LIMITS`` does not name
+to the limit its deployment module gives it.
 
 ``bf16_fold`` is the control: the same fold with readings, partial sums and
 the running sum held in bfloat16, the precision below the float32 the
@@ -34,6 +36,9 @@ LIMITS = {
     "late_rows": 0,
     "decode_fallback_rows": 0,
 }
+# what a comparison counts beside its numbers: no limit, never compared
+# (``bad_windows`` goes to the line's ``failed``)
+TALLIES = ("rows_compared", "blocks_compared", "bad_windows")
 # |avg - threshold| under this share of the threshold: f32 state may fall
 # on either side of the filter, so such a row may be there or not
 FILTER_BAND = 1e-4
@@ -219,11 +224,22 @@ def merge(numbers: list[dict]) -> dict:
     return out
 
 
-def verdict(numbers: dict) -> tuple[bool, dict]:
-    """``correct``, and every number compared beside its limit."""
+class Unlimited(ValueError):
+    """A number that no limit holds: the run has no verdict."""
+
+
+def verdict(numbers: dict, own_limits: dict | None = None) -> tuple[bool, dict]:
+    """``correct``, and every number compared beside its limit: ``LIMITS``
+    for the names it has, ``own_limits`` (the deployment module's) for the
+    others.  A number that neither names, and that is no tally, raises
+    ``Unlimited``: it would otherwise go unread."""
+    limits = {**(own_limits or {}), **LIMITS}
+    unlimited = sorted(set(numbers) - set(limits) - set(TALLIES))
+    if unlimited:
+        raise Unlimited(f"no limit holds the numbers {unlimited}")
     compared = {
-        k: {"value": numbers[k], "limit": LIMITS[k]}
-        for k in LIMITS if k in numbers
+        k: {"value": numbers[k], "limit": limits[k]}
+        for k in [*LIMITS, *limits] if k in numbers
     }
     ok = all(c["value"] <= c["limit"] for c in compared.values())
     return ok and numbers.get("rows_compared", 0) > 0, compared

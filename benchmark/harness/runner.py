@@ -1,12 +1,14 @@
 """One run of one cell: feeder, engine, warm-up, measured window, and the
 comparison with the reference.
 
-The entry the window drives is the user's: ``Context(EngineConfig(...))``
-→ ``ctx.from_topic(...)`` → ``.window(...)`` [→ ``.filter(...)``] →
-``ds.stream()``, consumed on a thread of its own.  Everything the harness
-measures it takes itself (host clock, the feeder's offsets, the profiler's
-trace) or reads by name from the engine's counters; the engine is given
-nothing but the broker's address.
+The entry the window drives is the user's, as the cell's deployment module
+builds it (``Context(EngineConfig(...))`` → ``ctx.from_topic(...)`` → ...),
+and ``ds.stream()``, consumed on a thread of its own.  Everything the
+harness measures it takes itself (host clock, the feeder's offsets, the
+profiler's trace) or reads by name from the engine's counters; the engine
+is given nothing but the broker's address.  What differs from one
+deployment to the next (records, stream, reference) is the module's; the
+rest is here.
 """
 
 from __future__ import annotations
@@ -34,7 +36,6 @@ from benchmark.harness import (
     trace_reduce,
 )
 
-SAMPLE_JSON = '{"occurred_at_ms": 100, "sensor_name": "foo", "reading": 0.0}'
 OUT_DIR = os.path.join(manifest.ROOT, ".bench_out")
 
 
@@ -88,21 +89,20 @@ class Feeder:
 
 def feeder_params(cell: manifest.Cell, seed: int) -> dict:
     cfg, tr = cell.config, cell.traffic
+    base = {
+        "seed": seed,
+        "partitions": cfg["partitions"],
+        "chunk_ms": tr["chunk_ms"],
+        "events_per_chunk": tr["events_per_second"] * tr["chunk_ms"] // 1000,
+    }
     return {
         "topic": cfg["topic"],
         "mode": tr["mode"],
         "lead_events": tr.get("lead_events", 0),
         "ahead_chunks": tr["ahead_chunks"],
         "encoders": tr["encoders"],
-        "feed": {
-            "seed": seed,
-            "n_keys": cfg["keys"]["count"],
-            "key_prefix": cfg["keys"]["prefix"],
-            "partitions": cfg["partitions"],
-            "chunk_ms": tr["chunk_ms"],
-            "events_per_chunk": tr["events_per_second"] * tr["chunk_ms"] // 1000,
-            "records_per_batch": cfg["records_per_batch"],
-        },
+        "module": cell.deployment.__file__,
+        "feed": cell.deployment.encoder_params(cfg, tr, base),
     }
 
 
@@ -110,28 +110,7 @@ def feeder_params(cell: manifest.Cell, seed: int) -> dict:
 
 
 def build_stream(cell: manifest.Cell, bootstrap: str):
-    from denormalized_tpu import Context, col
-    from denormalized_tpu.api import functions as F
-    from denormalized_tpu.api.context import EngineConfig
-
-    cfg = cell.config
-    q = cfg["query"]
-    ctx = Context(EngineConfig(**cfg.get("engine", {})))
-    aggs = [
-        getattr(F, kind)(col("reading")).alias(name)
-        for name, kind in q["aggregates"]
-    ]
-    ds = ctx.from_topic(
-        cfg["topic"], sample_json=SAMPLE_JSON, bootstrap_servers=bootstrap,
-        timestamp_column="occurred_at_ms",
-    )
-    if q["slide_ms"] == q["length_ms"]:
-        ds = ds.window(q["keys"], aggs, q["length_ms"])
-    else:
-        ds = ds.window(q["keys"], aggs, q["length_ms"], q["slide_ms"])
-    if q.get("filter"):
-        ds = ds.filter(col(q["filter"]["column"]) > q["filter"]["gt"])
-    return ctx, ds
+    return cell.deployment.build_stream(cell.config, bootstrap)
 
 
 def sampled_blocks(seed: int, every: int, n: int = 100_000) -> np.ndarray:
@@ -156,11 +135,11 @@ class Consumer(threading.Thread):
     def __init__(self, ds, cell: manifest.Cell, seed: int):
         super().__init__(daemon=True, name="bench-consumer")
         self._ds = ds
-        q, chk = cell.config["query"], cell.traffic["check"]
-        self._length = q["length_ms"]
+        chk = cell.traffic["check"]
+        self._length, _slide = cell.deployment.window_ms(cell.config)
         self._block_ms = chk["block_ms"]
         self.sampled = sampled_blocks(seed, chk["every"])
-        self.columns = [q["keys"][0]] + [a[0] for a in q["aggregates"]]
+        self.columns = cell.deployment.kept_columns(cell.config)
         self.arrival: dict[int, float] = {}  # window end -> first arrival
         self.kept: dict[int, list] = {}  # block -> [(window starts, columns)]
         self.rows = 0
@@ -329,20 +308,18 @@ def stop_trace() -> None:
 
 def check_blocks(cell: manifest.Cell, seed: int, consumer: Consumer,
                  control: bool) -> tuple[dict, dict | None, int]:
-    """Fold the reference over the sampled blocks the run covered and hold
-    the kept rows against it.  Returns the numbers, the control's numbers
-    (the bf16 fold put in the engine's place) when asked for, and the count
-    of windows with a row missing or wrong."""
+    """Hold the kept rows of the sampled blocks the run covered against the
+    deployment's reference.  Returns the numbers, the control's numbers
+    (the deployment's control put in the engine's place) when asked for,
+    and the count of windows with a row missing or wrong."""
     cfg, chk = cell.config, cell.traffic["check"]
-    q = cfg["query"]
-    feed = events.Feed(**feeder_params(cell, seed)["feed"])
-    aggs = [tuple(a) for a in q["aggregates"]]
-    flt = (q["filter"]["column"], q["filter"]["gt"]) if q.get("filter") else None
+    params = feeder_params(cell, seed)["feed"]
+    length_ms, _slide = cell.deployment.window_ms(cfg)
     block_ms = chk["block_ms"]
     if not consumer.arrival:
         return {"rows_compared": 0}, None, 0
     newest = max(consumer.arrival)  # end of the newest window delivered
-    oldest = min(consumer.arrival) - q["length_ms"]
+    oldest = min(consumer.arrival) - length_ms
     covered = [
         b for b in np.flatnonzero(consumer.sampled).tolist()
         if events.T0 + b * block_ms >= oldest
@@ -350,24 +327,19 @@ def check_blocks(cell: manifest.Cell, seed: int, consumer: Consumer,
     ][-chk["max_blocks"]:]
     numbers, controls, bad_windows = [], [], 0
     for b in covered:
-        ref = reference.Reference(
-            feed, q["length_ms"], q["slide_ms"],
-            events.T0 + b * block_ms, events.T0 + (b + 1) * block_ms,
-        )
         parts = consumer.kept.get(b, [])
         ws = np.concatenate([p[0] for p in parts]) if parts else np.zeros(0, np.int64)
         got = {
             c: np.concatenate([p[1][c] for p in parts]) if parts else np.zeros(0)
             for c in consumer.columns
         }
-        got["cells"] = ref.cells_of(ws, got[q["keys"][0]].tolist())
-        n = reference.compare(ref, got, aggs, flt)
+        n, c = cell.deployment.check(
+            cfg, params, events.T0 + b * block_ms,
+            events.T0 + (b + 1) * block_ms, (ws, got), control,
+        )
         bad_windows += n.pop("bad_windows")
         numbers.append(n)
-        if control:
-            c = reference.compare(
-                ref, reference.rows_of(ref.bf16_fold(), ref, aggs, flt), aggs, flt
-            )
+        if c is not None:
             c.pop("bad_windows")
             controls.append(c)
     merged = reference.merge(numbers) if numbers else {"rows_compared": 0}
@@ -504,7 +476,7 @@ def run_cell(cell: manifest.Cell, seed: int, seconds: float, trace: bool, *,
         due: dict[int, float] = {}
         if tr["mode"] == "paced":
             due = windows_due(
-                origin, t_open, t_close, cell.config["query"]["slide_ms"]
+                origin, t_open, t_close, cell.deployment.window_ms(cell.config)[1]
             )
             tail_end = t_close + tr["tail_s"]
             while time.monotonic() < tail_end and not all(
@@ -617,7 +589,13 @@ def run_cell(cell: manifest.Cell, seed: int, seconds: float, trace: bool, *,
     numbers["windows_undelivered"] = undelivered
     numbers["late_rows"] = int(final.get("late_rows", 0))
     numbers["decode_fallback_rows"] = int(final.get("decode_fallback_rows", 0))
-    correct, compared = reference.verdict(numbers)
+    own_limits = getattr(cell.deployment, "LIMITS", {})
+    try:
+        correct, compared = reference.verdict(numbers, own_limits)
+        if control_numbers is not None:
+            c_ok, c_compared = reference.verdict(control_numbers, own_limits)
+    except reference.Unlimited as e:
+        raise RunFailed(str(e)) from e
     failed = undelivered + bad_windows
     log(json.dumps({
         "workload": cell.name, "seed": seed, "seconds": seconds,
@@ -641,9 +619,8 @@ def run_cell(cell: manifest.Cell, seed: int, seconds: float, trace: bool, *,
         **trace_record,
     }))
     if control_numbers is not None:
-        c_ok, c_compared = reference.verdict(control_numbers)
-        log("control (bf16 fold in the engine's place): correct=%s %s"
-            % (c_ok, json.dumps(c_compared)))
+        log("control (the deployment's control in the engine's place): "
+            "correct=%s %s" % (c_ok, json.dumps(c_compared)))
     # a per-layer reader that found nothing to read leaves its metric out of
     # the line, which names it under ``not_read`` (the record on stderr under
     # ``per_layer_not_read``): laid over a commit whose program lacks a

@@ -2,6 +2,7 @@
 lateness, and the protocol of the process."""
 
 import json
+import os
 import socket
 import struct
 import subprocess
@@ -14,6 +15,7 @@ from benchmark.harness import events, feeder, manifest, wire
 
 FEED = dict(seed=5, n_keys=10, key_prefix="sensor_", partitions=4, chunk_ms=50,
             events_per_chunk=400, records_per_batch=64)
+EMIT = os.path.join(manifest.BENCH_DIR, "deployments", "emit_measurements.py")
 
 
 def fetch_request(parts, max_wait=0, topic=b"t"):
@@ -48,10 +50,10 @@ def parse_fetch(parts):
 
 def filled_broker(chunks=3):
     f = events.Feed(**FEED)
-    enc = wire.Encoder(f.key_prefix, f.key_width, f.records_per_batch)
+    deployment = manifest.load_module(EMIT, "bench_deployment_")
     b = feeder.Broker("t", f.partitions)
     for c in range(chunks):
-        b.append(feeder.encode_chunk(f, enc, c), f.events_per_chunk // f.partitions)
+        b.append(deployment.encode_chunk(FEED, c), f.events_per_chunk // f.partitions)
     return f, b
 
 
@@ -115,7 +117,7 @@ def test_lateness_arithmetic():
 @pytest.mark.parametrize("mode", ["drain", "paced"])
 def test_feeder_process_protocol(mode):
     params = {"topic": "t", "mode": mode, "lead_events": 2000,
-              "ahead_chunks": 4, "encoders": 2, "feed": FEED}
+              "ahead_chunks": 4, "encoders": 2, "module": EMIT, "feed": FEED}
     proc = subprocess.Popen(
         [sys.executable, "-m", "benchmark.harness.feeder", json.dumps(params)],
         cwd=manifest.ROOT, stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)

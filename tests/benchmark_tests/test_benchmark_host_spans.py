@@ -30,9 +30,12 @@ SEED = 3_000_000_023
 
 with open(os.path.join(manifest.ROOT, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
-#: what every one-chip cell lists (the four-chip cell lists two more)
+#: what a one-chip cell lists
 ENTRIES = [m for m in BENCH["per_layer"] if "emit_sliding.drain" in m["workloads"]]
 LISTED = [e["name"] for e in ENTRIES]
+#: what reads nothing on the CPU: no emission block crosses on the ``-d2h``
+#: worker where the emit lag is 0
+NO_D2H_WORKER = ["d2h_fetch_ready_share.drain"]
 #: the share readers that were parked under these names (PR 25): five of the
 #: eight window shares and the three of the sources
 NAMES = [
@@ -137,10 +140,11 @@ def test_attribute_gaps_on_the_recorded_chip_trace():
 # -- the readers, through the harness ------------------------------------------
 
 
-def test_every_entry_has_its_reader_and_every_reader_its_entry():
-    listed = {m["name"] for m in BENCH["per_layer"]}
-    assert len(listed) == len(BENCH["per_layer"]) == 24 and len(LISTED) == 22
-    held = {f[:-3] for f in os.listdir(os.path.join(manifest.BENCH_DIR, "metrics"))
+def test_every_entry_has_its_reader_and_every_reader_its_entry(tree):
+    bench = tree.bench
+    listed = {m["name"] for m in bench["per_layer"]}
+    assert len(listed) == len(bench["per_layer"])
+    held = {f[:-3] for f in os.listdir(os.path.join(tree.bench_dir, "metrics"))
             if f.endswith(".py")}
     assert held == listed
     # nothing of the manifest waits under the fixtures any more: what is left
@@ -153,15 +157,16 @@ def test_every_entry_has_its_reader_and_every_reader_its_entry():
                  "sliding_entries.json"):
         assert not os.path.exists(os.path.join(FIXTURES, gone))
     # the share readers: spans of the program, in percent of one thread; the
-    # three that were parked twice are one entry each, under PR 27's names
-    assert set(NAMES) <= set(LISTED) and len(NAMES) == 8
-    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    # three that were parked twice are one entry each, under PR 27's names;
+    # each lists every cell
+    assert set(NAMES) <= listed and len(NAMES) == 8
+    by_name = {m["name"]: m for m in bench["per_layer"]}
     for name in NAMES + ["stripe_flush_share.drain", "emit_d2h_wait_share.drain",
                          "emit_finalize_share.drain"]:
         m = by_name[name]
         assert (m["source"], m["moves"], m["better"], m["unit"]) == (
             "program_span", "events_per_s", "lower", "%")
-        assert len(m["workloads"]) == 5
+        assert m["workloads"] == tree.cells()
     for merged in ("window_flush_share.drain", "window_d2h_wait_share.drain",
                    "window_finalize_share.drain"):
         assert merged not in listed and merged not in held
@@ -219,8 +224,8 @@ def test_a_traced_run_prints_every_listed_metric(tiny_cell, traced_line):
     assert lastline.check_text(text, tiny_cell.per_layer, True) == []
     line = json.loads(text)
     assert line["correct"] is True
-    assert set(tiny_cell.per_layer) <= set(line["metrics"])
-    assert run["per_layer_not_read"] == line["not_read"] == []
+    assert set(tiny_cell.per_layer) - set(NO_D2H_WORKER) <= set(line["metrics"])
+    assert run["per_layer_not_read"] == line["not_read"] == NO_D2H_WORKER
     # of the three that read the trace, one needs nothing else
     assert tiny_cell.from_trace == {"device_idle_share.drain"}
 
@@ -324,10 +329,13 @@ def test_the_tiny_run_fills_every_counter_the_shares_read(traced_line):
 
 
 def test_every_reader_of_the_real_manifest_reads_the_tiny_run(traced_line):
-    """The readers ``emit_sliding.drain`` lists, on the same run."""
+    """The readers ``emit_sliding.drain`` lists, on the same run: every one
+    but the ``-d2h`` worker's reads a number."""
     _text, run = traced_line
     cell = manifest.load_cell("emit_sliding.drain")
-    assert len(cell.readers) == 22
+    assert set(cell.readers) == set(LISTED) == {
+        m["name"] for m in BENCH["per_layer"]
+        if cell.name in m["workloads"]}
     obs = {"counters": run["counters"], "window_s": run["window_s"],
            "feeder": {"backlog_min": run["backlog_min"]},
            "trace": {"busy_s": 0.5, "window_s": run["window_s"]},
@@ -335,6 +343,9 @@ def test_every_reader_of_the_real_manifest_reads_the_tiny_run(traced_line):
            "consumer_hold_ms": run["consumer_hold_ms"]}
     for name, read in cell.readers.items():
         value = read(obs)
+        if name in NO_D2H_WORKER:
+            assert value is None, name
+            continue
         assert value is not None and math.isfinite(value) and value >= 0, name
 
 
@@ -359,6 +370,7 @@ def test_a_reader_that_finds_no_counter_leaves_its_metric_out(
         return out
 
     monkeypatch.setattr(runner, "engine_counters", without)
+    left_out = left_out + NO_D2H_WORKER
     text, run = traced_run(tiny_cell, monkeypatch)
     line = json.loads(text)
     assert run["per_layer_not_read"] == line["not_read"] == left_out

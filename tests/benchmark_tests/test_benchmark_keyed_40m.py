@@ -32,33 +32,35 @@ def _json(*path):
         return json.load(f)
 
 
-def test_cell_loads_with_four_chips_and_every_reader(cell):
+def test_cell_loads_with_four_chips_and_every_reader(tree):
+    cell = tree.load_cell(CELL)
     assert cell.name == CELL and cell.chips == 4
     assert cell.config["name"] == "keyed_40m"
     assert cell.config["engine"]["mesh_devices"] == 4
     assert set(cell.end_to_end) == {"events_per_s", "setup_s"}
-    with open(os.path.join(manifest.ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    # every per-layer metric the benchmark has lists the cell
+    bench = tree.bench
+    # the cell reads every per-layer metric that lists every cell
     # (``d2h_bytes_per_window.drain`` too: the cell closes about six windows
-    # a run, and a reader that finds none leaves its metric out), two of
-    # them this cell alone
-    assert set(cell.per_layer) == set(cell.readers) == {
-        m["name"] for m in bench["per_layer"]}
-    assert len(cell.per_layer) == 24
+    # a run, and a reader that finds none leaves its metric out) and the
+    # mesh's two, which list the four-chip cells alone: exactly the metrics
+    # that list it
+    every = {m["name"] for m in bench["per_layer"]
+             if m["workloads"] == tree.cells()}
+    assert set(cell.per_layer) == set(cell.readers) == every | set(MESH_ONLY) == {
+        m["name"] for m in bench["per_layer"] if CELL in m["workloads"]}
     assert [m["name"] for m in bench["per_layer"]
             if m["workloads"] == [CELL]] == list(MESH_ONLY)
-    assert all(CELL in m["workloads"] and len(m["workloads"]) == 5
-               for m in bench["per_layer"] if m["name"] not in MESH_ONLY)
+    assert all(m["workloads"] == tree.cells()
+               for m in bench["per_layer"]
+               if CELL in m["workloads"] and m["name"] not in MESH_ONLY)
     # the first four-chip cell, and inside the limit on them
-    four = [w["name"] for w in bench["workloads"] if w["chips"] == 4]
+    four = tree.cells(chips=4)
     assert four == [CELL]
     assert len(four) <= max(1, len(bench["workloads"]) // 2)
 
 
-def test_the_two_mesh_entries_keep_to_the_contract(cell):
-    with open(os.path.join(manifest.ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+def test_the_two_mesh_entries_keep_to_the_contract(tree, cell):
+    bench = tree.bench
     entries = [m for m in bench["per_layer"] if m["name"] in MESH_ONLY]
     others = {m["layer"] for m in bench["per_layer"] if m["name"] not in MESH_ONLY}
     assert [(e["name"], e["unit"], e["layer"]) for e in entries] == [

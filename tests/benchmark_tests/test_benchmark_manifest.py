@@ -1,6 +1,9 @@
 """BENCHMARK.json agrees with the files under its paths, keeps to the
-contract's shapes, and the harness finds a cell, a configuration and a
-per-layer metric that exist only under the fixtures, by name."""
+contract's shapes and to the rules that let a cell, a configuration and a
+per-layer metric enter by new files and entries alone, and the harness
+finds a cell, a configuration and a per-layer metric that exist only under
+the fixtures, by name.  The rule tests take ``tree`` (``conftest.py``): the
+real manifest, and one with the fixtures' entering deployment appended."""
 
 import json
 import os
@@ -15,12 +18,13 @@ FIXTURES = os.path.join(HERE, "fixtures")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+#: the per-layer metrics of the mesh: they list the four-chip cells alone
+MESH_ONLY = ("shard_cells_max_share.drain", "stripe_copies_per_flush.drain")
 
 
-@pytest.fixture(scope="module")
-def bench():
-    with open(os.path.join(manifest.ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+@pytest.fixture
+def bench(tree):
+    return tree.bench
 
 
 def test_top_level_keys(bench):
@@ -31,44 +35,73 @@ def test_top_level_keys(bench):
     assert all(not w.startswith("/") and ".." not in w for w in bench["command"])
 
 
-def test_every_configuration_has_its_file_and_a_cell(bench):
+def test_every_configuration_has_its_file_and_a_cell(tree, bench):
     used = {w["config"] for w in bench["workloads"]}
     files = set()
     for c in bench["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and c["name"] in used
-        assert c["file"].startswith("benchmark/configs/")
-        with open(os.path.join(manifest.ROOT, c["file"])) as f:
+        assert c["file"] == "benchmark/configs/" + c["name"] + ".json"
+        with open(os.path.join(tree.root, c["file"])) as f:
             held = json.load(f)
         assert held["name"] == c["name"] and held["source"] == c["source"]
         assert held["reduced"] == c["reduced"]
         assert held["guarantees"] and held["assumed"] and held["precision"]
         assert 1 <= len(c["source"]) <= 200 and 1 <= len(c["why"]) <= 200
+        # the deployment module it names lies under the benchmark's paths
+        top = held["module"].split("/")[0]
+        assert top in bench["paths"] and ".." not in held["module"]
+        assert os.path.isfile(os.path.join(tree.root, held["module"]))
         files.add(c["file"])
     assert len(files) == len(bench["configs"])
 
 
-def test_every_cell_has_its_files_and_loads(bench):
+def test_every_cell_has_its_files_and_loads(tree, bench):
     pairs = set()
+    one_chip = tree.cells(chips=1)
     for w in bench["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
         assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
         pairs.add((w["config"], w["traffic"]))
-        cell = manifest.load_cell(w["name"])
+        cell = tree.load_cell(w["name"])
         assert "setup_s" in cell.end_to_end and len(cell.end_to_end) >= 2
         assert cell.per_layer and set(cell.readers) == set(cell.per_layer)
-        # every per-layer metric lists every cell, but the mesh's two
-        assert len(cell.per_layer) == (24 if w["chips"] == 4 else 22)
+        # a cell's per-layer metrics are exactly those that list it, and
+        # every metric that lists every one-chip cell lists every cell
+        assert set(cell.per_layer) == {
+            m["name"] for m in bench["per_layer"] if w["name"] in m["workloads"]}
+        assert {m["name"] for m in bench["per_layer"]
+                if set(one_chip) <= set(m["workloads"])} <= set(cell.per_layer)
         # of the three that read the trace one needs nothing else
         assert cell.from_trace == {"device_idle_share.drain"}
         assert cell.traffic["mode"] in ("drain", "paced")
     assert len(pairs) == len(bench["workloads"])
+    # no more than half the cells, rounded down, on four chips (one always)
     four = sum(w["chips"] == 4 for w in bench["workloads"])
     assert four <= max(1, len(bench["workloads"]) // 2)
 
 
-def test_metrics_keep_to_the_contract(bench):
+def test_each_metric_lists_every_cell_the_mesh_or_one_configuration(tree, bench):
+    """The rules a cell or a metric enters by.  Each per-layer metric lists
+    every cell, or the four-chip cells alone (the mesh's), or the cells of
+    one configuration alone (a deployment's own); every list names cells
+    that exist, in the manifest's order (entries are appended)."""
+    cells, four = tree.cells(), tree.cells(chips=4)
+    config_of = {w["name"]: w["config"] for w in bench["workloads"]}
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        listed = m.get("workloads", cells)
+        assert listed == [c for c in cells if c in listed], m["name"]
+    for m in bench["per_layer"]:
+        listed = m["workloads"]
+        if m["name"] in MESH_ONLY:
+            assert listed == four
+        else:
+            assert listed == cells or len({config_of[c] for c in listed}) == 1, (
+                m["name"])
+
+
+def test_metrics_keep_to_the_contract(tree, bench):
     cells = {w["name"] for w in bench["workloads"]}
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25
@@ -88,7 +121,7 @@ def test_metrics_keep_to_the_contract(bench):
             # the cell reports the end-to-end metric this one moves
             assert "workloads" not in moved or w in moved["workloads"]
         assert os.path.exists(os.path.join(
-            manifest.BENCH_DIR, "metrics", m["name"] + ".py"))
+            tree.bench_dir, "metrics", m["name"] + ".py"))
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert NAME.match(m["name"]), m["name"]
         assert UNIT.match(m["unit"]), m["unit"]
@@ -103,15 +136,15 @@ def test_layers_are_spelled_as_in_perf_md(bench):
         assert "| " + m["layer"] + " |" in perf, m["layer"]
 
 
-def test_files_under_paths_are_named_from_name_characters(bench):
+def test_files_under_paths_are_named_from_name_characters(tree, bench):
     ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
     for top in bench["paths"]:
-        for root, dirs, files in os.walk(os.path.join(manifest.ROOT, top)):
+        for root, dirs, files in os.walk(os.path.join(tree.root, top)):
             dirs[:] = [d for d in dirs if d != "__pycache__"]
             for f in files:
                 if f.endswith(".pyc"):
                     continue
-                rel = os.path.relpath(os.path.join(root, f), manifest.ROOT)
+                rel = os.path.relpath(os.path.join(root, f), tree.root)
                 assert ok.match(rel), rel
 
 
@@ -229,9 +262,18 @@ def test_reader_arithmetic(name):
     assert reader(name)(obs) == pytest.approx(want)
 
 
-def test_every_per_layer_metric_of_the_manifest_has_its_case(bench):
-    assert MANIFEST_READERS == sorted(m["name"] for m in bench["per_layer"])
-    assert len(MANIFEST_READERS) == 24
+def test_every_per_layer_metric_of_the_manifest_has_its_case(tree, bench):
+    """A case here, or a case file of the metric's own under
+    ``reader_cases/`` (how a later metric brings its case: a new file),
+    for every per-layer metric and no other; the files' cases hold."""
+    files = tree.cases()
+    assert not set(files) & set(READER_CASES)
+    assert sorted(MANIFEST_READERS + list(files)) == sorted(
+        m["name"] for m in bench["per_layer"])
+    for name, (obs, want) in files.items():
+        read = manifest.load_reader(os.path.join(
+            tree.bench_dir, "metrics", name + ".py"))
+        assert read(obs) == pytest.approx(want), name
 
 
 @pytest.mark.parametrize("name", MANIFEST_READERS + [

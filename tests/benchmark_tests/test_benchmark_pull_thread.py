@@ -1,24 +1,20 @@
 """The nine per-layer readers that name the rest of the pull thread and the
-``-d2h`` worker (ISSUE 36): ``window.finalize``'s three children, the
-window operator suspended at its yields, the collector's pauses, the pull
-thread's time off the CPU, the source's own work and watermarks, and the
-worker's wait for the programs ahead of its copy.
+``-d2h`` worker: ``window.finalize``'s three children, the window operator
+suspended at its yields, the collector's pauses, the pull thread's time
+off the CPU, the source's own work and watermarks, and the worker's wait
+for the programs ahead of its copy.
 
-They wait under ``fixtures/pull_thread/`` with their manifest entries
-(``entries.json``, each listing all five cells; the readers in ``metrics/``) until a
-``benchmark`` PR admits them: five tests of the benchmark pin the number of
-its entries, and a PR of another kind edits no file the benchmark has
-(PERF.md, Open questions); checking the entries against the contract is
-that PR's, with the harness's own tests.  Here: each reader's arithmetic, a
-reader with nothing to read (the parent's counters too) returns nothing, two
-of the new spans name gaps, and a tiny run through the real harness with the
-entries laid over a one-chip cell's reads eight of the nine (no emission
-block crosses on a ``-d2h`` worker on the CPU)."""
+They are the manifest's, under ``benchmark/metrics/``, each listing every
+cell, with a case file each under ``reader_cases/``.  Here: each reader's
+arithmetic, a reader with nothing to read (the counters of a program
+without their spans too) returns nothing, two of the spans name gaps, and
+a tiny run through the real harness with a one-chip cell's entries reads
+eight of the nine (no emission block crosses on a ``-d2h`` worker on the
+CPU)."""
 
 import json
 import math
 import os
-import shutil
 
 import pytest
 
@@ -26,56 +22,57 @@ from benchmark.harness import host_spans, lastline, manifest, runner, trace_redu
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 FIXTURES = os.path.join(HERE, "fixtures")
-PARKED = os.path.join(FIXTURES, "pull_thread")
+CASES = os.path.join(HERE, "reader_cases")
 XPLANE = os.path.join(FIXTURES, "small.xplane.pb")
 SEED = 3_000_000_036
 
-with open(os.path.join(PARKED, "entries.json")) as _f:
-    ENTRIES = json.load(_f)
-NAMES = [e["name"] for e in ENTRIES]
+NAMES = [
+    "emit_finalize_keys_share.drain", "emit_finalize_predicate_share.drain",
+    "emit_finalize_columns_share.drain", "window_downstream_share.drain",
+    "gc_pause_share.drain", "pull_thread_offcpu_share.drain",
+    "source_watermark_share.drain", "source_self_share.drain",
+    "d2h_fetch_ready_share.drain",
+]
 with open(os.path.join(manifest.ROOT, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
-#: what the nine would be read beside: every metric of a one-chip cell
+ENTRIES = [m for m in BENCH["per_layer"] if m["name"] in NAMES]
+#: what the nine are read beside: every metric of a one-chip cell
 ONE_CHIP = [m for m in BENCH["per_layer"] if "emit_sliding.drain" in m["workloads"]]
 
 
 def reader(name):
-    return manifest.load_reader(os.path.join(PARKED, "metrics", name + ".py"))
+    return manifest.load_reader(
+        os.path.join(manifest.BENCH_DIR, "metrics", name + ".py"))
 
 
 # -- the entries ----------------------------------------------------------------
 
 
 def test_every_parked_entry_has_its_reader_and_none_is_in_the_manifest():
-    parked = {f[:-3] for f in os.listdir(os.path.join(PARKED, "metrics"))}
-    assert parked == set(NAMES)
+    """Admitted: each of the nine is a manifest entry listing every cell,
+    with its reader under ``benchmark/metrics/``, and none waits under the
+    fixtures any more."""
+    cells = [w["name"] for w in BENCH["workloads"]]
+    assert [e["name"] for e in ENTRIES] == NAMES
+    assert all(e["workloads"] == cells for e in ENTRIES)
     own = {f[:-3] for f in os.listdir(os.path.join(manifest.BENCH_DIR, "metrics"))}
-    assert not own & set(NAMES)
+    assert set(NAMES) <= own
+    assert not os.path.exists(os.path.join(FIXTURES, "pull_thread"))
 
 
 # -- the readers ----------------------------------------------------------------
 
 
 SHARE = {"window_s": 10.0}
-READER_CASES = {
-    "emit_finalize_keys_share.drain": (
-        {**SHARE, "counters": {"phase_ms_finalize_keys": 1500.0}}, 15.0),
-    "emit_finalize_predicate_share.drain": (
-        {**SHARE, "counters": {"phase_ms_finalize_predicate": 250.0}}, 2.5),
-    "emit_finalize_columns_share.drain": (
-        {**SHARE, "counters": {"phase_ms_finalize_columns": 400.0}}, 4.0),
-    "window_downstream_share.drain": (
-        {**SHARE, "counters": {"downstream_ms": 2800.0}}, 28.0),
-    "gc_pause_share.drain": ({**SHARE, "counters": {"gc_pause_ms": 30.0}}, 0.3),
-    "pull_thread_offcpu_share.drain": (
-        {**SHARE, "counters": {"pull_cpu_ms": 8200.0}}, 18.0),
-    "source_watermark_share.drain": (
-        {**SHARE, "counters": {"source_watermark_ms": 120.0}}, 1.2),
-    "source_self_share.drain": (
-        {**SHARE, "counters": {"source_batch_ms": 700.0}}, 7.0),
-    "d2h_fetch_ready_share.drain": (
-        {"counters": {"d2h_fetch_ms": 4400.0, "d2h_ready_ms": 3300.0}}, 75.0),
-}
+
+
+def _case(name):
+    with open(os.path.join(CASES, name + ".json")) as f:
+        case = json.load(f)
+    return case["obs"], case["want"]
+
+
+READER_CASES = {name: _case(name) for name in NAMES}
 #: the window operator's and the source's counters on the parent commit
 PARENT = {
     "rows_in": 10, "dnz_op_batch_ms.window": 5.0, "phase_ms_finalize": 2.0,
@@ -173,22 +170,18 @@ def test_downstream_and_a_collection_inside_it_name_gaps(monkeypatch):
 @pytest.fixture(scope="module")
 def tiny_cell(tmp_path_factory):
     """``tiny_sliding.drain`` of the fixtures with a one-chip cell's entries
-    of the real manifest and the nine laid over its own; the fixtures' tree
-    with the nine readers beside its own."""
+    of the real manifest, the nine among them, laid over its own."""
     with open(os.path.join(FIXTURES, "BENCHMARK.json")) as f:
         fx = json.load(f)
     listed = {m["name"] for m in ONE_CHIP}
+    assert set(NAMES) <= listed
     fx["per_layer"] = [m for m in fx["per_layer"] if m["name"] not in listed]
-    for e in ONE_CHIP + ENTRIES:
+    for e in ONE_CHIP:
         fx["per_layer"].append({**e, "workloads": ["tiny_sliding.drain"]})
-    root = tmp_path_factory.mktemp("pull_thread")
-    (root / "BENCHMARK.json").write_text(json.dumps(fx))
-    tree = root / "benchmark"
-    shutil.copytree(os.path.join(FIXTURES, "benchmark"), tree)
-    for f in os.listdir(os.path.join(PARKED, "metrics")):
-        shutil.copy(os.path.join(PARKED, "metrics", f), tree / "metrics" / f)
+    path = tmp_path_factory.mktemp("pull_thread") / "BENCHMARK.json"
+    path.write_text(json.dumps(fx))
     return manifest.load_cell(
-        "tiny_sliding.drain", str(root / "BENCHMARK.json"), str(tree))
+        "tiny_sliding.drain", str(path), os.path.join(FIXTURES, "benchmark"))
 
 
 def test_a_tiny_traced_run_reads_the_nine_but_the_d2h_worker(tiny_cell):

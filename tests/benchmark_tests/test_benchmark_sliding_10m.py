@@ -35,30 +35,33 @@ def cell():
     return manifest.load_cell(CELL)
 
 
-def test_cell_and_configuration_load_by_name(cell):
+def test_cell_and_configuration_load_by_name(tree):
+    cell = tree.load_cell(CELL)
     assert cell.name == CELL and cell.chips == 1
     assert cell.config["name"] == "sliding_10m"
     assert cell.traffic["config"] == "sliding_10m"
     assert cell.traffic["traffic"] == "drain" == cell.traffic["mode"]
     assert set(cell.end_to_end) == {"events_per_s", "setup_s"}
-    # every list the keyed_10m cell is on: all per-layer metrics but the
-    # two of the four-chip cell
-    assert set(manifest.load_cell("keyed_10m.drain").per_layer) == set(
+    # every list the keyed_10m cell is on: the per-layer metrics that list
+    # every cell
+    assert set(tree.load_cell("keyed_10m.drain").per_layer) == set(
         cell.per_layer)
-    with open(os.path.join(manifest.ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    assert len(cell.per_layer) == len(bench["per_layer"]) - 2 == 22
-    # appended, not put in the middle
-    assert bench["configs"][-1]["name"] == "sliding_10m"
-    assert bench["workloads"][-1]["name"] == CELL
+    bench = tree.bench
+    assert set(cell.per_layer) == {
+        m["name"] for m in bench["per_layer"] if m["workloads"] == tree.cells()}
+    # appended, not put in the middle: after keyed_40m's entries, and in
+    # every list in the manifest's order of cells
+    configs = [c["name"] for c in bench["configs"]]
+    assert configs.index("sliding_10m") == configs.index("keyed_40m") + 1
+    cells = tree.cells()
+    assert cells.index(CELL) == cells.index("keyed_40m.drain.4chip") + 1
     for m in bench["end_to_end"] + bench["per_layer"]:
-        assert "workloads" not in m or m["workloads"][-1] == CELL or (
-            CELL not in m["workloads"])
+        listed = m.get("workloads", cells)
+        assert listed == [c for c in cells if c in listed]
 
 
-def test_the_two_entries_keep_to_the_contract(cell):
-    with open(os.path.join(manifest.ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+def test_the_two_entries_keep_to_the_contract(tree, cell):
+    bench = tree.bench
     entries = [m for m in bench["per_layer"] if m["name"] in NEW_READERS]
     assert [(m["name"], m["unit"], m["source"], m["layer"]) for m in entries] == [
         ("merge_steps_per_window.drain", "steps/window", "program_counter", "H2D"),
@@ -68,7 +71,7 @@ def test_the_two_entries_keep_to_the_contract(cell):
     for m in entries:
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-        assert CELL in m["workloads"] and len(m["workloads"]) == 5
+        assert m["workloads"] == tree.cells()
         assert m["moves"] == "events_per_s" and m["better"] == "lower"
         assert callable(cell.readers[m["name"]])  # admitted, and it loads
         assert not os.path.exists(os.path.join(
